@@ -52,7 +52,8 @@ test:
 # quick + slow (training loops, multi-process rigs) minus the two
 # multi-minute gates — r5 measured on this 1-core box: 11m51s with a
 # cold XLA compilation cache, 6m44s warm (tests/conftest.py persists
-# compiles under /tmp/mxrcnn_jax_test_cache).  VERDICT r04 item 8's
+# compiles under $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache —
+# mx_rcnn_tpu/runtime.py).  VERDICT r04 item 8's
 # <=15 min re-runnability target is met either way.
 test-all:
 	python -m pytest tests/ -x -q -m "not gate"

@@ -101,7 +101,7 @@ RULES: Dict[str, str] = {
 _TRANSFORMS = {
     "jit", "vmap", "pmap", "grad", "value_and_grad", "checkpoint", "remat",
     "custom_vjp", "custom_jvp", "pallas_call", "scan", "while_loop", "cond",
-    "switch", "fori_loop", "map", "shard_map", "shard_map_compat",
+    "switch", "fori_loop", "map", "shard_map",
     "defvjp", "defjvp", "associative_scan", "named_call",
 }
 
@@ -384,8 +384,8 @@ def _mark_transform_roots(mod: ModuleInfo) -> None:
         if not is_defvjp:
             if leaf not in _TRANSFORMS:
                 continue
-            if not (canon.startswith("jax") or leaf == "shard_map_compat"
-                    or "pallas" in canon or "shard_map" in canon):
+            if not (canon.startswith("jax") or "pallas" in canon
+                    or "shard_map" in canon):
                 continue
             if ".tree" in canon or "tree_util" in canon:
                 continue  # jax.tree.map is a pytree map, not a transform

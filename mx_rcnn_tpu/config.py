@@ -550,14 +550,6 @@ class FTConfig:
     # the error to a WARNING; the elastic controller (ft/elastic.py) sets
     # it for its own supervised restores, where the resize is the point.
     allow_resize_resume: bool = False
-    # persistent XLA compilation cache directory ("" = off).  Wired at
-    # CLI startup (tools/train.py, tools/serve.py, tools/fleet.py —
-    # ``serve/export.py — enable_compile_cache``) into BOTH the live
-    # process config and the child environment, so elastic relaunches
-    # (EXIT_RESIZE/EXIT_PEER_FAILURE supervisor restarts) skip XLA
-    # re-compilation and pay tracing only — the ROADMAP item 5
-    # recovery-time lever (measured deltas: docs/FT.md "Recovery time").
-    compile_cache_dir: str = ""
 
 
 @dataclass(frozen=True)

@@ -164,15 +164,12 @@ SMOKE_EVENTS: Tuple[KillEvent, ...] = (
 
 
 def _child_env() -> Dict[str, str]:
-    """CPU platform + the shared persistent XLA compile cache, so restart
-    attempts pay disk reads instead of recompiles (same routing as
-    tests/conftest.py gives its subprocess children)."""
+    """The crash loop is a CPU correctness protocol: children are pinned
+    to the CPU platform.  Each child (``tools.train``) arms the persistent
+    compile cache itself (``runtime.enable_compile_cache``), so restart
+    attempts pay disk reads instead of recompiles."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    cache = env.get("MXRCNN_TEST_JAX_CACHE", "/tmp/mxrcnn_jax_test_cache")
-    env["JAX_COMPILATION_CACHE_DIR"] = cache
-    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
-    env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
     return env
 
 

@@ -18,8 +18,10 @@ so a machine without a toolchain still runs — just slower.  Use
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Dict, Optional, Sequence
@@ -31,23 +33,50 @@ logger = logging.getLogger("mx_rcnn_tpu")
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
 _LIB_PATH = os.path.join(os.path.dirname(__file__), "libmxrcnn_native.so")
 _SOURCES = ("nms.cc", "maskapi.cc")
+_CXX = ("g++", "-O3", "-shared", "-fPIC", "-std=c++17")
+# digest of what the library beside it was built from; a library without a
+# matching stamp (copied in from another tree or machine, sources edited
+# since) is rebuilt, never loaded on trust
+_STAMP_PATH = _LIB_PATH + ".srchash"
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 _lock = threading.Lock()
 
 
-def build(force: bool = False) -> bool:
-    """Compile the shared library. Returns True on success."""
-    srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    if not force and os.path.exists(_LIB_PATH) and all(
-        os.path.getmtime(_LIB_PATH) >= os.path.getmtime(s) for s in srcs
-    ):
-        return True
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", _LIB_PATH] + srcs
+def _build_digest(srcs: Sequence[str]) -> str:
+    """sha256 over the compile command, the machine and the source bytes."""
+    h = hashlib.sha256(" ".join(_CXX + (platform.machine(),)).encode())
+    for path in srcs:
+        with open(path, "rb") as f:
+            h.update(os.path.basename(path).encode() + b"\0" + f.read())
+    return h.hexdigest()
+
+
+def _stamped_digest() -> Optional[str]:
     try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        with open(_STAMP_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def build(force: bool = False) -> bool:
+    """Compile the shared library unless the one on disk was built from
+    exactly these sources (content digest in ``_STAMP_PATH`` — file times
+    say nothing once a tree has been copied).  Returns True on success."""
+    srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
+    digest = _build_digest(srcs)
+    if (not force and os.path.exists(_LIB_PATH)
+            and _stamped_digest() == digest):
+        return True
+    try:
+        subprocess.run(list(_CXX) + ["-o", _LIB_PATH] + srcs, check=True,
+                       capture_output=True, text=True)
+        # stamp last: an interrupted build leaves the old (mismatching)
+        # stamp and is redone
+        with open(_STAMP_PATH, "w") as f:
+            f.write(digest + "\n")
         return True
     except (OSError, subprocess.CalledProcessError) as e:
         detail = getattr(e, "stderr", "") or str(e)

@@ -46,10 +46,19 @@ from mx_rcnn_tpu.ops.boxes import bbox_overlaps
 _NEG = -1e10
 
 # Suppression-sweep backend: the Pallas kernel (ops/nms_pallas.py) keeps the
-# whole sweep in VMEM; the jnp sweep below is the oracle and the fallback.
-# "auto" = Pallas on real TPU, jnp elsewhere (the kernel runs under
-# interpret=True on CPU, which is only useful for testing).
+# whole sweep in VMEM; the jnp sweep below is the oracle the tests compare
+# it against.  "auto" = the kernel on a TPU, the jnp sweep on any other
+# platform (see _resolve_backend — on a TPU it never gives way quietly).
 _BACKEND = "auto"
+
+# the kernel's tile: whole 128-lane registers, independent of the padding
+# tile (greedy NMS is exact at any tile size)
+_KERNEL_TILE = 128
+# the kernel holds a (tile, K) fp32 IoU slab and several temporaries of
+# that shape under the 16 MiB scoped-VMEM default.  K=12032 (the 12000-box
+# recipe) compiles and matches the jnp sweep on a v5e (chip_smoke.py checks
+# it); nothing larger has run, so the bound stays where it was
+_KERNEL_MAX_K = 16384
 
 
 def set_nms_backend(name: str) -> None:
@@ -66,17 +75,28 @@ def set_nms_backend(name: str) -> None:
 
 
 def _resolve_backend(backend: Optional[str], k: int, tile: int) -> str:
+    """'auto' → the sweep that runs for ``k`` padded boxes in tiles of
+    ``tile``.  Off-TPU that is the jnp sweep.  On a TPU it is the kernel
+    for every input of at least one tile; an input the kernel cannot take
+    (a tile that is not whole 128-lane registers, or more boxes than its
+    VMEM slab holds) raises instead of quietly running the slower sweep —
+    pass ``backend='jnp'`` to choose that one.  Inputs smaller than one
+    tile (k < tile_size, so tile == k) have no tiling for the kernel to
+    do and run the jnp single-tile sweep."""
     b = backend or _BACKEND
-    if b == "auto":
-        # lane-alignment guard: the kernel's (1, K)/(T, K) blocks want K and
-        # T in whole 128-lane registers; odd shapes fall back to jnp.
-        # VMEM guard: the (T, K) fp32 IoU slab must fit comfortably —
-        # 16 MB covers the production proposal shape (256 x 12032 ≈ 12.3 MB,
-        # verified on v5e) with headroom for Mosaic temporaries.
-        fits = tile * k * 4 <= 16 * 1024 * 1024
-        b = "pallas" if (jax.default_backend() == "tpu" and fits
-                         and tile % 128 == 0 and k % tile == 0) else "jnp"
-    return b
+    if b != "auto":
+        return b
+    if jax.default_backend() != "tpu":  # graphlint: disable=GL203 the platform name is a host string, fixed at trace time
+        return "jnp"
+    if tile % _KERNEL_TILE == 0 and k <= _KERNEL_MAX_K:
+        return "pallas"
+    if k == tile:
+        return "jnp"
+    raise ValueError(
+        f"NMS of {k} boxes in tiles of {tile} cannot run in the Pallas "
+        f"kernel on this TPU (needs a tile that is a multiple of "
+        f"{_KERNEL_TILE} and at most {_KERNEL_MAX_K} boxes); pass "
+        f"backend='jnp' to run the XLA sweep instead")
 
 
 def _chain_fixed_point(iou_self: jnp.ndarray, alive0: jnp.ndarray,
@@ -234,29 +254,30 @@ def _run_sweep(
     iou_threshold: float,
     t: int,
     backend: Optional[str],
+    interpret: bool,
 ) -> jnp.ndarray:
-    """Backend resolution + sweep dispatch — the ONE copy of the Pallas
-    tile-cap/VMEM-guard logic, shared by the per-image and batched paths
-    (rank-dispatched: (K, 4) runs the per-image sweep, (B, K, 4) the
-    cross-image batched one; the Pallas kernel is per-image either way,
-    vmapped over the batch — the shape the chip measurements validated).
+    """Backend resolution + sweep dispatch, shared by the per-image and
+    batched paths (rank-dispatched: (K, 4) runs the per-image sweep,
+    (B, K, 4) the cross-image batched one; the Pallas kernel is per-image
+    either way, vmapped over the batch — the shape the chip runs
+    validated).  ``interpret`` runs the kernel in the Pallas interpreter
+    and is only ever what the caller passed: off-TPU, ``backend='pallas'``
+    without it fails to lower ("Only interpret mode is supported on CPU
+    backend") instead of silently interpreting.
     """
     k = alive0.shape[-1]
     if _resolve_backend(backend, k, t) == "pallas":
         from mx_rcnn_tpu.ops.nms_pallas import suppression_sweep_pallas
 
-        # the kernel's tile is capped at 128 independent of the padding
-        # tile: at t=256 the (T, K) IoU slab alone is ~12.3 MB for the
-        # production K=12032 and compiles within 48 KB of the 16 MB scoped
-        # VMEM limit in some surrounding-graph contexts (observed under
-        # jvp(vmap(...))); 128 halves the slab at the same total work.
-        # Greedy NMS results are tile-size-invariant (exact sweep).
-        tp = 128 if t % 128 == 0 else t
+        # at the padding tile of 256 the (T, K) IoU slab alone is ~12.3 MB
+        # for K=12032 and once compiled within 48 KB of the 16 MiB scoped
+        # VMEM limit (under jvp(vmap(...))); the kernel tile halves the
+        # slab at the same total work
+        tp = _KERNEL_TILE if t % _KERNEL_TILE == 0 else t
 
         def pallas_one(bx, al):
-            return suppression_sweep_pallas(
-                bx, al, iou_threshold, tp,
-                interpret=jax.default_backend() != "tpu")
+            return suppression_sweep_pallas(bx, al, iou_threshold, tp,
+                                            interpret=interpret)
 
         if boxes_sorted.ndim == 3:
             return jax.vmap(pallas_one)(boxes_sorted, alive0)
@@ -274,6 +295,7 @@ def _sorted_survivors(
     iou_threshold: float,
     tile_size: int,
     backend: Optional[str] = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, int, int]:
     """Shared preamble of all four entry points: mask invalid scores, pad
     to a tile multiple, sort by score, run the suppression sweep.
@@ -287,12 +309,14 @@ def _sorted_survivors(
     """
     boxes_sorted, order, alive0, pad, t = _mask_pad_sort(
         boxes, scores, valid, tile_size)
-    keep = _run_sweep(boxes_sorted, alive0, iou_threshold, t, backend)
+    keep = _run_sweep(boxes_sorted, alive0, iou_threshold, t, backend,
+                      interpret)
     return order, keep, pad, t
 
 
 @functools.partial(jax.jit, static_argnames=("iou_threshold", "max_output",
-                                             "tile_size", "backend"))
+                                             "tile_size", "backend",
+                                             "interpret"))
 def nms(
     boxes: jnp.ndarray,
     scores: jnp.ndarray,
@@ -301,6 +325,7 @@ def nms(
     valid: Optional[jnp.ndarray] = None,
     tile_size: int = 256,
     backend: Optional[str] = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Greedy NMS; returns up to ``max_output`` surviving indices by score.
 
@@ -319,7 +344,8 @@ def nms(
         return (jnp.full((max_output,), -1, jnp.int32),
                 jnp.zeros((max_output,), bool))
     order, keep, _, t = _sorted_survivors(boxes, scores, valid,
-                                          iou_threshold, tile_size, backend)
+                                          iou_threshold, tile_size, backend,
+                                          interpret)
     # Compact survivors (in score order) into a fixed buffer.
     pos = jnp.cumsum(keep) - 1
     emit = keep & (pos < max_output)
@@ -332,7 +358,7 @@ def nms(
 
 
 @functools.partial(jax.jit, static_argnames=("iou_threshold", "tile_size",
-                                             "backend"))
+                                             "backend", "interpret"))
 def nms_mask(
     boxes: jnp.ndarray,
     scores: jnp.ndarray,
@@ -340,6 +366,7 @@ def nms_mask(
     valid: Optional[jnp.ndarray] = None,
     tile_size: int = 256,
     backend: Optional[str] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Greedy NMS returning a keep mask in the *original* box order.
 
@@ -350,13 +377,14 @@ def nms_mask(
     if k == 0:
         return jnp.zeros((0,), bool)
     order, keep_sorted, pad, _ = _sorted_survivors(
-        boxes, scores, valid, iou_threshold, tile_size, backend)
+        boxes, scores, valid, iou_threshold, tile_size, backend, interpret)
     keep = jnp.zeros((k + pad,), dtype=bool).at[order].set(keep_sorted)
     return keep[:k]
 
 
 @functools.partial(jax.jit, static_argnames=("iou_threshold", "max_output",
-                                             "tile_size", "backend"))
+                                             "tile_size", "backend",
+                                             "interpret"))
 def nms_batch(
     boxes: jnp.ndarray,
     scores: jnp.ndarray,
@@ -365,6 +393,7 @@ def nms_batch(
     valid: Optional[jnp.ndarray] = None,
     tile_size: int = 256,
     backend: Optional[str] = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Cross-image batched :func:`nms`: boxes (B, K, 4), scores (B, K) →
     ((B, max_output) indices, (B, max_output) valid).
@@ -380,7 +409,7 @@ def nms_batch(
         return (jnp.full((b, max_output), -1, jnp.int32),
                 jnp.zeros((b, max_output), bool))
     order, keep, _, t = _sorted_survivors(
-        boxes, scores, valid, iou_threshold, tile_size, backend)
+        boxes, scores, valid, iou_threshold, tile_size, backend, interpret)
     pos = jnp.cumsum(keep, axis=1) - 1
     emit = keep & (pos < max_output)
 
@@ -394,7 +423,7 @@ def nms_batch(
 
 
 @functools.partial(jax.jit, static_argnames=("iou_threshold", "tile_size",
-                                             "backend"))
+                                             "backend", "interpret"))
 def nms_mask_batch(
     boxes: jnp.ndarray,
     scores: jnp.ndarray,
@@ -402,6 +431,7 @@ def nms_mask_batch(
     valid: Optional[jnp.ndarray] = None,
     tile_size: int = 256,
     backend: Optional[str] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Cross-image batched :func:`nms_mask`: (B, K, ...) → (B, K) keep
     mask in original box order.  The eval postprocess flattens its
@@ -411,7 +441,7 @@ def nms_mask_batch(
     if k == 0:
         return jnp.zeros((b, 0), bool)
     order, keep_sorted, pad, _ = _sorted_survivors(
-        boxes, scores, valid, iou_threshold, tile_size, backend)
+        boxes, scores, valid, iou_threshold, tile_size, backend, interpret)
     keep = jax.vmap(
         lambda o, ks: jnp.zeros((k + pad,), dtype=bool).at[o].set(ks)
     )(order, keep_sorted)

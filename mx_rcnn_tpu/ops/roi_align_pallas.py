@@ -77,10 +77,8 @@ from mx_rcnn_tpu.ops.roi_pool import interp_matrices
 # raise the default 16 MiB scoped-VMEM cap: v5e has far more physical
 # VMEM, and the backward's value chain (g block, its transpose, RB fat-dot
 # results, da2, the fp32 accumulator) measured a 2x slowdown when Mosaic
-# spilled it under the default cap.  (``TPUCompilerParams`` is the 0.4.x
-# spelling of the same dataclass.)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_COMPILER_PARAMS = _CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+# spilled it under the default cap.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def _pick_blocks(r: int, c: int) -> Tuple[int, int]:

@@ -33,23 +33,6 @@ from mx_rcnn_tpu.core.train import Batch, TrainState, make_train_step
 from mx_rcnn_tpu.models.faster_rcnn import FasterRCNN
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    New jax exposes ``jax.shard_map`` with ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  Both
-    checks are disabled for the same reason: the RNG fold_in of
-    ``axis_index`` is deliberately replica-varying.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
 def device_mesh(n_devices: Optional[int] = None,
                 devices: Optional[Sequence[jax.Device]] = None,
                 dcn_size: int = 1) -> Mesh:
@@ -175,11 +158,14 @@ def make_dp_train_step(model: FasterRCNN, cfg: Config, tx, mesh: Mesh,
                             grad_accum=grad_accum)
 
     batch_spec = P(axes) if grad_accum <= 1 else P(None, axes)
-    sharded = shard_map_compat(
+    # check_vma off: the RNG fold_in of axis_index is deliberately
+    # replica-varying
+    sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), batch_spec, P()),
         out_specs=(P(), P()),
+        check_vma=False,
     )
     # donate the replicated state: in-place HBM update, no per-step copy
     return jax.jit(sharded, donate_argnums=(0,))
@@ -209,10 +195,11 @@ def make_dp_cached_step(model: FasterRCNN, cfg: Config, tx, mesh: Mesh,
     axes = data_axes(mesh)
     cached = make_cached_step(_folded_step(model, cfg, tx, axes, mode),
                               num_batches, shuffle=shuffle)
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         cached,
         mesh=mesh,
         in_specs=(P(), P(None, axes), P(), P()),
         out_specs=(P(), P(), P()),
+        check_vma=False,  # replica-varying RNG fold, as above
     )
     return jax.jit(sharded, donate_argnums=(0, 2))

@@ -52,10 +52,7 @@ def initialize(coordinator: str, num_processes: int, process_id: int,
         # fails with "Multiprocess computations aren't implemented on the
         # CPU backend".  Gloo ships in jaxlib; must be selected BEFORE
         # the backend initializes (harmless on TPU — guard on platform).
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except AttributeError:  # older jaxlib without the knob
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -24,12 +24,10 @@ the recovery-time lever of elastic training (ROADMAP item 5):
   (``tests/test_fleet.py`` pins the round trip; ``tools/loadgen.py
   --fleet_bench`` re-checks it cross-process).
 
-``enable_compile_cache`` is the shared CLI startup hook
-(tools/train.py / tools/serve.py / tools/fleet.py): it points jax's
-persistent compilation cache at ``cfg.ft.compile_cache_dir`` in the
-LIVE process config AND the child environment, so supervisor relaunches
-(elastic EXIT_RESIZE restarts, crash-loop restarts) inherit the warm
-cache and pay tracing only.
+Where the persistent compilation cache lives is decided by
+``mx_rcnn_tpu/runtime.py — enable_compile_cache``: a store's bundled
+``xla_cache/`` is only the directory used when
+``JAX_COMPILATION_CACHE_DIR`` does not place the cache elsewhere.
 """
 
 from __future__ import annotations
@@ -115,31 +113,6 @@ class ExportMismatch(RuntimeError):
     """The export store's manifest does not match this process's config /
     jax version — loading it would serve programs traced under different
     semantics.  Re-export (``tools/fleet.py export``) instead."""
-
-
-def enable_compile_cache(cache_dir: str, min_compile_s: float = 0.0) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir`` (no-op
-    when empty) — live config AND child env, so subprocesses (elastic
-    relaunches, fleet join benches) inherit it.  Returns True if armed."""
-    if not cache_dir:
-        return False
-    import jax
-
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_s)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # older jax without the knobs
-        logger.warning("persistent compile cache unavailable: %s", e)
-        return False
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = \
-        str(min_compile_s)
-    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
-    logger.info("persistent XLA compilation cache: %s", cache_dir)
-    return True
 
 
 def _spec_of(tree) -> Any:
@@ -468,7 +441,7 @@ class ExportStore:
         """Deserialize one program and wrap it in ``jax.jit`` so repeat
         calls dispatch through the compiled-executable cache.  The first
         call compiles the StableHLO — a persistent-cache READ when the
-        bundled ``xla_cache/`` is armed (``enable_compile_cache``)."""
+        bundled ``xla_cache/`` is armed (``runtime.enable_compile_cache``)."""
         import jax
         from jax import export as jexport
 
@@ -533,7 +506,7 @@ def export_serve_programs(predictor, cfg, root: str, *,
     pin each exported program's outputs BIT-EQUAL to the live-traced
     program on deterministic inputs.  The verify pass doubles as the
     persistent-cache population step: run it with
-    ``enable_compile_cache(store.cache_dir())`` armed and a joining
+    ``runtime.enable_compile_cache(store.cache_dir())`` armed and a joining
     replica's compiles become cache reads.
 
     Lineage (docs/SERVING.md "Rollout tier"): ``version`` stamps the
@@ -697,9 +670,9 @@ def export_train_step(cfg, *, out_dir: str, num_devices: int = 1,
     program, not the buffer-aliasing policy) — it is the
     scheduler-shippable program artifact and the persistent-cache
     pre-warmer, not a drop-in replacement for the fit loop's donating
-    step.  The compile-skip on restart comes from
-    ``ft.compile_cache_dir`` (``enable_compile_cache``); docs/FT.md
-    "Recovery time" has the measured deltas.
+    step.  The compile-skip on restart comes from the persistent cache
+    ``tools/train.py`` arms at start-up (``runtime.enable_compile_cache``);
+    docs/FT.md "Recovery time" has the measured deltas.
     """
     import jax
 

@@ -932,9 +932,10 @@ def partition_devices(n_replicas: int, devices: Sequence = None,
                       per_replica: int = 0) -> List[List]:
     """Split the device inventory into per-replica subsets.  Disjoint
     slices while the supply lasts; replicas beyond it wrap around and
-    SHARE devices (the 1-core CPU tier runs every replica on the same
-    device — throughput then validates the router, not the silicon;
-    docs/SERVING.md "Fleet tier" is explicit about which is which)."""
+    SHARE devices — logged, because throughput then validates the router,
+    not the silicon (the 1-core CPU tier runs every replica on the same
+    device; docs/SERVING.md "Fleet tier" is explicit about which is
+    which)."""
     import jax
 
     devices = list(devices if devices is not None else jax.devices())
@@ -944,6 +945,11 @@ def partition_devices(n_replicas: int, devices: Sequence = None,
     if per_replica <= 0:
         per_replica = max(d // n_replicas, 1)
     per_replica = min(per_replica, d)
+    if n_replicas * per_replica > d:
+        logger.warning(
+            "fleet: %d replicas x %d device(s) over %d device(s) — replica "
+            "subsets wrap around and SHARE devices; rates from this fleet "
+            "are not per-device rates", n_replicas, per_replica, d)
     return [[devices[(i * per_replica + j) % d]
              for j in range(per_replica)] for i in range(n_replicas)]
 
@@ -965,14 +971,14 @@ def make_engine_build_fn(cfg: Config, model, variables, *,
         from mx_rcnn_tpu.parallel.dp import device_mesh
 
         sub = subsets[rid % len(subsets)]
-        if export_root:
-            # exported programs are nr_devices=1 modules: an export-warm
-            # replica runs single-device, PLACED on its subset's first
-            # device via a 1-device mesh (per-chip placement on real
-            # hardware); mesh-sharded replicas are a trace-warm feature
-            mesh = device_mesh(devices=sub[:1]) if len(sub) > 1 else None
-        else:
-            mesh = device_mesh(devices=sub) if len(sub) > 1 else None
+        # always a mesh, even over ONE device: the mesh is what places the
+        # replica's variables and batches on ITS device — without one the
+        # Predictor commits to the default device and four one-chip
+        # replicas all land on device 0.  Exported programs are
+        # nr_devices=1 modules, so an export-warm replica runs on its
+        # subset's first device; mesh-sharded replicas are a trace-warm
+        # feature.
+        mesh = device_mesh(devices=sub[:1] if export_root else sub)
         run_fn = run_fn_factory(rid) if run_fn_factory else None
         predictor = Predictor(model, variables, cfg, mesh=mesh)
         engine = ServingEngine(predictor, cfg, run_fn=run_fn)

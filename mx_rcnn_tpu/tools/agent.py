@@ -83,8 +83,8 @@ def main(argv=None) -> int:
     if cfg.fleet.export_dir and args.stub_ms is None:
         import os
 
-        from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                              enable_compile_cache)
+        from mx_rcnn_tpu.runtime import enable_compile_cache
+        from mx_rcnn_tpu.serve.export import CACHE_SUBDIR
 
         # warm through the store's bundled XLA cache — the pulled store
         # carries it, so the join pays deserialize + cache read, not a

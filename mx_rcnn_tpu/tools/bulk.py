@@ -176,8 +176,8 @@ def run_single(args, cfg) -> int:
     from mx_rcnn_tpu.obs.runrec import cli_obs
     from mx_rcnn_tpu.serve.bulk import (BulkRunner, BulkSink, auto_inflight,
                                         make_sink_manifest)
+    from mx_rcnn_tpu.runtime import enable_compile_cache
     from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR, ExportStore,
-                                          enable_compile_cache,
                                           export_serve_programs)
     from mx_rcnn_tpu.serve.fleet import build_fleet
     from mx_rcnn_tpu.tools.data_bench import _vm_peak_mb
@@ -355,8 +355,8 @@ def run_kill_resume(args, cfg) -> int:
     they share one export store and one materialized corpus."""
     from mx_rcnn_tpu.obs.runrec import cli_obs
     from mx_rcnn_tpu.serve.bulk import BulkSink
+    from mx_rcnn_tpu.runtime import enable_compile_cache
     from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                          enable_compile_cache,
                                           export_serve_programs)
     from mx_rcnn_tpu.tools.loadgen import init_predictor
 

@@ -238,8 +238,8 @@ def _run_prepared_closed(target, prepared, duration_s: float,
 def run_crosshost_bench(args) -> int:
     from mx_rcnn_tpu.analysis import sanitizer
     from mx_rcnn_tpu.serve.agent import make_store_server
+    from mx_rcnn_tpu.runtime import enable_compile_cache
     from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                          enable_compile_cache,
                                           export_serve_programs)
     from mx_rcnn_tpu.serve.remote import (RemoteEngine,
                                           build_crosshost_router)

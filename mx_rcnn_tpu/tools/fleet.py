@@ -108,8 +108,8 @@ def _init_predictor(cfg, args):
 
 
 def cmd_export(args) -> int:
+    from mx_rcnn_tpu.runtime import enable_compile_cache
     from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                          enable_compile_cache,
                                           export_serve_programs)
 
     cfg = _config(args)
@@ -145,16 +145,15 @@ def cmd_serve(args) -> int:
         cfg = cfg.replace_in("fleet", replicas=args.replicas)
     export_dir = (cfg.fleet.export_dir if args.export_dir is None
                   else args.export_dir)
-    if export_dir:
-        from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                              enable_compile_cache)
-        import os
+    import os
 
-        enable_compile_cache(os.path.join(export_dir, CACHE_SUBDIR))
-    elif cfg.ft.compile_cache_dir:
-        from mx_rcnn_tpu.serve.export import enable_compile_cache
+    from mx_rcnn_tpu.runtime import enable_compile_cache
+    from mx_rcnn_tpu.serve.export import CACHE_SUBDIR
 
-        enable_compile_cache(cfg.ft.compile_cache_dir)
+    # an export store bundles its own cache; without one the fixed
+    # in-checkout cache serves restarts (runtime.py has the rule)
+    enable_compile_cache(os.path.join(export_dir, CACHE_SUBDIR)
+                         if export_dir else None)
 
     from mx_rcnn_tpu.obs.runrec import cli_obs
 
@@ -231,18 +230,16 @@ def cmd_join_bench(args) -> int:
     if args.mode == "export":
         if not args.export_dir:
             raise SystemExit("--mode export requires --export_dir")
-        from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                              enable_compile_cache)
         import os
+
+        from mx_rcnn_tpu.runtime import enable_compile_cache
+        from mx_rcnn_tpu.serve.export import CACHE_SUBDIR
 
         enable_compile_cache(os.path.join(args.export_dir, CACHE_SUBDIR))
     else:
-        # the trace-warm baseline must not read a cache some earlier run
-        # populated (tests export JAX_COMPILATION_CACHE_DIR process-wide)
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
+        # the trace-warm baseline measures tracing + compiling: it must
+        # not read a cache some earlier run populated
+        jax.config.update("jax_enable_compilation_cache", False)
 
     from mx_rcnn_tpu.obs.runrec import cli_obs
     from mx_rcnn_tpu.serve.engine import ServingEngine

@@ -415,8 +415,8 @@ def run_fleet_bench(args) -> int:
     acceptance invariants into the exit code for ``make fleet-smoke``."""
     import tempfile
 
+    from mx_rcnn_tpu.runtime import enable_compile_cache
     from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                          enable_compile_cache,
                                           export_serve_programs)
     from mx_rcnn_tpu.serve.metrics import LoweringCounter
 

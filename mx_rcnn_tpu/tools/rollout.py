@@ -212,8 +212,8 @@ def _check_exactly_once(name: str, leg: Dict,
 
 def run_rollout_bench(args) -> int:
     from mx_rcnn_tpu.analysis import sanitizer
+    from mx_rcnn_tpu.runtime import enable_compile_cache
     from mx_rcnn_tpu.serve.export import (CACHE_SUBDIR,
-                                          enable_compile_cache,
                                           export_serve_programs)
     from mx_rcnn_tpu.serve.remote import build_crosshost_router
     from mx_rcnn_tpu.serve.rollout import (DONE, ROLLED_BACK,

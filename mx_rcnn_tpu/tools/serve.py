@@ -58,13 +58,12 @@ def main(argv=None):
     args = parse_args(argv)
     cfg = generate_config(args.network, args.dataset,
                           **parse_set_overrides(args))
-    if cfg.ft.compile_cache_dir:
-        # persistent XLA cache: a restarted server's warmup pays
-        # tracing only (docs/FT.md "Recovery-time levers"; the fleet
-        # CLI's export stores bundle their own cache instead)
-        from mx_rcnn_tpu.serve.export import enable_compile_cache
+    # persistent XLA cache: a restarted server's warmup pays tracing
+    # only (docs/FT.md "Recovery-time levers"; the fleet CLI's export
+    # stores bundle their own cache instead)
+    from mx_rcnn_tpu.runtime import enable_compile_cache
 
-        enable_compile_cache(cfg.ft.compile_cache_dir)
+    enable_compile_cache()
     # observability (docs/OBSERVABILITY.md): publish serving metrics into
     # the PROCESS registry (so /metrics is the unified scrape), write a
     # runs/<id>/ record, optionally collect spans / arm SIGUSR2.  CliObs

@@ -11,6 +11,7 @@ import argparse
 import logging
 from typing import Dict
 
+from mx_rcnn_tpu import runtime
 from mx_rcnn_tpu.config import Config, generate_config
 from mx_rcnn_tpu.core.tester import Predictor, pred_eval
 from mx_rcnn_tpu.data import TestLoader, load_gt_roidb
@@ -115,6 +116,7 @@ def main(argv=None):
         overrides["dataset__dataset_path"] = args.dataset_path
     overrides.update(parse_set_overrides(args))
     cfg = generate_config(args.network, args.dataset, **overrides)
+    runtime.log_runtime(runtime.enable_compile_cache())
     test_rcnn(cfg, prefix=args.prefix, epoch=args.epoch,
               image_set=args.image_set, out_dir=args.out_dir,
               save_dets=args.save_dets, num_devices=args.num_devices)

@@ -19,6 +19,7 @@ import logging
 
 import jax
 
+from mx_rcnn_tpu import runtime
 from mx_rcnn_tpu.config import Config, generate_config
 from mx_rcnn_tpu.core.fit import fit
 from mx_rcnn_tpu.core.train import setup_training
@@ -535,10 +536,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "recipe into DIR (serve/export.py — "
                         "export_train_step: jax.export program + "
                         "manifest, verified bit-equal to the live "
-                        "trace) and exit.  With ft.compile_cache_dir "
-                        "set, the export's verify pass also pre-warms "
-                        "the persistent cache the next (re)start reads "
-                        "— docs/FT.md 'Recovery time'")
+                        "trace) and exit.  The export's verify pass "
+                        "also pre-warms the persistent cache the next "
+                        "(re)start reads — docs/FT.md 'Recovery time'")
     return p.parse_args(argv)
 
 
@@ -567,14 +567,15 @@ def main(argv=None):
                     "devices", jax.process_index(), jax.process_count(),
                     jax.local_device_count(), jax.device_count())
     cfg = config_from_args(args)
-    # persistent XLA compile cache (ROADMAP item 5 recovery-time lever,
-    # docs/FT.md "Recovery time"): armed BEFORE any compile, in the live
-    # config AND the child env — elastic EXIT_RESIZE relaunches and
-    # crash-loop restarts inherit it and pay tracing only
-    if cfg.ft.compile_cache_dir:
-        from mx_rcnn_tpu.serve.export import enable_compile_cache
-
-        enable_compile_cache(cfg.ft.compile_cache_dir)
+    # persistent XLA compile cache, armed BEFORE any compile: a restart
+    # (elastic EXIT_RESIZE relaunch, crash-loop attempt, the next chip
+    # call) reads the step instead of recompiling it (docs/FT.md
+    # "Recovery time").  Not for jax.distributed workers: ranks that hit
+    # the cache race ahead of ranks that compile and the collective-init
+    # barrier times the stragglers out — there every rank compiles.
+    cache_dir = ("(off: multi-process)" if multiproc
+                 else runtime.enable_compile_cache())
+    runtime.log_runtime(cache_dir)
     if args.export_train_step:
         from mx_rcnn_tpu.serve.export import export_train_step
 

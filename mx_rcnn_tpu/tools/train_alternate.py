@@ -18,7 +18,7 @@ available (this machine cannot download one), stage 2 initializes FRESH by
 default — closer in spirit to the reference (stage 2 starts from generic
 weights, never from the stage-1 RPN-specialized ones) than round 2's
 rpn1-checkpoint fallback.  Round-3 ablations
-(``script/ablate_alternate.py``, ``docs/ROUND3.md``) found the two inits
+(``script/ablate_alternate.py``) found the two inits
 statistically indistinguishable across seeds (means 0.87 both) and showed
 the round-2 "alternate vs e2e mAP gap" was run-to-run seed variance of the
 small synthetic eval, not a schedule defect; ``--stage2_init rpn1`` keeps

@@ -157,7 +157,7 @@ def test_alternate_four_stages_and_combine(tmp_path):
 
 def test_stage2_init_knob(tmp_path):
     """stage2_init='rpn1' must seed stage 2 from the rpn1 backbone;
-    the default 'fresh' must not (docs/ROUND3.md item-5 ablation)."""
+    the default 'fresh' must not (script/ablate_alternate.py)."""
     cfg = _cfg(tmp_path)
     prefix = str(tmp_path / "model" / "alt2")
     alternate_train(cfg, prefix=prefix, rpn_epoch=1, rcnn_epoch=1,

@@ -126,6 +126,49 @@ def test_partition_devices_shares_scarce_supply():
         partition_devices(0, devices=devs)
 
 
+def test_partition_devices_logs_when_replicas_share(caplog):
+    """Wrapping onto shared devices is said out loud; disjoint subsets
+    are silent."""
+    import logging
+
+    with caplog.at_level(logging.WARNING, logger="mx_rcnn_tpu"):
+        partition_devices(2, devices=["d0", "d1", "d2", "d3"])
+        assert "SHARE devices" not in caplog.text
+        partition_devices(3, devices=["d0", "d1"])
+        assert "SHARE devices" in caplog.text
+
+
+def test_one_device_replicas_live_on_their_own_devices(predictor):
+    """Four one-device replicas on a four-device host: each replica's
+    variables AND its batch placement sit on ITS device, not all on the
+    default device (a one-device subset used to get mesh=None, and the
+    Predictor then committed everything to device 0)."""
+    import jax
+
+    from mx_rcnn_tpu.serve.fleet import make_engine_build_fn
+
+    devices = jax.devices()[:4]
+    cfg = _fleet_cfg(replicas=4, fleet__devices_per_replica=1)
+    build = make_engine_build_fn(
+        cfg, predictor.model, predictor.variables, devices=devices,
+        run_fn_factory=_Gate().factory(cfg))
+    var_devs, batch_devs = [], []
+    for rid in range(4):
+        engine, join = build(rid)
+        try:
+            p = engine.predictor
+            on = {d for leaf in jax.tree.leaves(p.variables)
+                  for d in leaf.devices()}
+            assert len(on) == 1
+            var_devs.append(on.pop())
+            batch_devs.append(next(iter(p._batch_sharding.device_set)))
+            assert join["devices"] == 1
+        finally:
+            engine.close()
+    assert var_devs == list(devices)
+    assert batch_devs == list(devices)
+
+
 # ---------------------------------------------------------------------------
 # AOT export: round trip, admission checks, corruption
 # ---------------------------------------------------------------------------

@@ -246,3 +246,33 @@ def test_iou_matrix_matches_pairwise(backend, monkeypatch):
     assert native.iou_matrix(dts, []).shape == (5, 0)
     with pytest.raises(ValueError, match="crowd flags"):
         native.iou_matrix(dts, gts, [True])
+
+
+def test_build_trusts_content_digest_not_file_times(tmp_path, monkeypatch,
+                                                    has_native):
+    """A library whose stamp does not match these sources (copied in from
+    another tree, sources edited since) is rebuilt even when its mtime is
+    newest; one built from exactly these sources is kept."""
+    if not has_native:
+        pytest.skip("no C++ toolchain")
+    import os
+
+    lib = tmp_path / "libmxrcnn_native.so"
+    stamp = tmp_path / "libmxrcnn_native.so.srchash"
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(native, "_STAMP_PATH", str(stamp))
+
+    # a foreign library, newer than every source, no stamp
+    lib.write_bytes(b"not an ELF file")
+    assert native.build()
+    assert lib.read_bytes()[:4] == b"\x7fELF"
+    # same sources: kept, even when a copy left the library OLDER than
+    # its sources (the mtime rule would rebuild; a rebuild resets mtime)
+    os.utime(lib, ns=(1, 1))
+    assert native.build()
+    assert os.stat(lib).st_mtime_ns == 1
+    # stamp of other sources: rebuilt
+    stamp.write_text("0" * 64 + "\n")
+    lib.write_bytes(b"stale")
+    assert native.build()
+    assert lib.read_bytes()[:4] == b"\x7fELF"

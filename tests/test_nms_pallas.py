@@ -1,8 +1,8 @@
 """Pallas NMS kernel vs the jnp suppression sweep (the oracle).
 
 The kernel must reproduce sequential greedy NMS decision-for-decision; on
-CPU it runs under interpret=True (correctness only — the perf claim is
-checked on real TPU by tools/profile_step.py / bench.py).
+CPU it runs only when the caller says interpret=True (correctness only —
+compiled parity at K=6144/12032 is checked on the chip by chip_smoke.py).
 """
 
 import jax.numpy as jnp
@@ -25,7 +25,8 @@ def test_pallas_matches_jnp_nms_mask(k, tile):
     rng = np.random.RandomState(k)
     boxes, scores = _rand(rng, k)
     want = nms_mask(boxes, scores, 0.5, tile_size=tile, backend="jnp")
-    got = nms_mask(boxes, scores, 0.5, tile_size=tile, backend="pallas")
+    got = nms_mask(boxes, scores, 0.5, tile_size=tile, backend="pallas",
+                   interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -36,7 +37,7 @@ def test_pallas_matches_jnp_nms_indices():
     want_i, want_v = nms(boxes, scores, 0.7, 100, valid=valid,
                          tile_size=128, backend="jnp")
     got_i, got_v = nms(boxes, scores, 0.7, 100, valid=valid,
-                       tile_size=128, backend="pallas")
+                       tile_size=128, backend="pallas", interpret=True)
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
 
@@ -54,7 +55,8 @@ def test_pallas_dense_cluster():
     boxes = jnp.asarray(np.asarray(boxes, np.float32))
     scores = jnp.asarray(rng.uniform(size=len(boxes)).astype(np.float32))
     want = nms_mask(boxes, scores, 0.5, tile_size=128, backend="jnp")
-    got = nms_mask(boxes, scores, 0.5, tile_size=128, backend="pallas")
+    got = nms_mask(boxes, scores, 0.5, tile_size=128, backend="pallas",
+                   interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -75,19 +77,51 @@ def test_set_nms_backend_validation():
 
 
 def test_resolve_backend_guards(monkeypatch):
-    """Auto selection requires TPU + 128-lane-aligned tiles + a bounded
-    (T, K) VMEM slab; anything else falls back to jnp."""
+    """On a TPU 'auto' is the kernel, and an input the kernel cannot take
+    is an error, never a quiet jnp; off-TPU 'auto' is the jnp sweep."""
     import importlib
 
     nms_mod = importlib.import_module("mx_rcnn_tpu.ops.nms")
     monkeypatch.setattr(nms_mod.jax, "default_backend", lambda: "tpu")
     r = nms_mod._resolve_backend
-    assert r(None, 12032, 256) == "pallas"      # production proposal shape
+    assert r(None, 6144, 256) == "pallas"       # the 6000-box recipe
+    assert r(None, 12032, 256) == "pallas"      # the 12000-box recipe
     assert r(None, 512, 128) == "pallas"
-    assert r(None, 500, 100) == "jnp"           # tile not lane-aligned
-    assert r(None, 513, 128) == "jnp"           # K not a tile multiple
-    assert r(None, 40000, 256) == "jnp"         # slab over the VMEM guard
-    assert r("jnp", 12032, 256) == "jnp"        # explicit override wins
-    assert r("pallas", 500, 100) == "pallas"    # explicit override wins
+    assert r(None, 200, 200) == "jnp"           # smaller than one tile
+    with pytest.raises(ValueError, match="cannot run in the Pallas"):
+        r(None, 500, 100)                       # tile not lane-aligned
+    with pytest.raises(ValueError, match="cannot run in the Pallas"):
+        r(None, 40192, 256)                     # past the VMEM bound
+    assert r("jnp", 12032, 256) == "jnp"        # explicit choice wins
+    assert r("pallas", 500, 100) == "pallas"    # explicit choice wins
     monkeypatch.setattr(nms_mod.jax, "default_backend", lambda: "cpu")
     assert r(None, 12032, 256) == "jnp"         # no TPU -> jnp
+
+
+def test_pallas_off_tpu_needs_explicit_interpret():
+    """backend='pallas' on a CPU host raises unless the caller asked for
+    the interpreter — it is never inferred from the platform."""
+    boxes, scores = _rand(np.random.RandomState(0), 256)
+    with pytest.raises(ValueError, match="interpret mode"):
+        nms_mask(boxes, scores, 0.5, tile_size=128, backend="pallas")
+
+
+@pytest.mark.parametrize("k", [6144, 12032])
+def test_sweep_kernel_lowers_for_tpu_at_recipe_shapes(k):
+    """The compiled (not interpreted) kernel lowers for the TPU platform
+    at both recipe shapes, plain and under the train step's vmap, to
+    exactly one Mosaic custom call.  Lowering runs on CPU; what libtpu
+    does with the call is chip_smoke.py's to check."""
+    import jax
+
+    from mx_rcnn_tpu.ops.nms_pallas import suppression_sweep_pallas
+
+    def sweep(b, a):
+        return suppression_sweep_pallas(b, a, 0.7, 128, interpret=False)
+
+    for fn, lead in ((sweep, ()), (jax.vmap(sweep), (2,))):
+        text = jax.jit(fn).trace(
+            jax.ShapeDtypeStruct(lead + (k, 4), jnp.float32),
+            jax.ShapeDtypeStruct(lead + (k,), jnp.bool_),
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 1

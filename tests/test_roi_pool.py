@@ -192,18 +192,57 @@ def test_roi_align_pallas_rois_grad_is_explicit_zeros():
 from mx_rcnn_tpu.ops.roi_pool import roi_align_batched, roi_align_blocked
 
 
+def test_roi_align_pallas_lowers_for_tpu_at_production_shape():
+    """Forward and backward kernels lower for the TPU platform at the
+    ResNet-101 training shape — (2, 38, 64, 1024) bf16 features, 128
+    ROIs/image, 14x14 pool — to exactly one Mosaic custom call each.
+    Lowering runs on CPU; the on-chip compile outcome is recorded in
+    ROADMAP.md D3."""
+    from mx_rcnn_tpu.ops.roi_align_pallas import roi_align_pallas
+
+    feat = jax.ShapeDtypeStruct((2, 38, 64, 1024), jnp.bfloat16)
+    rois = jax.ShapeDtypeStruct((2, 128, 4), jnp.float32)
+
+    def fwd(f, r):
+        return roi_align_pallas(f, r, (14, 14), 1 / 16.0, 2, False)
+
+    def bwd(f, r, g):
+        return jax.vjp(lambda x: fwd(x, r), f)[1](g)[0]
+
+    cot = jax.ShapeDtypeStruct((2, 128, 14, 14, 1024), jnp.bfloat16)
+    for fn, args in ((fwd, (feat, rois)), (bwd, (feat, rois, cot))):
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 1
+
+
+def _assert_within_ulps(got, want, ulps=2):
+    """fp32 agreement to ``ulps`` units in the last place of the LARGEST
+    output magnitude.  The blocked path runs the same two einsums on
+    ROI-chunked operands; jaxlib 0.9.0's CPU backend picks its dot
+    reduction order per operand shape, so the same products are summed in
+    another association (measured: exactly 1 ulp at the output scale,
+    2.4e-7 on O(1) values) — bit-equality was a property of jaxlib
+    0.4.37's CPU dot, not of the math.  Two ulps still fails any change
+    of weights, padding or chunk bookkeeping, which moves values by far
+    more."""
+    got, want = np.asarray(got), np.asarray(want)
+    atol = ulps * np.spacing(np.float32(np.abs(want).max()))
+    np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+
+
 @pytest.mark.parametrize("r,chunk", [(13, 4), (8, 8), (5, 64), (1, 4)])
 def test_roi_align_blocked_forward_bit_equal_fp32(r, chunk):
-    """Odd ROI counts vs chunk size: forward must be BIT-equal to the
-    einsum pair, including when padding rounds R up and when one chunk
-    covers everything."""
+    """Odd ROI counts vs chunk size: forward must match the einsum pair
+    to the last place (see ``_assert_within_ulps``), including when
+    padding rounds R up and when one chunk covers everything."""
     rng = np.random.RandomState(0)
     feat = jnp.asarray(rng.randn(19, 32, 16).astype(np.float32))
     rois = jnp.asarray(_rand_rois(rng, 1, r, 19 * 16, 32 * 16)[0])
     want = roi_align(feat, rois, (7, 7), 1 / 16.0)
     got = roi_align_blocked(feat, rois, (7, 7), 1 / 16.0, 2, chunk)
     assert got.dtype == want.dtype
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_within_ulps(got, want)
 
 
 def test_roi_align_blocked_forward_bit_equal_bf16():
@@ -276,8 +315,9 @@ def test_roi_align_blocked_grads_close_random():
 
 
 def test_roi_align_blocked_single_chunk_grads_bit_equal_random():
-    """chunk >= R is ONE chunk of the identical einsums — grads bit-equal
-    even on random vectors (no cross-chunk accumulation exists)."""
+    """chunk >= R is ONE chunk of the identical einsums — grads agree to
+    the last place even on random vectors (no cross-chunk accumulation
+    exists; see ``_assert_within_ulps``)."""
     rng = np.random.RandomState(4)
     feat = jnp.asarray(rng.randn(12, 20, 8).astype(np.float32))
     rois = jnp.asarray(_rand_rois(rng, 1, 7, 12 * 16, 20 * 16)[0])
@@ -286,7 +326,7 @@ def test_roi_align_blocked_single_chunk_grads_bit_equal_random():
         roi_align(f, rois, (7, 7), 1 / 16.0) * cot))(feat)
     g_blk = jax.grad(lambda f: jnp.sum(
         roi_align_blocked(f, rois, (7, 7), 1 / 16.0, 2, 64) * cot))(feat)
-    np.testing.assert_array_equal(np.asarray(g_ein), np.asarray(g_blk))
+    _assert_within_ulps(g_blk, g_ein)
 
 
 def test_roi_align_blocked_rois_grad_is_explicit_zeros():

@@ -460,12 +460,24 @@ def test_loadgen_smoke_checks_pass(capsys):
     assert rec["shed_rate"] == 0.0  # closed loop cannot overrun the queue
 
 
-def test_loadgen_open_loop_sheds_gracefully_when_overdriven(capsys):
+def test_loadgen_open_loop_sheds_gracefully_when_overdriven(capsys,
+                                                            monkeypatch):
     """Open-loop arrivals far past capacity must terminate EVERY request
     (served, shed, or expired — none lost, none failed) with a tight
-    admission queue — overload degrades by rejection, not collapse."""
-    from mx_rcnn_tpu.tools.loadgen import main
+    admission queue — overload degrades by rejection, not collapse.
 
+    The service time is a stub (50 ms per dispatched batch of 2, so at
+    most 80 imgs/s over the two bucket lanes): "400 qps overdrives the
+    engine" must hold by construction, not because the host happens to
+    be slow — on a fast host the real tiny model served all 574 of 574
+    and nothing shed."""
+    from mx_rcnn_tpu.tools import loadgen
+    from mx_rcnn_tpu.tools.loadgen import main, make_stub_run_fn
+
+    monkeypatch.setattr(
+        loadgen, "ServingEngine",
+        lambda predictor, cfg: ServingEngine(
+            predictor, cfg, run_fn=make_stub_run_fn(cfg, model_ms=50.0)))
     rc = main(["--smoke", "--mode", "open", "--duration", "2",
                "--qps", "400", "--timeout_ms", "250",
                "--set", "serve__queue_depth=8",
@@ -474,7 +486,7 @@ def test_loadgen_open_loop_sheds_gracefully_when_overdriven(capsys):
     assert rc == 0
     assert rec["lost"] == 0 and rec["failed"] == 0
     assert rec["submitted"] == rec["served"] + rec["shed"] + rec["expired"]
-    # at 400 qps against a ~300 imgs/s engine with a depth-4 watermark,
+    # at 400 qps against a <=80 imgs/s engine with a depth-4 watermark,
     # admission control MUST have engaged
     assert rec["shed"] + rec["expired"] > 0, rec
     assert rec["recompiles_after_warmup"] == 0

@@ -197,7 +197,7 @@ class TestAbsentEqualsDown:
             assert never[k] == downed[k]
         assert never["action"] == "add"
 
-    def test_run_check_reports_never_up_sources(self):
+    def test_run_check_lists_never_up_sources(self):
         from mx_rcnn_tpu.tools.obs import run_check
         cfg = generate_config("tiny", "synthetic")
         reg = Registry()
