@@ -1,0 +1,413 @@
+"""The benchmark's own arithmetic and its files: window edges and rate,
+trace reduction, operation counts, manifest <-> files, and the refusal to
+run without the chip."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+from bench_tiny import ROOT
+from benchmark import flops, trace, window
+from benchmark import run as bench_run
+
+BENCH = bench_run.manifest()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+# ---- window arithmetic ---------------------------------------------------
+
+LOGS = [(10.0, 20), (14.0, 40), (18.0, 60), (22.5, 80), (26.5, 100),
+        (30.5, 120), (34.5, 140), (38.5, 160), (42.5, 180)]
+
+
+def _feed(edges):
+    return [edges.add(t, step) for t, step in LOGS]
+
+
+def test_window_opens_after_warmup_and_closes_after_seconds():
+    edges = window.Edges(warmup_steps=40, seconds=12.0, traced=False)
+    assert _feed(edges) == [None, None, None, None, "close",
+                            None, None, None, None]
+    assert (edges.opened, edges.closed) == (1, 4)
+    never = window.Edges(warmup_steps=40, seconds=100.0, traced=False)
+    assert set(_feed(never)) == {None} and never.closed is None
+    cold = window.Edges(warmup_steps=1000, seconds=1.0, traced=False)
+    assert set(_feed(cold)) == {None} and cold.opened is None
+
+
+def test_traced_run_traces_two_intervals_then_opens_its_window():
+    edges = window.Edges(warmup_steps=40, seconds=7.0, traced=True)
+    assert _feed(edges) == [None, "start_trace", None, "stop_trace", None,
+                            None, "close", None, None]
+    assert (edges.trace_from, edges.trace_to) == (1, 3)
+    # the interval after the trace absorbs its write-out: the window opens
+    # at that interval's end
+    assert (edges.opened, edges.closed) == (4, 6)
+
+
+def test_window_rate_is_all_images_over_all_time():
+    edges = window.Edges(warmup_steps=40, seconds=12.0, traced=False)
+    _feed(edges)
+    stats = edges.stats(images_per_step=16)
+    assert stats["steps"] == 60
+    assert stats["seconds"] == pytest.approx(12.5)
+    assert stats["imgs_per_s"] == pytest.approx(60 * 16 / 12.5)
+    # the slow stretch (4.5 s for 20 steps) is in the rate and is the slowest
+    assert stats["slowest_ms_per_step"] == pytest.approx(225.0)
+    assert stats["mean_ms_per_step"] == pytest.approx(12.5 / 60 * 1e3)
+
+
+# ---- trace reduction -----------------------------------------------------
+
+def test_union_and_gaps():
+    spans = [(0, 10), (5, 12), (20, 30), (22, 25)]
+    assert trace.union_ns(spans) == 22
+    assert trace.gaps(spans) == [(12, 20)]
+
+
+def _recorded():
+    with open(os.path.join(ROOT, "tests", "benchmark", "data",
+                           "small_trace.json")) as f:
+        return json.load(f)
+
+
+def test_reduction_of_a_recorded_trace():
+    neutral = _recorded()
+    dev = neutral["devices"][0]
+    red = trace.Reduced(neutral, steps=neutral["steps"], chips=1)
+    # the window runs from the start of one execution of the step program
+    # to the start of the one ``steps`` later, the last such pair
+    starts = sorted(s for name, s, _ in dev["programs"]
+                    if name.startswith("jit_step"))
+    assert red.steps == neutral["steps"] == len(starts) - 1
+    assert red.window_s == pytest.approx((starts[-1] - starts[0]) * 1e-9)
+    inside = [o for o in dev["ops"] if starts[0] <= o[2] < starts[-1]]
+    busy = red.busy_s()
+    # ops on one device never overlap by more than rounding: the union is
+    # within a part in a thousand of the plain sum, and inside the window
+    assert busy == pytest.approx(sum(o[3] for o in inside) * 1e-9, rel=1e-3)
+    assert 0 < busy <= red.window_s
+    assert red.scope_s("backbone") > red.scope_s("proposal") > 0
+    assert red.scope_s("no_such_scope") is None
+    top = red.top_ops(10)
+    assert len(top) == 10 and top[0][1] >= top[-1][1] > 0
+    idle = sum(sec for _, sec in red.idle_gaps(10))
+    assert idle == pytest.approx(red.window_s - busy, rel=1e-6)
+
+
+def test_window_is_cut_from_the_step_programs_last_executions():
+    programs = [["jit_step(7)", 100.0 * i, 90.0] for i in range(1, 8)]
+    programs += [["jit_fetch(9)", 250.0, 5.0], ["jit_fetch(9)", 650.0, 5.0]]
+    assert trace.cut_window(programs, steps=3) == (400.0, 700.0, 3)
+    # fewer executions than asked for: what the trace holds
+    assert trace.cut_window(programs[:3], steps=20) == (100.0, 300.0, 2)
+    assert trace.cut_window(programs[:1], steps=20) is None
+    assert trace.cut_window([], steps=20) is None
+    ops = [["op", "jit(step)/jvp(backbone)/x", 100.0 * i + 10.0, 50.0]
+           for i in range(1, 8)]
+    red = trace.Reduced({"devices": [{"name": "d", "ops": ops,
+                                      "programs": programs}]},
+                        steps=3, chips=1)
+    assert (red.steps, red.window_s) == (3, pytest.approx(300e-9))
+    assert red.busy_s() == pytest.approx(150e-9)
+    assert red.scope_s("backbone") == pytest.approx(150e-9)
+
+
+def test_named_scopes_are_read_from_the_programs_in_a_trace(tmp_path):
+    """The device's op events name HLO instructions; their named scopes are
+    in the compiled programs the profiler stores beside them."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import xplane
+
+    @jax.jit
+    def f(x):
+        with jax.named_scope("backbone"):
+            y = jnp.tanh(x @ x)
+        with jax.named_scope("bench_target_zz"):
+            return jnp.sort(y, axis=-1)
+
+    x = jnp.ones((64, 64))
+    # compiled here and now: a CPU executable read back from the persistent
+    # cache has lost the name stacks of its instructions
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        f(x).block_until_ready()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    pb, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    programs = xplane.read_hlo_programs(pb)
+    mine, = [p for p in programs
+             if any("bench_target_zz" in v for v in p.values())]
+    # instruction names repeat between programs: the one picked is the one
+    # the events seen come from, however large the others are
+    bigger = {f"{name}.other": "" for name in mine} | {
+        name: "jit(g)/elsewhere" for name in list(mine)[:3]}
+    paths = trace.pick_program(programs + [bigger], set(mine))
+    assert paths is mine
+    ops = [[name, path, 10.0 * i, 5.0] for i, (name, path)
+           in enumerate(sorted(paths.items()))]
+    programs = [["jit_f(1)", 0.0, 1.0], ["jit_f(1)", 10.0 * len(ops), 1.0]]
+    red = trace.Reduced({"devices": [{"name": "d", "ops": ops,
+                                      "programs": programs}]},
+                        steps=1, chips=1)
+    assert red.scope_s("backbone") > 0
+    assert red.scope_s("bench_target_zz") > 0
+    # a scope is a whole component of the name path, not a piece of one
+    assert red.scope_s("bench_target") is None
+    assert red.scope_s("back") is None
+
+
+def test_reader_returns_nothing_without_a_trace():
+    ctx = {"trace": None, "counters": {}, "peak_bytes": 0, "bytes_limit": 0,
+           "window": {"slowest_ms_per_step": 200.0}, "layers": [],
+           "rois": 128, "chips": 1, "cell": {}}
+    for m in BENCH["per_layer"]:
+        value = bench_run.read_metric(m["name"], ctx)
+        if m["name"] == "fit.slowest_window_ms":
+            assert value == 200.0
+        else:
+            assert value is None, m["name"]
+
+
+# ---- operation counts ----------------------------------------------------
+
+def test_conv_and_dense_hand_counts():
+    conv = {"kind": "conv", "cin": 64, "cout": 128, "k": 3, "stride": 2,
+            "out_hw": [10, 12], "per": "image", "grad": "both",
+            "scope": "backbone"}
+    assert flops.forward_flops(conv) == 2 * 9 * 64 * 128 * 120
+    dense = {"kind": "dense", "cin": 4096, "cout": 21, "per": "roi",
+             "grad": "both", "scope": "rcnn_losses"}
+    assert flops.forward_flops(dense) == 2 * 4096 * 21
+    # forward + input gradient + weight gradient; the dense layer per ROI
+    assert flops.step_flops_per_image([conv, dense], 128) == (
+        3 * 2 * 9 * 64 * 128 * 120 + 3 * 128 * 2 * 4096 * 21)
+    assert flops.step_flops_per_image([conv, dense], 128, "backbone") == (
+        3 * 2 * 9 * 64 * 128 * 120)
+    frozen = dict(conv, grad="none")
+    assert flops.step_flops_per_image([frozen], 128) == flops.forward_flops(conv)
+
+
+def _config(name):
+    with open(os.path.join(ROOT, "benchmark", "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name,want_tflop", [("r101-coco", 1.16),
+                                             ("vgg16-voc07", 1.02)])
+def test_layer_tables_match_the_compilers_count(name, want_tflop):
+    """XLA's ``cost_analysis`` of the program's jitted train step, compiled
+    for the chip at batch 2 and 16, counted 1.15-1.16 TFLOP an image for
+    ResNet-101 and 1.01-1.03 for VGG16 (ISSUE 30's sizing table) on the
+    whole 608x1024 bucket; the tables at that extent must land within 3 %
+    of that."""
+    config = _config(name)
+    table = flops.layer_table(config, config["bucket"])
+    got = flops.step_flops_per_image(table, 128) / 1e12
+    assert got == pytest.approx(want_tflop, rel=0.03)
+
+
+@pytest.mark.parametrize("name,image_hw,share", [
+    ("r101-coco", (600, 1000), 0.98), ("vgg16-voc07", (600, 800), 0.77)])
+def test_padding_is_not_counted(name, image_hw, share):
+    """The backbone's operations follow the images' own extent, not the
+    bucket's; what runs per ROI does not change."""
+    config = _config(name)
+    bucket = flops.layer_table(config, config["bucket"])
+    real = flops.layer_table(config, image_hw)
+    assert [r["name"] for r in real] == [r["name"] for r in bucket]
+    ratio = (flops.step_flops_per_image(real, 128, "backbone")
+             / flops.step_flops_per_image(bucket, 128, "backbone"))
+    assert ratio == pytest.approx(share, abs=0.01)
+    assert (flops.step_flops_per_image(real, 128, "rcnn_losses")
+            == flops.step_flops_per_image(bucket, 128, "rcnn_losses"))
+
+
+def test_counts_against_cost_analysis_of_a_small_forward():
+    import jax
+    import jax.numpy as jnp
+
+    layers = [
+        {"kind": "conv", "cin": 3, "cout": 16, "k": 3, "stride": 1,
+         "out_hw": [32, 48], "per": "image", "grad": "none", "scope": "s"},
+        {"kind": "conv", "cin": 16, "cout": 32, "k": 3, "stride": 2,
+         "out_hw": [16, 24], "per": "image", "grad": "none", "scope": "s"},
+        {"kind": "dense", "cin": 16 * 24 * 32, "cout": 10, "per": "image",
+         "grad": "none", "scope": "s"}]
+
+    def forward(x, k1, k2, w):
+        dn = ("NHWC", "HWIO", "NHWC")
+        y = jax.lax.conv_general_dilated(x, k1, (1, 1), "SAME",
+                                         dimension_numbers=dn)
+        y = jax.lax.conv_general_dilated(y, k2, (2, 2), "SAME",
+                                         dimension_numbers=dn)
+        return y.reshape(1, -1) @ w
+
+    args = [jnp.zeros(s, jnp.float32) for s in
+            ((1, 32, 48, 3), (3, 3, 3, 16), (3, 3, 16, 32),
+             (16 * 24 * 32, 10))]
+    cost = jax.jit(forward).lower(*args).compile().cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    want = flops.step_flops_per_image(layers, 1)
+    # XLA leaves out the taps that fall on SAME padding: within 10 %
+    assert cost["flops"] == pytest.approx(want, rel=0.10)
+
+
+def test_unknown_device_kind_has_no_peaks():
+    assert flops.peaks("TPU v5 lite")["flops_per_s"] == 197e12
+    with pytest.raises(KeyError):
+        flops.peaks("TPU v9")
+    with pytest.raises(KeyError):
+        flops.peaks("_source")
+
+
+# ---- manifest <-> files --------------------------------------------------
+
+def test_manifest_names_units_and_sources():
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in BENCH[group]]
+        assert len(set(names)) == len(names)
+        for e in BENCH[group]:
+            assert NAME.match(e["name"]), e["name"]
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    for c in BENCH["configs"]:
+        assert 1 <= len(c["source"]) <= 200 and "\n" not in c["source"]
+    for w in BENCH["workloads"]:
+        assert 1 <= len(w["why"]) <= 200
+    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
+    for m in BENCH["end_to_end"]:
+        assert 0 < m["bound"] <= 0.1
+
+
+def test_every_name_has_its_file_and_every_file_its_name():
+    bdir = os.path.join(ROOT, "benchmark")
+    cells = {w["name"] for w in BENCH["workloads"]}
+    assert cells == {f[:-5] for f in os.listdir(os.path.join(bdir, "workloads"))}
+    configs = {c["name"] for c in BENCH["configs"]}
+    assert configs == {f[:-5] for f in os.listdir(os.path.join(bdir, "configs"))}
+    families = {f[:-3] for f in os.listdir(os.path.join(bdir, "families"))
+                if f.endswith(".py") and f != "__init__.py"}
+    assert families == {_config(c)["network"]["family"] for c in configs}
+    readers = {f[:-3] for f in os.listdir(os.path.join(bdir, "metrics"))
+               if f.endswith(".py")}
+    assert readers == {m["name"] for m in BENCH["per_layer"]}
+    traffic = {f[:-5] for f in os.listdir(os.path.join(bdir, "traffic"))}
+    assert traffic == {w["traffic"] for w in BENCH["workloads"]}
+    e2e = {m["name"] for m in BENCH["end_to_end"]}
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", cells)) <= cells
+    for w in BENCH["workloads"]:
+        cell = bench_run.load_cell(w["name"])
+        assert cell["name"] == w["name"] and cell["chips"] == w["chips"]
+        assert cell["config"]["name"] == w["config"] in configs
+        assert cell["why"] == w["why"]
+        c = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+        assert c["file"] == f"benchmark/configs/{w['config']}.json"
+        assert c["reduced"] == cell["config"]["reduced"]
+        known = {f"{k}_s{i}" for k in ("loss", "rpn_loss")
+                 for i in range(1, cell["check"]["steps"] + 1)} | {
+            "grad_worst", "grad_median", "first_delta_worst",
+            "first_delta_median", "delta_worst", "delta_median"}
+        limits = cell["check"]["limits"]
+        assert limits and set(limits) <= known
+        assert all(0 < v < 1 for v in limits.values()), limits
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_config_file_states_what_the_program_runs(name):
+    """The reference reads its sizes from the configuration's file, never
+    from the program: the two must say the same."""
+    from mx_rcnn_tpu.config import generate_config
+
+    with open(os.path.join(ROOT, "benchmark", "configs", f"{name}.json")) as f:
+        config = json.load(f)
+    prog = config["program"]
+    cfg = generate_config(prog["network"], prog["dataset"], **prog["overrides"])
+    net, tr, opt = config["network"], config["train"], config["optimizer"]
+    for key, value in tr.items():
+        got = getattr(cfg.train, key)
+        assert (list(got) if isinstance(got, tuple) else got) == value, key
+    assert cfg.num_classes == net["num_classes"]
+    assert list(cfg.network.anchor_scales) == net["anchor_scales"]
+    assert list(cfg.network.anchor_ratios) == net["anchor_ratios"]
+    assert cfg.network.rpn_feat_stride == net["feat_stride"]
+    assert list(cfg.network.rcnn_pooled_size) == net["pooled_size"]
+    assert list(cfg.network.pixel_means) == net["pixel_means"]
+    assert cfg.network.compute_dtype == net["compute_dtype"]
+    assert list(cfg.network.fixed_params) == opt["fixed_params"]
+    assert (cfg.default.momentum, cfg.default.wd, cfg.default.clip_gradient,
+            cfg.default.momentum_dtype) == (
+                opt["momentum"], opt["wd"], opt["clip_gradient"],
+                opt["momentum_dtype"])
+    assert list(cfg.bucket.shapes[0]) == config["bucket"]
+
+
+# ---- no chip, no result --------------------------------------------------
+
+def test_run_without_the_chip_fails_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    cell = BENCH["workloads"][0]["name"]
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", cell, "--seed", "2147483659", "--seconds", "1",
+         "--trace", "0"],
+        capture_output=True, text=True, env=env, cwd=ROOT, timeout=600)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "chip" in p.stderr
+
+
+def test_run_without_the_program_fails_and_prints_no_result(tmp_path):
+    """In a directory that holds only BENCHMARK.json and the benchmark's
+    own directories there is no system under test."""
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for path in BENCH["paths"]:
+        shutil.copytree(os.path.join(ROOT, path), tmp_path / path,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    p = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         BENCH["workloads"][0]["name"], "--seed", "7", "--seconds", "1",
+         "--trace", "0"],
+        capture_output=True, text=True, env=dict(env, JAX_PLATFORMS="cpu"),
+        cwd=tmp_path, timeout=600)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+
+
+def test_readings_plant_only_what_the_reference_takes():
+    """``benchmark/readings.py`` re-reads the limits' upper readings: what
+    it plants has to be an option of ``reference_steps`` and a policy of
+    ``reference/nets.py``."""
+    import inspect
+
+    from benchmark import readings
+    from benchmark.reference import nets, step
+
+    taken = inspect.signature(step.reference_steps).parameters
+    for tag, kw in readings.PLANTED.items():
+        assert set(kw) <= set(taken), tag
+        if "precision" in kw:
+            assert len(nets.policy(kw["precision"])) == 4
