@@ -668,10 +668,14 @@ class ObsConfig:
     # tools/train.py (0 = off; tools/serve.py always exposes /metrics on
     # its own HTTP front end)
     metrics_port: int = 0
-    # collect host-side spans (obs/trace.py) and export a chrome trace
-    # into the run record on exit
+    # export the collected host-side spans (obs/trace.py) as a chrome
+    # trace into the run record on exit.  tools/train.py — train_net
+    # collects them under ``enabled`` alone; tools/serve.py collects them
+    # under this flag
     trace: bool = False
-    trace_cap: int = 100_000     # span buffer bound (overflow counted)
+    # span ring size: the newest trace_cap events are kept (older ones
+    # fall off, counted)
+    trace_cap: int = 100_000
     # on-demand profiler window (obs/profiler.py): capture a
     # profile_steps-step jax.profiler window starting at this GLOBAL
     # step (0 = never), rolled up into per-scope device-time tables

@@ -108,6 +108,29 @@ def _mean_metrics(window: List[Dict]) -> Dict[str, float]:
     return {k: float(np.mean([m[k] for m in window])) for k in keys}
 
 
+def _sync_metrics(window: List[Dict], step: int) -> Dict[str, float]:
+    """The loop's one sync, under a ``train.sync`` span that carries the
+    global ``step`` it follows and the count ``n`` of steps it fetched.
+    One path whether spans are collected or not: start every value's copy
+    to the host (as ``jax.device_get`` does first, so the earlier steps'
+    values cross while the last step still runs), wait for the last step,
+    take the means.  With spans collected the span also carries
+    ``fetch_us``, its time after the last step's result was ready: the
+    span's end less the fetch is the moment the host learned that step
+    ``step`` had left the device, the one point at which the host's clock
+    and the device's are tied (``benchmark/hostspans.py``)."""
+    with obs_trace.span("train.sync", step=step, n=len(window)) as sp:
+        for leaf in jax.tree.leaves(window):
+            leaf.copy_to_host_async()
+        jax.block_until_ready(window[-1])
+        if sp is None:
+            return _mean_metrics(window)
+        t_ready = time.time_ns()
+        avg = _mean_metrics(window)
+        sp.args["fetch_us"] = (time.time_ns() - t_ready) / 1e3
+        return avg
+
+
 def fit(
     model: FasterRCNN,
     cfg: Config,
@@ -122,7 +145,6 @@ def fit(
     mesh=None,
     mode: str = "e2e",
     epoch_end_callback: Optional[Callable[[int, TrainState], None]] = None,
-    profile_dir: Optional[str] = None,
     stop_flag: Optional[Callable[[], bool]] = None,
     device_cache: bool = False,
     step_callback: Optional[Callable[[int], None]] = None,
@@ -140,9 +162,6 @@ def fit(
     ``mode``: 'e2e' | 'rpn' | 'rcnn' (alternate-training stages).
     ``key`` is the base RNG; the step folds in ``state.step`` so resuming
     from a checkpoint replays the identical sample stream.
-    ``profile_dir``: capture a ``jax.profiler`` trace of a few early steps
-    (after compile warm-up) into this directory for tensorboard inspection;
-    the coarse per-stage breakdown lives in ``tools/profile_step.py``.
     ``stop_flag``: polled after every step; when it returns True the loop
     saves a mid-epoch interrupt checkpoint (``<prefix>-interrupt.ckpt``)
     and returns — the preemption path (SIGTERM on preemptible TPUs).
@@ -161,7 +180,14 @@ def fit(
     registry, and ``cfg.obs.profile_at_step`` opens an on-demand
     profiler window (``obs/profiler.py``); all of it is absent from the
     hot path when disabled (the default — overhead pinned by
-    ``tests/test_obs.py``).
+    ``tests/test_obs.py``).  The loop body is covered end to end by
+    ``obs/trace.py`` spans on this thread — ``train.data_wait``,
+    ``train.dispatch``, ``train.hooks`` (profiler window,
+    ``step_callback``, ``stop_flag``), and at a log step ``train.sync``
+    and ``train.log`` — each with ``step=``, the global step it belongs
+    to; the first step's ``train.dispatch`` holds the step's trace,
+    lowering and compile or cache read (``setup.first_step_s``).  With
+    span collection off each is one module-flag read.
     ``device_cache``: stage the loader's epoch in HBM once and gather each
     step's batch on device (``data/device_cache.py``) — for RAM/HBM-scale
     datasets on hosts or links too slow to stream per step.  Shuffling is
@@ -392,7 +418,6 @@ def fit(
             epoch_metrics: List[Dict] = []
             t0 = time.perf_counter()
             nbatch = skip
-            tracing = False
             stop_requested = False
             if cache is not None:
                 # batches gather on device from the staged epoch; the
@@ -435,32 +460,29 @@ def fit(
                     # batch k+1 overlap step k (docs/DATA.md)
                     stager = DeviceStager(batch_iter, stage_place,
                                           depth=data_cfg.stage_depth,
-                                          rec=rec)
+                                          rec=rec, first_seq=skip + 1)
                     batch_iter = iter(stager)
             if run_record is not None:
                 run_record.event("epoch_start", epoch=epoch, skip=skip,
                                  steps_per_epoch=steps_per_epoch)
             while True:
+                # the global step this iteration produces: every span of
+                # the iteration carries it
+                gstep = epoch * steps_per_epoch + nbatch + 1
                 if rec is None:
                     batch = next(batch_iter, _END)
                 else:
+                    lowerings.mark_step(gstep)
                     t_wait = time.perf_counter()
-                    with obs_trace.span("train.data_wait"):
+                    with obs_trace.span("train.data_wait", step=gstep):
                         batch = next(batch_iter, _END)
                     wait_s = time.perf_counter() - t_wait
                 if batch is _END:
                     break
-                # trace steps [skip+2, skip+5) of the first epoch: the first
-                # two executed steps carry compile
-                if (profile_dir is not None and epoch == begin_epoch
-                        and nbatch == skip + 2):
-                    jax.profiler.start_trace(profile_dir)
-                    tracing = True
-                    logger.info("profiler trace started -> %s", profile_dir)
                 if rec is None:
                     state, metrics = run_step(state, batch)
                 else:
-                    with obs_trace.span("train.dispatch"):
+                    with obs_trace.span("train.dispatch", step=gstep):
                         state, metrics = run_step(state, batch)
                     step_s = time.perf_counter() - t_wait
                     rec.inc("train.steps")
@@ -476,74 +498,75 @@ def fit(
                                 lo=0.01, hi=1000.0)
                 window.append(metrics)
                 nbatch += 1
-                if tracing and nbatch >= skip + 5:
-                    jax.block_until_ready(metrics)
-                    jax.profiler.stop_trace()
-                    tracing = False
-                    logger.info("profiler trace written to %s", profile_dir)
-                if prof is not None:
-                    m = metrics  # bind: the lambda must sync THIS step
-                    prof.on_step(epoch * steps_per_epoch + nbatch,
-                                 sync=lambda: jax.block_until_ready(m))
-                if step_callback is not None:
-                    step_callback(epoch * steps_per_epoch + nbatch)
-                if stop_flag is not None and stop_flag():
-                    stop_requested = True
-                    # mid-epoch: save the step-exact interrupt state and
-                    # leave.  On the epoch's LAST batch, fall through
-                    # instead — the normal epoch end writes the
-                    # (superseding) epoch checkpoint and the run stops
-                    # cleanly at the boundary.
-                    if nbatch < steps_per_epoch:
-                        if tracing:
-                            jax.profiler.stop_trace()
-                        if snap is not None:
-                            with obs_trace.span("train.snapshot",
-                                                kind="interrupt"):
-                                path = snap.save_interrupt(state)
-                            if run_record is not None:
-                                run_record.event(
-                                    "interrupt", epoch=epoch, nbatch=nbatch,
-                                    path=path)
-                            logger.info(
-                                "stop requested: saved interrupt checkpoint "
-                                'to "%s" (step %d) — rerun with --resume to '
-                                "continue", path,
-                                int(jax.device_get(state.step)))
-                        else:
-                            logger.info(
-                                "stop requested: no prefix, state not saved")
-                        return state
+                with obs_trace.span("train.hooks", step=gstep):
+                    if prof is not None:
+                        m = metrics  # bind: the lambda must sync THIS step
+                        prof.on_step(gstep,
+                                     sync=lambda: jax.block_until_ready(m))
+                    if step_callback is not None:
+                        step_callback(gstep)
+                    if stop_flag is not None and stop_flag():
+                        stop_requested = True
+                        # mid-epoch: save the step-exact interrupt state
+                        # and leave.  On the epoch's LAST batch, fall
+                        # through instead — the normal epoch end writes
+                        # the (superseding) epoch checkpoint and the run
+                        # stops cleanly at the boundary.
+                        if nbatch < steps_per_epoch:
+                            if snap is not None:
+                                with obs_trace.span("train.snapshot",
+                                                    kind="interrupt",
+                                                    step=gstep):
+                                    path = snap.save_interrupt(state)
+                                if run_record is not None:
+                                    run_record.event(
+                                        "interrupt", epoch=epoch,
+                                        nbatch=nbatch, path=path)
+                                logger.info(
+                                    "stop requested: saved interrupt "
+                                    'checkpoint to "%s" (step %d) — rerun '
+                                    "with --resume to continue", path,
+                                    int(jax.device_get(state.step)))
+                            else:
+                                logger.info("stop requested: no prefix, "
+                                            "state not saved")
+                            return state
                 if nbatch % frequent == 0:
-                    with obs_trace.span("train.sync"):
-                        avg = _mean_metrics(window)
-                    epoch_metrics.append(avg)
-                    window = []
-                    speedo(epoch, nbatch, avg)
-                    if rec is not None:
-                        loss = avg.get("loss")
-                        if loss is not None:
-                            a = cfg.obs.loss_ema
-                            loss_ema = (loss if loss_ema is None
-                                        else a * loss_ema + (1 - a) * loss)
-                            rec.set_gauge("train.loss_ema", loss_ema)
-                        rec.set_gauge("train.lowerings_total", lowerings.n)
-                    if run_record is not None:
-                        run_record.event(
-                            "log", epoch=epoch, nbatch=nbatch,
-                            samples_per_sec=(
-                                None if rec is None
-                                else rec.gauge("train.samples_per_sec")),
-                            **avg)
+                    avg = _sync_metrics(window, gstep)
+                    with obs_trace.span("train.log", step=gstep) as sp:
+                        # dropping the window frees n steps' device
+                        # scalars, a millisecond of the log step's own
+                        epoch_metrics.append(avg)
+                        window = []
+                        speedo(epoch, nbatch, avg)
+                        if rec is not None:
+                            loss = avg.get("loss")
+                            if loss is not None:
+                                a = cfg.obs.loss_ema
+                                loss_ema = (
+                                    loss if loss_ema is None
+                                    else a * loss_ema + (1 - a) * loss)
+                                rec.set_gauge("train.loss_ema", loss_ema)
+                            rec.set_gauge("train.lowerings_total",
+                                          lowerings.n)
+                            if sp is not None:
+                                # the registry's compile seconds as they
+                                # stand at this edge: the first edge's
+                                # value is what the start paid
+                                sp.args["backend_compile_s"] = rec.counter(
+                                    "compile.backend_s")
+                        if run_record is not None:
+                            run_record.event(
+                                "log", epoch=epoch, nbatch=nbatch,
+                                samples_per_sec=(
+                                    None if rec is None
+                                    else rec.gauge("train.samples_per_sec")),
+                                **avg)
                 else:
                     speedo(epoch, nbatch, {})
             if stager is not None:  # epoch drained: join the stage thread
                 stager.close()
                 stager = None
-            if tracing:  # epoch shorter than the trace window
-                jax.block_until_ready(metrics)
-                jax.profiler.stop_trace()
-                logger.info("profiler trace written to %s", profile_dir)
             if window:
                 epoch_metrics.append(_mean_metrics(window))
             epoch_s = time.perf_counter() - t0
@@ -568,7 +591,8 @@ def fit(
                 # device_get here, serialize+write+manifest+GC in the
                 # background; the interrupt file is cleared by the writer
                 # only after this epoch checkpoint commits
-                with obs_trace.span("train.snapshot", kind="epoch"):
+                with obs_trace.span("train.snapshot", kind="epoch",
+                                    step=(epoch + 1) * steps_per_epoch):
                     path = snap.save_epoch(epoch + 1, state)
                 if run_record is not None:
                     run_record.event("snapshot", epoch=epoch, path=path)

@@ -98,20 +98,21 @@ def _rpn_losses(model: FasterRCNN, rpn_cls, rpn_box, anchors, batch,
     """
     tr = cfg.train
     n = batch.images.shape[0]
-    at = jax.vmap(
-        functools.partial(
-            anchor_target,
-            rpn_batch_size=tr.rpn_batch_size,
-            rpn_fg_fraction=tr.rpn_fg_fraction,
-            positive_overlap=tr.rpn_positive_overlap,
-            negative_overlap=tr.rpn_negative_overlap,
-            clobber_positives=tr.rpn_clobber_positives,
-            allowed_border=tr.rpn_allowed_border,
-            bbox_weights=tr.rpn_bbox_weights,
-        ),
-        in_axes=(None, 0, 0, 0, 0),
-    )(anchors, batch.gt_boxes, batch.gt_valid, batch.im_info,
-      jax.random.split(key, n))
+    with jax.named_scope("anchor_target"):
+        at = jax.vmap(
+            functools.partial(
+                anchor_target,
+                rpn_batch_size=tr.rpn_batch_size,
+                rpn_fg_fraction=tr.rpn_fg_fraction,
+                positive_overlap=tr.rpn_positive_overlap,
+                negative_overlap=tr.rpn_negative_overlap,
+                clobber_positives=tr.rpn_clobber_positives,
+                allowed_border=tr.rpn_allowed_border,
+                bbox_weights=tr.rpn_bbox_weights,
+            ),
+            in_axes=(None, 0, 0, 0, 0),
+        )(anchors, batch.gt_boxes, batch.gt_valid, batch.im_info,
+          jax.random.split(key, n))
 
     rpn_cls32 = rpn_cls.astype(jnp.float32)
     cls_loss = softmax_cross_entropy_with_ignore(
@@ -153,9 +154,10 @@ def _rcnn_losses(model: FasterRCNN, variables, feat, rois, rois_valid,
             bbox_stds=tr.bbox_stds,
             gt_append=tr.gt_append)
 
-    pt = jax.vmap(one_img)(
-        rois, rois_valid, batch.gt_boxes, batch.gt_classes, batch.gt_valid,
-        jax.random.split(k_prop, n))
+    with jax.named_scope("proposal_target"):
+        pt = jax.vmap(one_img)(
+            rois, rois_valid, batch.gt_boxes, batch.gt_classes,
+            batch.gt_valid, jax.random.split(k_prop, n))
 
     # 'auto' resolves to the einsum pair — the fused Pallas kernel wins
     # isolated but loses ~13 ms to custom-call boundary costs in the full
@@ -163,14 +165,15 @@ def _rcnn_losses(model: FasterRCNN, variables, feat, rois, rois_valid,
     # same pair ROI-chunked (bit-equal forward, intermediate shrunk by
     # roi_align_chunk/R); 'pallas' opts into the kernel
     backend = None if tr.roi_align_backend == "auto" else tr.roi_align_backend
-    pooled = roi_align_batched(feat, pt.rois, model.pooled_size,
-                               1.0 / model.feat_stride,
-                               backend=backend,
-                               chunk=tr.roi_align_chunk)  # (N, B, ph, pw, C)
+    with jax.named_scope("roi_align"):
+        pooled = roi_align_batched(
+            feat, pt.rois, model.pooled_size, 1.0 / model.feat_stride,
+            backend=backend, chunk=tr.roi_align_chunk)  # (N, B, ph, pw, C)
     flat = pooled.reshape((-1,) + pooled.shape[2:])
-    cls_logits, bbox_deltas = model.apply(
-        variables, flat, True, method=model.roi_head,
-        rngs={"dropout": k_drop})
+    with jax.named_scope("roi_head"):
+        cls_logits, bbox_deltas = model.apply(
+            variables, flat, True, method=model.roi_head,
+            rngs={"dropout": k_drop})
     cls_logits = cls_logits.astype(jnp.float32)
     bbox_deltas = bbox_deltas.astype(jnp.float32)
 
@@ -222,8 +225,12 @@ def loss_and_metrics(  # graphlint: jit (traced via LOSS_FNS inside the step)
     k_anchor, k_rcnn = jax.random.split(key)
 
     # named_scope on each stage: jax.profiler traces then attribute device
-    # time per stage (tools/profile_step.py --trace_summary), the loop-free
-    # fallback to the unrolled-chain timing
+    # time per stage (benchmark/metrics/<scope>.device_ms.py,
+    # tools/profile_step.py --trace_summary).  Scopes are metadata: no
+    # jit boundary, the compiled step keeps its instructions and fusions.
+    # Inside them: anchor_target (rpn_losses), nms_sweep (proposal, in
+    # ops/nms.py), proposal_target / roi_align / roi_head (rcnn_losses);
+    # beside them in make_train_step: grad_sync, optimizer
     with jax.named_scope("backbone"):
         feat = _backbone_features(model, variables, batch, cfg)
     with jax.named_scope("rpn_head"):
@@ -275,12 +282,16 @@ def loss_and_metrics_rpn(  # graphlint: jit (traced via LOSS_FNS)
     ``train_rpn.py``): backbone → RPN heads → anchor targets → two losses.
     Shares ``_rpn_losses`` with the e2e objective."""
     variables = {"params": params, "batch_stats": batch_stats}
-    feat = _backbone_features(model, variables, batch, cfg)
-    rpn_cls, rpn_box = model.apply(variables, feat, method=model.rpn_raw)
+    with jax.named_scope("backbone"):
+        feat = _backbone_features(model, variables, batch, cfg)
+    with jax.named_scope("rpn_head"):
+        rpn_cls, rpn_box = model.apply(variables, feat,
+                                       method=model.rpn_raw)
     _, fh, fw, _ = feat.shape
     anchors = model.anchors_for(fh, fw)
-    cls_loss, bbox_loss, metrics = _rpn_losses(
-        model, rpn_cls, rpn_box, anchors, batch, key, cfg)
+    with jax.named_scope("rpn_losses"):
+        cls_loss, bbox_loss, metrics = _rpn_losses(
+            model, rpn_cls, rpn_box, anchors, batch, key, cfg)
     total = cls_loss + bbox_loss
     return total, {**metrics, "loss": total}
 
@@ -297,10 +308,12 @@ def loss_and_metrics_rcnn(  # graphlint: jit (traced via LOSS_FNS)
     2/4; ref ``train_rcnn.py`` + host-side ``sample_rois``).  Shares
     ``_rcnn_losses`` with the e2e objective."""
     variables = {"params": params, "batch_stats": batch_stats}
-    feat = _backbone_features(model, variables, batch, cfg)
-    cls_loss, bbox_loss, metrics = _rcnn_losses(
-        model, variables, feat, batch.rois, batch.rois_valid, batch, key,
-        cfg)
+    with jax.named_scope("backbone"):
+        feat = _backbone_features(model, variables, batch, cfg)
+    with jax.named_scope("rcnn_losses"):
+        cls_loss, bbox_loss, metrics = _rcnn_losses(
+            model, variables, feat, batch.rois, batch.rois_valid, batch,
+            key, cfg)
     total = cls_loss + bbox_loss
     return total, {**metrics, "loss": total}
 
@@ -447,10 +460,13 @@ def make_train_step(model: FasterRCNN, cfg: Config,
             grads = jax.tree.map(lambda g: g.mean(axis=0), grads)
             metrics = jax.tree.map(lambda m: m.mean(axis=0), metrics)
         if axis_name is not None:
-            grads = jax.lax.pmean(grads, axis_name)
-            metrics = jax.lax.pmean(metrics, axis_name)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("grad_sync"):
+                grads = jax.lax.pmean(grads, axis_name)
+                metrics = jax.lax.pmean(metrics, axis_name)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(state.step + 1, params, state.batch_stats,
                                opt_state)
         return new_state, metrics

@@ -30,7 +30,12 @@ batches, ``loader.stage_put_ms`` the worker-side assemble+place time,
 ``loader.stage_hits``/``loader.stage_misses`` whether the consumer found
 a batch ready (hit = the overlap did its job; the data-smoke gate
 asserts hits > 0), and the ``loader.stage_depth`` gauge the occupancy
-at each pop.
+at each pop.  With span collection on (``obs/trace.py``) the worker also
+leaves three spans per batch on its own thread — ``stage.assemble`` (the
+``next`` on the source), ``stage.place`` and ``stage.put_wait`` (blocked
+on the full queue) — each with ``seq=``, the batch's ordinal in the
+epoch, which is the fit loop's step within it: what the input plane did
+while the loop sat in ``train.data_wait``.
 """
 
 from __future__ import annotations
@@ -38,7 +43,9 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Callable, Iterable, Iterator, Optional
+from typing import Callable, Iterable, Iterator
+
+from mx_rcnn_tpu.obs import trace as obs_trace
 
 _END = object()
 
@@ -55,32 +62,39 @@ class DeviceStager:
       depth: max device-resident batches in flight (>= 1).
       rec: an ``obs/metrics.py`` Registry, or None (the default) to keep
         the hot path metric-free.
+      first_seq: the ordinal of the first batch ``source`` yields (the
+        spans' ``seq``): 1 at an epoch's start, one past the batches a
+        mid-epoch resume skipped.
     """
 
     def __init__(self, source: Iterable, place: Callable, depth: int = 2,
-                 rec=None):
+                 rec=None, first_seq: int = 1):
         self._q: "queue.Queue" = queue.Queue(maxsize=max(int(depth), 1))
         self._rec = rec
         self._closed = False
         self._thread = threading.Thread(
-            target=self._run, args=(iter(source), place),
+            target=self._run, args=(iter(source), place, int(first_seq)),
             name="device-stager", daemon=True)
         self._thread.start()
 
-    def _run(self, it: Iterator, place: Callable) -> None:
+    def _run(self, it: Iterator, place: Callable, seq: int) -> None:
         try:
             while not self._closed:
                 t0 = time.perf_counter()
                 try:
-                    batch = next(it)
+                    with obs_trace.span("stage.assemble", seq=seq):
+                        batch = next(it)
                 except StopIteration:
                     break
-                placed = place(batch)
+                with obs_trace.span("stage.place", seq=seq):
+                    placed = place(batch)
                 if self._rec is not None:
                     self._rec.inc("loader.staged_batches")
                     self._rec.observe("loader.stage_put_ms",
                                       (time.perf_counter() - t0) * 1e3)
-                self._put(placed)
+                with obs_trace.span("stage.put_wait", seq=seq):
+                    self._put(placed)
+                seq += 1
         except BaseException as e:  # noqa: BLE001 — re-raised in consumer
             self._put(e)
             return

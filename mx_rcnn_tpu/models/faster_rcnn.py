@@ -144,18 +144,36 @@ class FasterRCNN(nn.Module):
         """RPN-only forward (ref ``get_*_rpn_test`` symbol): images →
         (rois, fg scores, valid) — used by generate_proposals in alternate
         training and by test_rpn."""
-        feat = self.features(images, im_info)
-        rpn_cls, rpn_box = self.rpn_raw(feat)
+        with jax.named_scope("backbone"):
+            feat = self.features(images, im_info)
+        with jax.named_scope("rpn_head"):
+            rpn_cls, rpn_box = self.rpn_raw(feat)
         _, fh, fw, _ = feat.shape
         anchors = self.anchors_for(fh, fw)
         fg = jax.nn.softmax(rpn_cls.astype(jnp.float32), axis=-1)[..., 1]
-        return propose_batch(
-            fg, rpn_box.astype(jnp.float32), anchors, im_info,
-            pre_nms_top_n=pre_nms_top_n,
-            post_nms_top_n=post_nms_top_n,
-            nms_thresh=self.test_nms_thresh,
-            min_size=self.test_min_size,
-        )
+        with jax.named_scope("proposal"):
+            return propose_batch(
+                fg, rpn_box.astype(jnp.float32), anchors, im_info,
+                pre_nms_top_n=pre_nms_top_n,
+                post_nms_top_n=post_nms_top_n,
+                nms_thresh=self.test_nms_thresh,
+                min_size=self.test_min_size,
+            )
+
+    def _pool_and_classify(self, feat: jnp.ndarray, rois: jnp.ndarray):
+        """ROIAlign + the per-ROI head of the two test forwards, under the
+        train step's scope names; returns (cls_logits, deltas, R)."""
+        def pool_one(feat_i, rois_i):
+            return roi_align(feat_i, rois_i, self.pooled_size,
+                             1.0 / self.feat_stride)
+
+        with jax.named_scope("roi_align"):
+            pooled = jax.vmap(pool_one)(feat, rois)  # (N, R, ph, pw, C)
+        n, r = pooled.shape[:2]
+        flat = pooled.reshape((n * r,) + pooled.shape[2:])
+        with jax.named_scope("roi_head"):
+            cls_logits, deltas = self.roi_head(flat, train=False)
+        return cls_logits, deltas, r
 
     def detect_rois(self, images: jnp.ndarray, im_info: jnp.ndarray,
                     rois: jnp.ndarray, roi_valid: jnp.ndarray
@@ -172,17 +190,10 @@ class FasterRCNN(nn.Module):
         Returns the same tuple as ``__call__`` so the eval postprocess is
         shared: (rois, roi_valid, cls_prob, bbox_deltas).
         """
-        feat = self.features(images, im_info)
+        with jax.named_scope("backbone"):
+            feat = self.features(images, im_info)
         n = feat.shape[0]
-
-        def pool_one(feat_i, rois_i):
-            return roi_align(feat_i, rois_i, self.pooled_size,
-                             1.0 / self.feat_stride)
-
-        pooled = jax.vmap(pool_one)(feat, rois)  # (N, R, ph, pw, C)
-        r = pooled.shape[1]
-        flat = pooled.reshape((n * r,) + pooled.shape[2:])
-        cls_logits, deltas = self.roi_head(flat, train=False)
+        cls_logits, deltas, r = self._pool_and_classify(feat, rois)
         cls_prob = jax.nn.softmax(cls_logits.astype(jnp.float32), axis=-1)
         return (
             rois,
@@ -204,27 +215,24 @@ class FasterRCNN(nn.Module):
           rois (N, R, 4), roi_valid (N, R), cls_prob (N, R, classes),
           bbox_deltas (N, R, 4*classes) — R = test_post_nms_top_n.
         """
-        feat = self.features(images, im_info)
-        rpn_cls, rpn_box = self.rpn_raw(feat)
+        # the train step's scope names (core/train.py), so a trace of the
+        # test forward reads by the same stages
+        with jax.named_scope("backbone"):
+            feat = self.features(images, im_info)
+        with jax.named_scope("rpn_head"):
+            rpn_cls, rpn_box = self.rpn_raw(feat)
         n, fh, fw, _ = feat.shape
         anchors = self.anchors_for(fh, fw)
         fg_scores = jax.nn.softmax(rpn_cls.astype(jnp.float32), axis=-1)[..., 1]
-        rois, _, roi_valid = propose_batch(
-            fg_scores, rpn_box, anchors, im_info,
-            pre_nms_top_n=self.test_pre_nms_top_n,
-            post_nms_top_n=self.test_post_nms_top_n,
-            nms_thresh=self.test_nms_thresh,
-            min_size=self.test_min_size,
-        )
-
-        def pool_one(feat_i, rois_i):
-            return roi_align(feat_i, rois_i, self.pooled_size,
-                             1.0 / self.feat_stride)
-
-        pooled = jax.vmap(pool_one)(feat, rois)  # (N, R, ph, pw, C)
-        r = pooled.shape[1]
-        flat = pooled.reshape((n * r,) + pooled.shape[2:])
-        cls_logits, deltas = self.roi_head(flat, train=False)
+        with jax.named_scope("proposal"):
+            rois, _, roi_valid = propose_batch(
+                fg_scores, rpn_box, anchors, im_info,
+                pre_nms_top_n=self.test_pre_nms_top_n,
+                post_nms_top_n=self.test_post_nms_top_n,
+                nms_thresh=self.test_nms_thresh,
+                min_size=self.test_min_size,
+            )
+        cls_logits, deltas, r = self._pool_and_classify(feat, rois)
         cls_prob = jax.nn.softmax(cls_logits.astype(jnp.float32), axis=-1)
         return (
             rois,

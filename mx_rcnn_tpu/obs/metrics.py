@@ -113,7 +113,9 @@ class Registry:
         self._hists: Dict[str, Histogram] = {}
 
     # -- record -------------------------------------------------------------
-    def inc(self, name: str, n: int = 1) -> None:
+    def inc(self, name: str, n: float = 1) -> None:
+        """Add ``n`` to a counter: a count, or seconds that accumulate
+        (``compile.backend_s``)."""
         with self.lock:
             self._counters[name] = self._counters.get(name, 0) + n
 
@@ -301,17 +303,31 @@ class ServeMetrics:
             return out
 
 
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# its seconds accumulate in the registry's ``compile.backend_s``; a
+# persistent-cache read is inside it
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
 class LoweringCounter:
     """Counts pjit lowering events (jit cache misses) inside a ``with``
     block via ``jax.monitoring`` — fired on every trace+lower regardless
     of the persistent XLA compile cache, so "zero new compiles on a
     warmed program" is assertable across cold and warm processes.
 
+    The one listener also adds every backend compile's seconds to the
+    process registry (``compile.backend_s``) and, for each lowering, emits
+    a ``compile.lowering`` instant into the span buffer (``obs/trace.py``;
+    a no-op unless spans are collected) carrying its seconds and the train
+    step current at the time (:meth:`mark_step`; None outside the fit
+    loop), so "which step recompiled" has an answer with a time on it.
+
     Import-light: registering the listener touches jax only on first use.
     """
 
     _events = {"lowerings": 0}
     _registered = False
+    _step: Optional[int] = None
 
     @classmethod
     def _ensure_listener(cls) -> None:
@@ -319,12 +335,24 @@ class LoweringCounter:
             return
         import jax
 
+        from mx_rcnn_tpu.obs import trace as obs_trace
+
         def on_event(event, duration, **kw):
-            if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            if event == _LOWER_EVENT:
                 cls._events["lowerings"] += 1
+                obs_trace.instant("compile.lowering", step=cls._step,
+                                  lower_s=float(duration))
+            elif event == _BACKEND_EVENT:
+                _GLOBAL.inc("compile.backend_s", float(duration))
 
         jax.monitoring.register_event_duration_secs_listener(on_event)
         cls._registered = True
+
+    @classmethod
+    def mark_step(cls, step: Optional[int]) -> None:
+        """The fit loop's current global step (one attribute store): the
+        ``compile.lowering`` instants carry it."""
+        cls._step = step
 
     def __enter__(self) -> "LoweringCounter":
         self._ensure_listener()

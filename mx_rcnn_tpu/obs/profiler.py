@@ -17,10 +17,11 @@ per-op-class device-time tables written next to the trace
 (``rollup.json``) — the answer a human wants, without opening
 TensorBoard.
 
-Only ONE window can be open at a time (module-level guard shared with
-nothing else; ``core/fit.py``'s legacy ``profile_dir`` early-step trace
-uses ``jax.profiler`` directly, so don't combine both in one run —
-``start_window`` fails soft with a log line if the profiler is busy).
+Only ONE window can be open at a time (module-level guard;
+``start_window`` fails soft with a log line if the profiler is busy —
+e.g. a caller that drives ``jax.profiler`` itself, as the benchmark's
+traced run does).  These two triggers are the program's only ways to
+open a window.
 """
 
 from __future__ import annotations

@@ -299,7 +299,9 @@ class CliObs:
         try:
             from mx_rcnn_tpu.obs import trace as obs_trace
 
-            if obs_trace.enabled():
+            # obs.trace's one meaning for a train run (train_net collects
+            # spans under obs.enabled alone): write them out at exit
+            if self.cfg.obs.trace:
                 obs_trace.export_chrome_trace(
                     os.path.join(self.record.dir, "trace.json"))
         except Exception:

@@ -21,9 +21,13 @@ Export is the chrome trace event format (load in Perfetto /
 * request lifecycles → ``ph:"b"/"e"`` async events keyed by trace id;
 * :func:`device_trace_events` decodes an ``*.xplane.pb`` (via
   ``utils/xplane.py``) into the same format so host + device merge into
-  ONE timeline: host timestamps use ``time.time_ns()`` (unix epoch) and
-  xplane ``XLine.timestamp_ns`` is the same epoch clock, so the two
-  align without offset surgery (``merge_device_trace``).
+  ONE timeline (``merge_device_trace``).  Host timestamps use
+  ``time.time_ns()`` (unix epoch); the xplane's lines count from the
+  profiler session's start (``XLine.timestamp_ns + offset_ps`` is
+  nanoseconds since then, on the v5e as on the CPU — PERF.md, PR 31),
+  and the file states that start on the epoch clock as
+  ``profile_start_time`` in its ``Task Environment`` plane
+  (:func:`session_start_ns`): the device lines are shifted by it.
 
 IMPORTANT: never call ``span`` (or any host clock) INSIDE jitted code —
 host clocks in traced code measure tracing, not compute.  graphlint rule
@@ -42,20 +46,22 @@ from collections import OrderedDict, deque
 from typing import Dict, List, Optional
 
 _lock = threading.Lock()
-_events: List[dict] = []
+_events: deque = deque(maxlen=100_000)
 _enabled = False
-_cap = 100_000
 _dropped = 0
 _tls = threading.local()
 _ids = itertools.count(1)
 
 
 def enable(cap: int = 100_000) -> None:
-    """Start collecting spans (bounded buffer of ``cap`` events; overflow
-    is counted, never grows memory)."""
-    global _enabled, _cap
+    """Start collecting spans into a ring of the NEWEST ``cap`` events:
+    once full, each new event pushes the oldest out (counted by
+    :func:`dropped`), so on a long run the window someone asks for last
+    is the one that is there.  Memory never grows."""
+    global _enabled, _events
     with _lock:
-        _cap = int(cap)
+        if int(cap) != _events.maxlen:
+            _events = deque(_events, maxlen=int(cap))
         _enabled = True
 
 
@@ -89,15 +95,15 @@ def events() -> List[dict]:
 def _emit(ev: dict) -> None:
     global _dropped
     with _lock:
-        if len(_events) >= _cap:
-            _dropped += 1
-            return
+        if len(_events) == _events.maxlen:
+            _dropped += 1   # the oldest event falls off the ring
         _events.append(ev)
 
 
 def _now_us() -> float:
-    # wall clock, not perf_counter: xplane device lines timestamp in
-    # ns-since-epoch, so host events on the same clock merge cleanly
+    # wall clock, not perf_counter: an xplane file states its session's
+    # start on this clock (session_start_ns), so device lines can be laid
+    # beside host events; as a float of epoch us a stamp is good to 0.25 us
     return time.time_ns() / 1e3
 
 
@@ -247,20 +253,43 @@ def export_chrome_trace(path: str, extra_events: List[dict] = None) -> str:
     return path
 
 
+def session_start_ns(planes: List[dict]) -> Optional[int]:
+    """The profiler session's start in unix-epoch ns as the file states
+    it: ``profile_start_time`` of the ``Task Environment`` plane.  None
+    where the file has none (then the lines cannot be placed on the
+    host's clock from the file alone).  On the v5e the device tracer
+    starts inside ``start_trace``, a millisecond or more after this
+    stamp: lines shifted by it read 1.1-1.2 ms early against the host's
+    own stamps (PERF.md, PR 31) — near enough to look at side by side,
+    not to say which span covers a 50 us gap
+    (``benchmark/hostspans.py`` ties the clocks at a sync for that)."""
+    from mx_rcnn_tpu.utils.xplane import plane_stats
+
+    for plane in planes:
+        if plane.get("name") == "Task Environment":
+            start = plane_stats(plane).get("profile_start_time")
+            if isinstance(start, int) and start > 0:
+                return start
+    return None
+
+
 def device_trace_events(source) -> List[dict]:
     """Decode an ``*.xplane.pb`` path (or pre-parsed planes) into chrome
-    duration events, one pid per device plane, one tid per XLine.  Event
-    start = ``XLine.timestamp_ns + offset_ps`` — the same unix-epoch ns
-    clock host spans use, so the merged file lines up."""
+    duration events, one pid per device plane, one tid per XLine.  Within
+    the file an event starts ``XLine.timestamp_ns + offset_ps`` after the
+    profiler session began; the events returned are shifted by
+    :func:`session_start_ns` onto the unix-epoch clock host spans use,
+    and left as the file has them where it states no start."""
     from mx_rcnn_tpu.utils.xplane import device_planes, parse_xspace
 
     planes = parse_xspace(source) if isinstance(source, str) else source
+    start_us = (session_start_ns(planes) or 0) / 1e3
     out: List[dict] = []
     for plane in device_planes(planes):
         pid = f"device:{plane.get('name', '?')}"
         emd = plane.get("event_metadata", {})
         for line in plane["lines"]:
-            base_us = line.get("timestamp_ns", 0) / 1e3
+            base_us = start_us + line.get("timestamp_ns", 0) / 1e3
             tid = line.get("display_name") or line.get("name", "")
             for ev in line["events"]:
                 md = emd.get(ev.get("metadata_id"), {})
@@ -277,7 +306,8 @@ def device_trace_events(source) -> List[dict]:
 def merge_device_trace(path: str, trace_dir: str) -> str:
     """Export host spans merged with the newest device trace under
     ``trace_dir`` (a ``jax.profiler`` output directory) into one
-    chrome-trace file."""
+    chrome-trace file, the device lines shifted onto the host's clock by
+    the session start the file states (:func:`device_trace_events`)."""
     from mx_rcnn_tpu.obs.profiler import newest_xplane
 
     pb = newest_xplane(trace_dir)

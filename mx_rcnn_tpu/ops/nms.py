@@ -266,26 +266,29 @@ def _run_sweep(
     backend") instead of silently interpreting.
     """
     k = alive0.shape[-1]
-    if _resolve_backend(backend, k, t) == "pallas":
-        from mx_rcnn_tpu.ops.nms_pallas import suppression_sweep_pallas
+    # one name for the sweep whatever implements it (metadata only): the
+    # benchmark's nms.device_ms reads the device time under it
+    with jax.named_scope("nms_sweep"):
+        if _resolve_backend(backend, k, t) == "pallas":
+            from mx_rcnn_tpu.ops.nms_pallas import suppression_sweep_pallas
 
-        # at the padding tile of 256 the (T, K) IoU slab alone is ~12.3 MB
-        # for K=12032 and once compiled within 48 KB of the 16 MiB scoped
-        # VMEM limit (under jvp(vmap(...))); the kernel tile halves the
-        # slab at the same total work
-        tp = _KERNEL_TILE if t % _KERNEL_TILE == 0 else t
+            # at the padding tile of 256 the (T, K) IoU slab alone is ~12.3
+            # MB for K=12032 and once compiled within 48 KB of the 16 MiB
+            # scoped VMEM limit (under jvp(vmap(...))); the kernel tile
+            # halves the slab at the same total work
+            tp = _KERNEL_TILE if t % _KERNEL_TILE == 0 else t
 
-        def pallas_one(bx, al):
-            return suppression_sweep_pallas(bx, al, iou_threshold, tp,
-                                            interpret=interpret)
+            def pallas_one(bx, al):
+                return suppression_sweep_pallas(bx, al, iou_threshold, tp,
+                                                interpret=interpret)
 
+            if boxes_sorted.ndim == 3:
+                return jax.vmap(pallas_one)(boxes_sorted, alive0)
+            return pallas_one(boxes_sorted, alive0)
         if boxes_sorted.ndim == 3:
-            return jax.vmap(pallas_one)(boxes_sorted, alive0)
-        return pallas_one(boxes_sorted, alive0)
-    if boxes_sorted.ndim == 3:
-        return _suppression_sweep_batched(boxes_sorted, alive0,
-                                          iou_threshold, t)
-    return _suppression_sweep(boxes_sorted, alive0, iou_threshold, t)
+            return _suppression_sweep_batched(boxes_sorted, alive0,
+                                              iou_threshold, t)
+        return _suppression_sweep(boxes_sorted, alive0, iou_threshold, t)
 
 
 def _sorted_survivors(

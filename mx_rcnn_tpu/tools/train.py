@@ -14,8 +14,10 @@ ICI, see ``parallel/dp.py``).
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import logging
+import time
 
 import jax
 
@@ -26,6 +28,7 @@ from mx_rcnn_tpu.core.train import setup_training
 from mx_rcnn_tpu.data import (AnchorLoader, cache_from_config,
                               decode_pool_from_config, load_gt_roidb)
 from mx_rcnn_tpu.models import build_model
+from mx_rcnn_tpu.obs import trace as obs_trace
 from mx_rcnn_tpu.utils.checkpoint import restore_state
 
 logger = logging.getLogger("mx_rcnn_tpu")
@@ -113,13 +116,38 @@ def _check_spe(saved_spe, steps_per_epoch: int, prefix: str) -> None:
             f"last epoch checkpoint instead")
 
 
+def _collects_spans(fn):
+    """With ``cfg.obs.enabled`` the wrapped entry collects ``obs/trace.py``
+    spans while it runs, whoever calls it, and counts compile seconds from
+    its start (``obs/metrics.py — LoweringCounter``); the spans stay in
+    ``obs_trace.events()`` afterwards.  Collection that was already on
+    (the CLI's ``obs.trace``) is left as it was found."""
+    @functools.wraps(fn)
+    def wrapper(cfg, **kw):
+        if not cfg.obs.enabled:
+            return fn(cfg, **kw)
+        from mx_rcnn_tpu.obs.metrics import LoweringCounter
+
+        LoweringCounter._ensure_listener()
+        if obs_trace.enabled():
+            return fn(cfg, **kw)
+        obs_trace.enable(cfg.obs.trace_cap)
+        try:
+            return fn(cfg, **kw)
+        finally:
+            obs_trace.disable()
+
+    return wrapper
+
+
+@_collects_spans
 def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
               end_epoch: int = None, lr: float = None, lr_step: str = None,
               num_devices: int = 1, frequent: int = None, seed: int = 0,
               pretrained: str = None, pretrained_epoch: int = 0,
               roidb=None, dataset_kw: dict = None,
               frozen_prefixes=None, mode: str = "e2e", proposals=None,
-              init_from=None, profile_dir: str = None, dcn_size: int = 1,
+              init_from=None, dcn_size: int = 1,
               resume=False, stop_flag=None,
               device_cache: bool = False, fault_plan: str = None,
               run_record=None, step_callback=None,
@@ -164,6 +192,11 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     ``core.fit.fit`` (instrumentation hooks — ``tools/obs_smoke.py`` uses
     them to time steps and count per-epoch lowerings); a ``fault_plan``'s
     injector chains in front of a caller ``step_callback``.
+    With ``cfg.obs.enabled`` the start is laid out in spans
+    (``obs/trace.py``, collected for any caller): ``setup.loader`` (roidb
+    to loader), ``setup.init`` (the init program and the optimizer's
+    slots), ``setup.load`` (``init_from`` / the pretrained graft),
+    ``setup.resume``, then the ``train.*`` spans of ``core.fit.fit``.
     """
     if cfg.quant.enabled:
         # quantization is inference-only (docs/PERF.md "Quantized
@@ -175,6 +208,7 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
             "config and enable quant at test/serve/export time")
     if end_epoch is None:
         end_epoch = cfg.default.e2e_epoch
+    t_loader = time.perf_counter()
     if roidb is None:
         _, roidb = load_gt_roidb(cfg, training=True, **(dataset_kw or {}))
     logger.info("[%s] training on %d roidb images", mode, len(roidb))
@@ -229,6 +263,8 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
         loader = StreamLoader(roidb, cfg, **loader_kw)
     else:
         loader = AnchorLoader(roidb, cfg, **loader_kw)
+    obs_trace.complete("setup.loader",
+                       (time.perf_counter() - t_loader) * 1e3)
     if shard is not None:
         logger.info("loader shard %d/%d: this process decodes %d of %d "
                     "rows per batch", shard[0], shard[1],
@@ -244,23 +280,28 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     model = build_model(cfg)
     bh, bw = cfg.bucket.shapes[0]
     key = jax.random.PRNGKey(seed)
-    state, tx = setup_training(
-        model, cfg, key, (cfg.train.batch_images, bh, bw, 3),
-        steps_per_epoch, base_lr=lr, lr_step=lr_step,
-        frozen_prefixes=frozen_prefixes)
+    with obs_trace.span("setup.init"):
+        state, tx = setup_training(
+            model, cfg, key, (cfg.train.batch_images, bh, bw, 3),
+            steps_per_epoch, base_lr=lr, lr_step=lr_step,
+            frozen_prefixes=frozen_prefixes)
 
     if pretrained:
         from mx_rcnn_tpu.utils.pretrained import load_pretrained_into
 
-        state = load_pretrained_into(state, pretrained, pretrained_epoch, cfg)
+        with obs_trace.span("setup.load", source="pretrained"):
+            state = load_pretrained_into(state, pretrained,
+                                         pretrained_epoch, cfg)
         logger.info("grafted pretrained backbone from %s", pretrained)
     if init_from is not None:
         from mx_rcnn_tpu.utils.checkpoint import load_param
 
-        p, s = load_param(*init_from)
-        state = state._replace(params=p, batch_stats=s)
+        with obs_trace.span("setup.load", source="init_from"):
+            p, s = load_param(*init_from)
+            state = state._replace(params=p, batch_stats=s)
         logger.info("initialized params from %s epoch %d", *init_from)
     data_cursor = None
+    t_resume = time.perf_counter()
     if resume == "auto" and begin_epoch == 0:
         # integrity-verified resume (ft/integrity.py): scan candidates
         # newest→oldest by manifest step, verify checksums, fall back past
@@ -354,6 +395,9 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     elif begin_epoch > 0:
         state = restore_state(state, prefix, begin_epoch)
         logger.info("resumed from %s epoch %d", prefix, begin_epoch)
+    if resume or begin_epoch > 0:
+        obs_trace.complete("setup.resume",
+                           (time.perf_counter() - t_resume) * 1e3)
 
     mesh = None
     if multiproc:
@@ -391,7 +435,7 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
         state = fit(model, cfg, state, tx, loader, end_epoch, key,
                     begin_epoch=begin_epoch, prefix=prefix,
                     frequent=frequent, mesh=mesh, mode=mode,
-                    profile_dir=profile_dir, stop_flag=stop_flag,
+                    stop_flag=stop_flag,
                     device_cache=device_cache, step_callback=step_callback,
                     run_record=run_record,
                     epoch_end_callback=epoch_end_callback,
@@ -501,8 +545,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "kwargs, e.g. \"{'num_images': 32}\" (synthetic "
                         "sizing for smokes and the crash-loop driver)")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--profile_dir", default=None,
-                   help="capture a jax.profiler trace of early steps here")
     p.add_argument("--elastic", action="store_true",
                    help="elastic training (ft/elastic.py, docs/FT.md "
                         "'Elasticity'): watch topology directives at "
@@ -647,7 +689,7 @@ def main(argv=None):
                       num_devices=args.num_devices, frequent=args.frequent,
                       seed=args.seed, pretrained=args.pretrained,
                       pretrained_epoch=args.pretrained_epoch,
-                      profile_dir=args.profile_dir, dcn_size=args.dcn_size,
+                      dcn_size=args.dcn_size,
                       resume=args.resume, stop_flag=lambda: stop["flag"],
                       device_cache=args.device_cache,
                       fault_plan=args.fault_plan,

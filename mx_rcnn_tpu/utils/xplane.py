@@ -12,7 +12,8 @@ Field numbers follow ``tensorflow/core/profiler/protobuf/xplane.proto``
 (stable since 2020):
 
 * XSpace.planes = 1
-* XPlane: id=1, name=2, lines=3, event_metadata(map)=4, stat_metadata=5
+* XPlane: id=1, name=2, lines=3, event_metadata(map)=4, stat_metadata=5,
+  stats=6
 * XLine: id=1, name=2, timestamp_ns=3, events=4, display_name=11
 * XEvent: metadata_id=1, offset_ps=2, duration_ps=3, stats=4
 * XEventMetadata: id=1, name=2, display_name=4
@@ -149,8 +150,12 @@ def _parse_named_metadata(buf: bytes) -> Dict:
 
 
 def _parse_plane(buf: bytes) -> Dict:
-    plane: Dict = {"lines": [], "event_metadata": {}, "stat_metadata": {}}
+    plane: Dict = {"lines": [], "event_metadata": {}, "stat_metadata": {},
+                   "stats": []}
     for f, wt, v in _fields(buf):
+        if f == 6:
+            plane["stats"].append(_parse_stat(v))
+            continue
         if f == 2:
             plane["name"] = v.decode("utf-8", "replace")
         elif f == 3:
@@ -173,6 +178,16 @@ def parse_xspace(path: str) -> List[Dict]:
         if f_ == 1:
             planes.append(_parse_plane(v))
     return planes
+
+
+def plane_stats(plane: Dict) -> Dict:
+    """A plane's own stats by name (the ``Task Environment`` plane states
+    the profiler session's ``profile_start_time`` / ``profile_stop_time``
+    there, in unix-epoch ns)."""
+    smd = plane.get("stat_metadata", {})
+    return {smd.get(s.get("metadata_id"), {}).get("name",
+                                                  str(s.get("metadata_id"))):
+            s.get("value") for s in plane.get("stats", [])}
 
 
 def event_rows(plane: Dict) -> Iterator[Dict]:

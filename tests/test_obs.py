@@ -275,6 +275,13 @@ def test_trace_buffer_is_bounded():
                 pass
         assert len(obs_trace.events()) == 10
         assert obs_trace.dropped() == 40
+        # a ring: the ten kept are the newest, oldest first
+        assert [e["name"] for e in obs_trace.events()] == [
+            f"s{i}" for i in range(40, 50)]
+        # a smaller cap keeps the newest of what is held
+        obs_trace.enable(cap=3)
+        assert [e["name"] for e in obs_trace.events()] == [
+            "s47", "s48", "s49"]
     finally:
         obs_trace.disable()
 
