@@ -54,10 +54,15 @@ _BACKEND = "auto"
 # the kernel's tile: whole 128-lane registers, independent of the padding
 # tile (greedy NMS is exact at any tile size)
 _KERNEL_TILE = 128
-# the kernel holds a (tile, K) fp32 IoU slab and several temporaries of
-# that shape under the 16 MiB scoped-VMEM default.  K=12032 (the 12000-box
+# what the kernel holds in VMEM grows with K through its (K, 4) box block
+# alone: padded to whole 128-lane rows it is 512 B a box, twice that under
+# vmap (the next image's block is fetched while this one runs) — the IoU
+# blocks are (tile, 1024) at most whatever K is.  K=12032 (the 12000-box
 # recipe) compiles and matches the jnp sweep on a v5e (chip_smoke.py checks
-# it); nothing larger has run, so the bound stays where it was
+# it); compiled for a described v5e, under the train step's vmap K=14336
+# fits the 16 MiB scoped-VMEM default and K=15360 does not (alone, 24576
+# fits).  Nothing above 12032 has run, so the bound stays where it was: an
+# input between them fails in the compiler, loudly
 _KERNEL_MAX_K = 16384
 
 
@@ -79,7 +84,7 @@ def _resolve_backend(backend: Optional[str], k: int, tile: int) -> str:
     ``tile``.  Off-TPU that is the jnp sweep.  On a TPU it is the kernel
     for every input of at least one tile; an input the kernel cannot take
     (a tile that is not whole 128-lane registers, or more boxes than its
-    VMEM slab holds) raises instead of quietly running the slower sweep —
+    VMEM blocks hold) raises instead of quietly running the slower sweep —
     pass ``backend='jnp'`` to choose that one.  Inputs smaller than one
     tile (k < tile_size, so tile == k) have no tiling for the kernel to
     do and run the jnp single-tile sweep."""
@@ -272,10 +277,10 @@ def _run_sweep(
         if _resolve_backend(backend, k, t) == "pallas":
             from mx_rcnn_tpu.ops.nms_pallas import suppression_sweep_pallas
 
-            # at the padding tile of 256 the (T, K) IoU slab alone is ~12.3
-            # MB for K=12032 and once compiled within 48 KB of the 16 MiB
-            # scoped VMEM limit (under jvp(vmap(...))); the kernel tile
-            # halves the slab at the same total work
+            # the kernel runs at its own tile whatever the padding tile is
+            # (greedy NMS is exact at any tile): 128 is what the chip runs
+            # proved, and keeps the fixed point's (T, T) chain block at 16
+            # vector registers
             tp = _KERNEL_TILE if t % _KERNEL_TILE == 0 else t
 
             def pallas_one(bx, al):

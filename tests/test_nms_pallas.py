@@ -60,6 +60,93 @@ def test_pallas_dense_cluster():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _sweeps(boxes, alive, thr=0.5, tile=128):
+    """(kernel, oracle) full keep masks over already score-sorted boxes."""
+    from mx_rcnn_tpu.ops.nms import _suppression_sweep
+    from mx_rcnn_tpu.ops.nms_pallas import suppression_sweep_pallas
+
+    boxes, alive = jnp.asarray(boxes), jnp.asarray(alive)
+    got = suppression_sweep_pallas(boxes, alive, thr, tile, interpret=True)
+    want = _suppression_sweep(boxes, alive, thr, tile)
+    return np.asarray(got), np.asarray(want)
+
+
+def _disjoint(k):
+    """k sorted boxes that overlap nothing: 10x10 on a 20-pixel grid."""
+    gx, gy = np.arange(k) % 64, np.arange(k) // 64
+    x1, y1 = 20.0 * gx, 20.0 * gy
+    return np.stack([x1, y1, x1 + 9, y1 + 9], axis=1).astype(np.float32)
+
+
+# what the (T, K) slab could not get wrong and the loops over column chunks
+# can: (suppressor, victim) ranks at the ends of the loops' range and at the
+# seams between chunks of every width (1024 halving down to the tile)
+@pytest.mark.parametrize("k,pairs", [
+    (1024, [(5, 1000)]),                    # tile 0 suppresses the last tile
+    (1024, [(127, 1023), (128, 1022)]),     # last column of a chunk, first
+    (640, [(255, 600), (256, 601)]),        # of the next, at several seams
+    (640, [(511, 639), (512, 638)]),
+    (384, [(0, 383), (127, 256), (255, 257)]),
+    (256, [(127, 128), (0, 255)]),          # two tiles: one chunk, no more
+    (128, [(0, 127), (3, 4)]),              # one tile: no loop runs at all
+    (2304, [(1023, 2200), (1024, 2201), (2047, 2303), (2048, 2302),
+            (2175, 2176), (1100, 1290)]),   # the 1024-wide chunks' seams
+])
+def test_pallas_sweep_suppressor_at_chunk_seams(k, pairs):
+    boxes = _disjoint(k)
+    for s, v in pairs:
+        boxes[v] = boxes[s] + np.float32(1.0)   # IoU 0.68 with s alone
+    got, want = _sweeps(boxes, np.ones(k, bool))
+    np.testing.assert_array_equal(got, want)
+    assert not want[[v for _, v in pairs]].any()
+    assert want.sum() == k - len(pairs)
+
+
+def test_pallas_sweep_dead_suppressor_across_tiles():
+    """A box suppressed in tile 0 suppresses nothing later: the chunk's
+    keep values, not its overlaps alone, decide."""
+    k = 512
+    boxes = _disjoint(k)
+    boxes[100] = boxes[3] + np.float32(1.0)     # 3 kills 100 (IoU 0.68)
+    boxes[400] = boxes[100] + np.float32(1.0)   # 100 overlaps 400; 3 not
+    got, want = _sweeps(boxes, np.ones(k, bool))
+    np.testing.assert_array_equal(got, want)
+    assert not want[100] and want[400]
+
+
+@pytest.mark.parametrize("k", [128, 256, 384, 640, 1152, 2432])
+def test_pallas_sweep_matches_jnp_random(k):
+    """K of one and two tiles, and multiples of the tile that are not
+    multiples of 256, 512 or 1024 (at 2432 tile 15 starts at 1920 = 1024 +
+    512 + 256 + 128: every loop width runs)."""
+    rng = np.random.RandomState(100 + k)
+    boxes, _ = _rand(rng, k)
+    alive = rng.uniform(size=k) > 0.05
+    got, want = _sweeps(np.asarray(boxes), alive)
+    np.testing.assert_array_equal(got, want)
+    assert 0 < want.sum() < alive.sum()
+
+
+@pytest.mark.parametrize("tile_i", [1, 2, 4])
+def test_pallas_sweep_is_causal(tile_i):
+    """Boxes at or after tile i never reach keep[: i*T]."""
+    k, t = 640, 128
+    rng = np.random.RandomState(tile_i)
+    boxes = np.asarray(_rand(rng, k)[0])
+    alive = np.ones(k, bool)
+    other = boxes.copy()
+    other[tile_i * t:] = np.asarray(_rand(rng, k)[0])[tile_i * t:]
+    other_alive = alive.copy()
+    other_alive[tile_i * t + 7:] = False
+    base, want = _sweeps(boxes, alive)
+    np.testing.assert_array_equal(base, want)
+    for b, a in ((other, alive), (boxes, other_alive)):
+        got, want = _sweeps(b, a)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got[:tile_i * t], base[:tile_i * t])
+        assert not np.array_equal(got, base)    # the change did reach later
+
+
 def test_set_nms_backend_validation():
     import importlib
 
