@@ -8,6 +8,13 @@ seed), ``run_record`` (a hook that stamps the fit loop's events with the
 host clock), ``step_callback`` and ``stop_flag``.  The window's edges are
 the fit loop's synced ``log`` events (``benchmark/window.py``).
 
+This file is the detector program's half of the run: its configuration,
+``roidb``, seed-made weights and their hand-over as a checkpoint, the probe
+of the first steps, the call of ``train_net``, the reference and the
+comparison.  What no program decides (the window, the profiler, the memory
+read, ``failed``, ``setup_s``, the result object) is
+``drivers/measure.py``'s, shared with every driver kind.
+
 What the timed path produced in its first steps is read where the fit
 loop holds it: the callbacks run inside ``fit``'s frame, whose ``state``
 and ``metrics`` locals are the one compiled step's own output.  After the
@@ -19,35 +26,18 @@ steps from the same seed-made weights and ``compare_training`` decides
 
 from __future__ import annotations
 
-import math
 import os
 import shutil
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, List
+from typing import Dict, List
 
 import numpy as np
 
+from benchmark.drivers import measure
+from benchmark.drivers.measure import CellFailure
 from benchmark.reference.step import tree_paths
-
-
-class CellFailure(RuntimeError):
-    """The run cannot produce a result line."""
-
-
-class Events:
-    """``run_record`` hook: the fit loop's events with their host time."""
-
-    def __init__(self, on_log: Callable[[float, Dict], None]):
-        self.rows: List = []
-        self._on_log = on_log
-
-    def event(self, kind: str, **fields) -> None:
-        now = time.perf_counter()
-        self.rows.append((now, kind, fields))
-        if kind == "log":
-            self._on_log(now, fields)
 
 
 def _fit_locals() -> Dict:
@@ -145,13 +135,7 @@ def run(cell: Dict, *, seed: int, seconds: float, trace: bool,
     from mx_rcnn_tpu import native
 
     native.backend()   # the one child process (g++) ends before JAX starts
-    import jax
-
-    devices = jax.devices()
-    if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
-        raise CellFailure(
-            f"cell needs {cell['chips']} tpu chip(s); JAX found "
-            f"{len(devices)} x {devices[0].platform}")
+    measure.need_chips(cell["chips"])
     return run_cell(cell, seed=seed, seconds=seconds, trace=trace,
                     t_start=t_start)
 
@@ -176,9 +160,7 @@ def run_cell(cell: Dict, *, seed: int, seconds: float, trace: bool,
     from mx_rcnn_tpu.config import generate_config
     from mx_rcnn_tpu.tools.train import train_net
 
-    from benchmark import trace as trace_mod
     from benchmark import traffic_gen as traffic_mod
-    from benchmark import window
     from benchmark.reference import compare, nets, step as ref_step
 
     seed32 = seed % (2 ** 31 - 1)
@@ -194,7 +176,11 @@ def run_cell(cell: Dict, *, seed: int, seconds: float, trace: bool,
         if isinstance(v, list) else v for k, v in overrides.items()})
 
     work = tempfile.mkdtemp(prefix="bench_")
-    phases = {"imports_s": time.perf_counter() - t_start}
+    m = measure.Measurement(
+        chips=chips, warmup_steps=traffic["warmup_steps"],
+        log_every=cfg.default.frequent, seconds=seconds, trace=trace,
+        work=work, t_start=t_start)
+    m.mark("imports_s")
     try:
         net = config["network"]
         items = traffic_mod.make_images(
@@ -203,7 +189,7 @@ def run_cell(cell: Dict, *, seed: int, seconds: float, trace: bool,
         roidb = traffic_mod.write_roidb(
             items, os.path.join(work, "images"),
             traffic["epoch_steps"] * n_total)
-        phases["data_s"] = time.perf_counter() - t_start
+        m.mark("data_s")
         weights = nets.make_weights(net, seed32)
         p0 = jax.device_get(weights)
         del weights
@@ -212,70 +198,18 @@ def run_cell(cell: Dict, *, seed: int, seconds: float, trace: bool,
         probe = Probe(p0, config["optimizer"], check["steps"])
         del p0
 
-        edges = window.Edges(traffic["warmup_steps"], seconds, trace)
-        log_loss: List = []
-        held = [0]
-        trace_dir = os.path.join(work, "trace")
-
-        def on_log(now: float, fields: Dict) -> None:
-            log_loss.append(float(fields.get("loss", math.nan)))
-            action = edges.add(now, int(fields["nbatch"]))
-            if action == "start_trace":
-                # the device alone: Python call tracing slows the thread
-                # that dispatches, and the host's own events (15 million
-                # in 20 steps, one per chunk of every input transfer's
-                # relayout) slow the input path until the chip starves
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                opts.host_tracer_level = 0
-                jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            elif action == "stop_trace":
-                jax.profiler.stop_trace()
-            elif action == "close":
-                # what the chip holds while the step runs: live arrays plus
-                # the memory reserved for the loaded programs' scratch
-                for d in jax.local_devices()[:chips]:
-                    m = d.memory_stats() or {}
-                    held[0] = max(held[0], m.get("bytes_in_use", 0)
-                                  + m.get("bytes_reserved", 0))
-
-        phases["weights_s"] = time.perf_counter() - t_start
+        m.mark("weights_s")
         state = train_net(
             cfg, prefix=None, end_epoch=1, lr=config["optimizer"]["lr"],
             num_devices=chips, seed=seed32, roidb=roidb,
             init_from=(os.path.join(work, "init", "w"), 0),
-            run_record=Events(on_log), step_callback=probe.on_step,
-            stop_flag=lambda: edges.closed is not None)
-        if edges.closed is None:
-            if edges.trace_from is not None and edges.trace_to is None:
-                jax.profiler.stop_trace()
-            raise CellFailure("the epoch ended before the window closed: "
-                              "raise the traffic file's epoch_steps")
-        stats = edges.stats(n_total)
-        setup_s = edges.logs[edges.opened][0] - t_start
-        mem = [d.memory_stats() or {} for d in jax.local_devices()[:chips]]
-        # the allocator's peak leaves out the programs' scratch, which it
-        # books as reserved (PERF.md section 6): the peak is at least what
-        # was held at the closing edge
-        peak = max(max((m.get("peak_bytes_in_use", 0) for m in mem),
-                       default=0), held[0])
-        limit = max((m.get("bytes_limit", 0) for m in mem), default=0)
-        counters = {}
-        if trace:
-            from mx_rcnn_tpu.obs.metrics import registry
-
-            reg = registry()
-            for name in ("train.data_wait_ms", "train.step_ms"):
-                h = reg.hist(name)
-                if h is not None and h.mean is not None:
-                    counters[name] = h.mean
+            run_record=m.events, step_callback=probe.on_step,
+            stop_flag=m.closed)
+        m.end(n_total)
         del state
         program = probe.result()
 
-        reduced = None
-        if trace:
-            reduced = trace_mod.reduce_dir(
-                trace_dir, steps=cfg.default.frequent, chips=chips)
+        m.reduce()
 
         # ---- the plain reference, on the freed chip ---------------------
         t_ref = time.perf_counter()
@@ -293,19 +227,6 @@ def run_cell(cell: Dict, *, seed: int, seconds: float, trace: bool,
     finally:
         shutil.rmtree(work, ignore_errors=True)
 
-    failed = sum(cfg.default.frequent for v in
-                 log_loss[edges.opened + 1:edges.closed + 1]
-                 if not math.isfinite(v))
-    return {
-        "correct": ok, "attempted": stats["steps"], "failed": failed,
-        "end_to_end": {"train_imgs_per_s": stats["imgs_per_s"],
-                       "setup_s": setup_s},
-        "window": stats, "setup_s": setup_s, "peak_bytes": peak,
-        "bytes_limit": limit, "counters": counters, "trace": reduced,
-        "reference_s": ref_s, "numbers": numbers, "notes": notes,
-        "phases": dict(phases, first_log_s=edges.logs[0][0] - t_start),
-        "images_per_step": n_total,
-        "device": {"platform": devices[0].platform,
-                   "kind": devices[0].device_kind, "count": len(devices),
-                   "memory_peak_bytes": peak},
-    }
+    return m.result(
+        correct=ok, numbers=numbers, notes=notes, reference_s=ref_s,
+        end_to_end={"train_imgs_per_s": m.stats["imgs_per_s"]})

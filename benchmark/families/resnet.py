@@ -1,7 +1,7 @@
 """The ResNet C4 family (mx-rcnn ``symbol_resnet.py``): stages 1-3 are the
 backbone, stage 4 is the per-ROI head.  Everything the benchmark knows of
-the family is here: its parameter rows, its plain forward passes and its
-layer table for the operation count."""
+the family is here: its parameter rows, its plain forward passes, its
+layer table for the operation count and (``STAGES``) its step's scopes."""
 
 from __future__ import annotations
 
@@ -9,12 +9,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmark import flops
+from benchmark.families import _detector
 from benchmark.reference.nets import bn_rows, max_pool
 
 BN_EPS = 2e-5
 UNITS = {50: (3, 4, 6, 3), 101: (3, 4, 23, 3)}
 FILTERS = (256, 512, 1024, 2048)
 FEAT_CHANNELS, HEAD_CHANNELS = 1024, 2048
+STAGES = _detector.STAGES
 # the residual branch's last convolution starts small and random: at zero
 # (the program's own init) nothing flows back through the branch in the
 # first step and conv1/conv2 of every unit would go uncompared; at full
@@ -114,14 +117,16 @@ def _half(hw, s):
     return (-(-hw[0] // s), -(-hw[1] // s))
 
 
-def layers(net, image_hw, conv, dense):
-    """Rows of ``benchmark/flops.py`` for an image of ``image_hw``; ``conv``
-    and ``dense`` make a row.  conv0 and stage 1 are frozen with nothing
-    trainable before them (forward only); the first trainable layers need
-    no gradient for their input."""
+def layers(config, traffic):
+    """The whole layer table (``benchmark/flops.py``) of one image of the
+    traffic.  conv0 and stage 1 are frozen with nothing trainable before
+    them (forward only); the first trainable layers need no gradient for
+    their input."""
+    net, image_hw = config["network"], _detector.image_hw(traffic)
+    conv, rois = flops.conv, _detector.rois(config)
     units = UNITS[net["depth"]]
     rows = [conv("conv0", "backbone", 3, 64, 7, 2, _half(image_hw, 2),
-                 "image", "none")]
+                 1, "none")]
     hw, cin = _half(image_hw, 4), 64
     for stage in (1, 2, 3):
         f = FILTERS[stage - 1]
@@ -132,14 +137,14 @@ def layers(net, image_hw, conv, dense):
             pre = f"stage{stage}_unit{u + 1}"
             first = "weight" if (stage == 2 and u == 0) else grad
             rows.append(conv(f"{pre}/conv1", "backbone", cin, f // 4, 1, 1,
-                             hw, "image", first))
+                             hw, 1, first))
             rows.append(conv(f"{pre}/conv2", "backbone", f // 4, f // 4, 3, s,
-                             out, "image", grad))
+                             out, 1, grad))
             rows.append(conv(f"{pre}/conv3", "backbone", f // 4, f, 1, 1, out,
-                             "image", grad))
+                             1, grad))
             if u == 0:
                 rows.append(conv(f"{pre}/sc", "backbone", cin, f, 1, s, out,
-                                 "image", first))
+                                 1, first))
             hw, cin = out, f
     feat_hw = hw
     phw = tuple(net["pooled_size"])
@@ -150,13 +155,14 @@ def layers(net, image_hw, conv, dense):
         pre = f"stage4_unit{u + 1}"
         f = FILTERS[3]
         head_rows.append(conv(f"{pre}/conv1", "rcnn_losses", cin, f // 4, 1,
-                              1, phw, "roi", "both"))
+                              1, phw, rois, "both"))
         head_rows.append(conv(f"{pre}/conv2", "rcnn_losses", f // 4, f // 4,
-                              3, s, out, "roi", "both"))
+                              3, s, out, rois, "both"))
         head_rows.append(conv(f"{pre}/conv3", "rcnn_losses", f // 4, f, 1, 1,
-                              out, "roi", "both"))
+                              out, rois, "both"))
         if u == 0:
             head_rows.append(conv(f"{pre}/sc", "rcnn_losses", cin, f, 1, s,
-                                  out, "roi", "both"))
+                                  out, rois, "both"))
         phw, cin = out, f
-    return rows, feat_hw, head_rows
+    return _detector.table(config, rows, feat_hw, head_rows, FEAT_CHANNELS,
+                           HEAD_CHANNELS)

@@ -1,17 +1,20 @@
 """The VGG16 family (mx-rcnn ``symbol_vgg.py``): conv1_1 .. conv5_3 are the
 backbone, fc6/fc7 with dropout the per-ROI head.  Everything the benchmark
-knows of the family is here: its parameter rows, its plain forward passes
-and its layer table for the operation count."""
+knows of the family is here: its parameter rows, its plain forward passes,
+its layer table for the operation count and (``STAGES``) its step's scopes."""
 
 from __future__ import annotations
 
 import jax
 
+from benchmark import flops
+from benchmark.families import _detector
 from benchmark.reference.nets import conv_rows, max_pool
 
 BLOCKS = (("conv1", 2, 64), ("conv2", 2, 128), ("conv3", 3, 256),
           ("conv4", 3, 512), ("conv5", 3, 512))
 FEAT_CHANNELS, HEAD_CHANNELS = 512, 4096
+STAGES = _detector.STAGES
 
 
 def param_rows(net):
@@ -50,23 +53,25 @@ def head(net, params, pooled, mm, drop_masks=None):
     return x
 
 
-def layers(net, image_hw, conv, dense):
-    """Rows of ``benchmark/flops.py`` for an image of ``image_hw``.  conv1
-    and conv2 are frozen (forward only); conv3_1, the first trainable
-    layer, needs no gradient for its input."""
-    rows, cin, hw = [], 3, tuple(image_hw)
+def layers(config, traffic):
+    """The whole layer table (``benchmark/flops.py``) of one image of the
+    traffic.  conv1 and conv2 are frozen (forward only); conv3_1, the first
+    trainable layer, needs no gradient for its input."""
+    net, rois = config["network"], _detector.rois(config)
+    rows, cin, hw = [], 3, _detector.image_hw(traffic)
     for i, (name, n, f) in enumerate(BLOCKS):
         for j in range(n):
             grad = ("none" if i < 2 else
                     "weight" if (i, j) == (2, 0) else "both")
-            rows.append(conv(f"{name}_{j + 1}", "backbone", cin, f, 3, 1, hw,
-                             "image", grad))
+            rows.append(flops.conv(f"{name}_{j + 1}", "backbone", cin, f, 3,
+                                   1, hw, 1, grad))
             cin = f
         if i < 4:
             hw = (hw[0] // 2, hw[1] // 2)
     ph, pw = net["pooled_size"]
-    head_rows = [dense("fc6", "rcnn_losses", ph * pw * cin, HEAD_CHANNELS,
-                       "roi"),
-                 dense("fc7", "rcnn_losses", HEAD_CHANNELS, HEAD_CHANNELS,
-                       "roi")]
-    return rows, hw, head_rows
+    head_rows = [flops.dense("fc6", "rcnn_losses", ph * pw * cin,
+                             HEAD_CHANNELS, rois),
+                 flops.dense("fc7", "rcnn_losses", HEAD_CHANNELS,
+                             HEAD_CHANNELS, rois)]
+    return _detector.table(config, rows, hw, head_rows, FEAT_CHANNELS,
+                           HEAD_CHANNELS)

@@ -35,10 +35,6 @@ from benchmark import window
 
 GAP_NS = 50e3   # the same 50 us that Reduced.idle_gaps splits at
 UNNAMED = "unnamed"
-# the stages a device op can lie under (core/train.py); what lies under none
-# is step.unscoped_ms
-STAGES = ("backbone", "rpn_head", "rpn_losses", "proposal", "rcnn_losses",
-          "optimizer", "grad_sync")
 # spans whose idle is the log step's: the sync, the log line and the hooks,
 # and the dispatch that follows them (the first after the loop has lost its
 # lead on the device)
@@ -204,11 +200,13 @@ def attribute(reduced, events: Sequence[dict], warmup_steps: int
             "stage_ms": stage_ms}
 
 
-def unscoped_s(reduced) -> Optional[float]:
-    """Device seconds (mean over chips) of ops under none of ``STAGES``."""
+def unscoped_s(reduced, stages: Sequence[str]) -> Optional[float]:
+    """Device seconds (mean over chips) of ops under none of ``stages``,
+    the scopes the cell's family names (its ``STAGES``)."""
     if reduced is None or not reduced.devices:
         return None
-    inside = re.compile(r"(^|[/(])(" + "|".join(STAGES) + r")([/)]|$)")
+    inside = re.compile(r"(^|[/(])(" + "|".join(map(re.escape, stages))
+                        + r")([/)]|$)")
     per = [trace_mod.union_ns([(s, s + d) for _, path, s, d in dev["ops"]
                                if not inside.search(path)]) * 1e-9
            for dev in reduced.devices]

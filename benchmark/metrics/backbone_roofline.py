@@ -13,6 +13,6 @@ def read(ctx):
     if not sec:
         return None
     per_image, _ = flops.least_seconds_per_image(
-        ctx["layers"], ctx["rois"], ctx["peak"], "backbone")
+        ctx["layers"], ctx["peak"], "backbone")
     least = per_image * t.steps * ctx["images_per_step"] / ctx["chips"]
     return 100.0 * least / sec

@@ -10,6 +10,22 @@ that returns a number or nothing.  The last stdout line is the result
 object; the numbers compared for ``correct`` go to stderr beside their
 limits and into the result under ``compared``.  Without the chips the cell
 asks for the run exits non-zero and prints no result.
+
+Nothing here knows a model.  What depends on the model's family comes from
+files the cell's data names, so a cell of a family the benchmark has never
+seen is new files and ``BENCHMARK.json`` entries, and no edit to this one:
+
+* ``configs/<c>.json`` states ``network.family``, and
+  ``families/<family>.py`` exports ``layers(config, traffic)``, the whole
+  layer table of one sample (rows as ``benchmark/flops.py`` describes them),
+  and ``STAGES``, the step's named scopes;
+* ``drivers/<kind>.py`` exports ``run(cell, *, seed, seconds, trace,
+  t_start)`` and ``CellFailure``; ``run`` returns what
+  ``drivers/measure.py::Measurement.result`` assembles, and a driver through
+  the fit loop calls that class for the measurement's half (window edges,
+  profiler, memory, ``failed``, ``setup_s``);
+* ``metrics/<name>.py`` exports ``read(ctx)``; a ``per_layer`` entry with a
+  ``workloads`` list is read in those cells alone.
 """
 
 from __future__ import annotations
@@ -66,8 +82,9 @@ def metrics_of(result: dict, cell: dict, bench: dict, trace: bool) -> dict:
     a trace (the driver's ``end_to_end``, by name), its per-layer metrics
     with one.  A reader gets the driver's result and the whole loaded cell:
     ``cell`` (with its ``config`` and ``traffic``), ``chips``, the chip's
-    ``peak`` and the configuration's ``layers`` at the traffic's image size
-    (``benchmark/flops.py``), so a new metric is a new file."""
+    ``peak``, and from the configuration's family its ``layers`` for one
+    sample of the traffic (``benchmark/flops.py``) and its ``stages``, so a
+    new metric is a new file."""
     name = cell["name"]
     if not trace:
         return {m["name"]: {"value": result["end_to_end"][m["name"]],
@@ -78,8 +95,8 @@ def metrics_of(result: dict, cell: dict, bench: dict, trace: bool) -> dict:
     config = cell["config"]
     ctx = dict(result, cell=cell, chips=cell["chips"],
                peak=flops.peaks(result["device"]["kind"]),
-               layers=flops.layer_table(config, cell["traffic"]["image_hw"]),
-               rois=config["train"]["batch_rois"])
+               layers=flops.layer_table(config, cell["traffic"]),
+               stages=flops.family(config["network"]).STAGES)
     out = {}
     for m in bench["per_layer"]:
         if _listed(m, name):

@@ -9,8 +9,11 @@ least ``seconds`` later.  A traced run first traces the two intervals that
 start there (the first absorbs the profiler's start-up, the reduction
 takes the last interval's worth of steps), lets one more interval absorb
 the profiler's write-out, and opens its window at the edge after that.
-The rate is all images of all steps between the two edges over all the
-time between them.
+What the rate counts is said once, here: the rows of the batch of all
+optimizer steps between the two edges over all the time between them
+(``steps x images_per_step / seconds``, ``images_per_step`` being the rows of
+one step's batch over all chips).  For the detectors' cells a row is an
+image; for a cell whose batch is ``(B, S)`` token ids it is a sequence.
 """
 
 from __future__ import annotations
@@ -58,8 +61,9 @@ class Edges:
         return None
 
     def stats(self, images_per_step: int) -> dict:
-        """Steps, seconds, images per second and the slowest stretch (ms
-        per step of the longest interval between consecutive edges)."""
+        """Steps, seconds, rows of the batch per second (``imgs_per_s``)
+        and the slowest stretch (ms per step of the longest interval
+        between consecutive edges)."""
         edges = self.logs[self.opened:self.closed + 1]
         steps = edges[-1][1] - edges[0][1]
         seconds = edges[-1][0] - edges[0][0]

@@ -7,6 +7,7 @@ import os
 import re
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -60,6 +61,68 @@ def test_window_rate_is_all_images_over_all_time():
     # the slow stretch (4.5 s for 20 steps) is in the rate and is the slowest
     assert stats["slowest_ms_per_step"] == pytest.approx(225.0)
     assert stats["mean_ms_per_step"] == pytest.approx(12.5 / 60 * 1e3)
+
+
+# ---- the measurement's half of a driver -----------------------------------
+
+def _measurement(tmp_path, losses, seconds=0.0):
+    """A fit loop's log events through ``drivers/measure.py`` with no
+    program behind them: a log edge every 2 steps, warm-up 4."""
+    from benchmark.drivers import measure
+
+    m = measure.Measurement(chips=1, warmup_steps=4, log_every=2,
+                            seconds=seconds, trace=False, work=str(tmp_path),
+                            t_start=time.perf_counter())
+    m.mark("imports_s")
+    for i, loss in enumerate(losses):
+        m.events.event("step", nbatch=2 * i + 1)
+        m.events.event("log", nbatch=2 * (i + 1), loss=loss)
+    return m
+
+
+def test_measurement_gives_the_result_its_fixed_keys(tmp_path):
+    m = _measurement(tmp_path, [3.0, 2.5, float("nan"), 2.0, 1.5])
+    # opens at the first edge at or after warm-up, closes at the next
+    assert m.closed() and (m.edges.opened, m.edges.closed) == (1, 2)
+    m.end(rows_per_step=16)
+    m.reduce()
+    r = m.result(correct=True, numbers={}, notes={}, reference_s=1.0,
+                 end_to_end={"train_imgs_per_s": m.stats["imgs_per_s"]})
+    assert set(r) == {
+        "correct", "attempted", "failed", "end_to_end", "window", "setup_s",
+        "peak_bytes", "bytes_limit", "counters", "trace", "reference_s",
+        "numbers", "notes", "phases", "images_per_step", "device"}
+    assert r["attempted"] == 2 and r["images_per_step"] == 16
+    # the one interval of the window logged a loss that is not finite
+    assert r["failed"] == 2
+    assert r["end_to_end"] == {"train_imgs_per_s": r["window"]["imgs_per_s"],
+                               "setup_s": r["setup_s"]}
+    assert r["window"]["imgs_per_s"] == pytest.approx(
+        2 * 16 / r["window"]["seconds"])
+    assert 0 < r["phases"]["imports_s"] <= r["phases"]["first_log_s"] \
+        <= r["setup_s"]
+    assert r["trace"] is None and r["counters"] == {}
+    assert set(r["device"]) == {"platform", "kind", "count",
+                                "memory_peak_bytes"}
+    assert r["device"]["memory_peak_bytes"] == r["peak_bytes"]
+
+
+def test_measurement_fails_the_cell_where_the_window_never_closed(tmp_path):
+    from benchmark.drivers import measure, train
+
+    m = _measurement(tmp_path, [3.0, 2.5, 2.0], seconds=3600.0)
+    assert not m.closed() and m.edges.opened == 1
+    with pytest.raises(measure.CellFailure, match="before the window closed"):
+        m.end(rows_per_step=16)
+    # one failure class for every driver kind: run.py catches the driver's
+    assert train.CellFailure is measure.CellFailure
+
+
+def test_no_chip_is_a_cell_failure():
+    from benchmark.drivers import measure
+
+    with pytest.raises(measure.CellFailure, match="needs 1 tpu chip"):
+        measure.need_chips(1)
 
 
 # ---- trace reduction -----------------------------------------------------
@@ -175,7 +238,7 @@ def test_named_scopes_are_read_from_the_programs_in_a_trace(tmp_path):
 def test_reader_returns_nothing_without_a_trace():
     ctx = {"trace": None, "counters": {}, "peak_bytes": 0, "bytes_limit": 0,
            "window": {"slowest_ms_per_step": 200.0}, "layers": [],
-           "rois": 128, "chips": 1, "cell": {}}
+           "stages": (), "chips": 1, "cell": {}}
     for m in BENCH["per_layer"]:
         value = bench_run.read_metric(m["name"], ctx)
         if m["name"] == "fit.slowest_window_ms":
@@ -188,19 +251,19 @@ def test_reader_returns_nothing_without_a_trace():
 
 def test_conv_and_dense_hand_counts():
     conv = {"kind": "conv", "cin": 64, "cout": 128, "k": 3, "stride": 2,
-            "out_hw": [10, 12], "per": "image", "grad": "both",
+            "out_hw": [10, 12], "times": 1, "grad": "both",
             "scope": "backbone"}
     assert flops.forward_flops(conv) == 2 * 9 * 64 * 128 * 120
-    dense = {"kind": "dense", "cin": 4096, "cout": 21, "per": "roi",
+    dense = {"kind": "dense", "cin": 4096, "cout": 21, "times": 128,
              "grad": "both", "scope": "rcnn_losses"}
     assert flops.forward_flops(dense) == 2 * 4096 * 21
     # forward + input gradient + weight gradient; the dense layer per ROI
-    assert flops.step_flops_per_image([conv, dense], 128) == (
+    assert flops.step_flops_per_image([conv, dense]) == (
         3 * 2 * 9 * 64 * 128 * 120 + 3 * 128 * 2 * 4096 * 21)
-    assert flops.step_flops_per_image([conv, dense], 128, "backbone") == (
+    assert flops.step_flops_per_image([conv, dense], "backbone") == (
         3 * 2 * 9 * 64 * 128 * 120)
     frozen = dict(conv, grad="none")
-    assert flops.step_flops_per_image([frozen], 128) == flops.forward_flops(conv)
+    assert flops.step_flops_per_image([frozen]) == flops.forward_flops(conv)
 
 
 def _config(name):
@@ -217,8 +280,8 @@ def test_layer_tables_match_the_compilers_count(name, want_tflop):
     whole 608x1024 bucket; the tables at that extent must land within 3 %
     of that."""
     config = _config(name)
-    table = flops.layer_table(config, config["bucket"])
-    got = flops.step_flops_per_image(table, 128) / 1e12
+    table = flops.layer_table(config, {"image_hw": config["bucket"]})
+    got = flops.step_flops_per_image(table) / 1e12
     assert got == pytest.approx(want_tflop, rel=0.03)
 
 
@@ -228,14 +291,14 @@ def test_padding_is_not_counted(name, image_hw, share):
     """The backbone's operations follow the images' own extent, not the
     bucket's; what runs per ROI does not change."""
     config = _config(name)
-    bucket = flops.layer_table(config, config["bucket"])
-    real = flops.layer_table(config, image_hw)
+    bucket = flops.layer_table(config, {"image_hw": config["bucket"]})
+    real = flops.layer_table(config, {"image_hw": image_hw})
     assert [r["name"] for r in real] == [r["name"] for r in bucket]
-    ratio = (flops.step_flops_per_image(real, 128, "backbone")
-             / flops.step_flops_per_image(bucket, 128, "backbone"))
+    ratio = (flops.step_flops_per_image(real, "backbone")
+             / flops.step_flops_per_image(bucket, "backbone"))
     assert ratio == pytest.approx(share, abs=0.01)
-    assert (flops.step_flops_per_image(real, 128, "rcnn_losses")
-            == flops.step_flops_per_image(bucket, 128, "rcnn_losses"))
+    assert (flops.step_flops_per_image(real, "rcnn_losses")
+            == flops.step_flops_per_image(bucket, "rcnn_losses"))
 
 
 def test_counts_against_cost_analysis_of_a_small_forward():
@@ -244,10 +307,10 @@ def test_counts_against_cost_analysis_of_a_small_forward():
 
     layers = [
         {"kind": "conv", "cin": 3, "cout": 16, "k": 3, "stride": 1,
-         "out_hw": [32, 48], "per": "image", "grad": "none", "scope": "s"},
+         "out_hw": [32, 48], "times": 1, "grad": "none", "scope": "s"},
         {"kind": "conv", "cin": 16, "cout": 32, "k": 3, "stride": 2,
-         "out_hw": [16, 24], "per": "image", "grad": "none", "scope": "s"},
-        {"kind": "dense", "cin": 16 * 24 * 32, "cout": 10, "per": "image",
+         "out_hw": [16, 24], "times": 1, "grad": "none", "scope": "s"},
+        {"kind": "dense", "cin": 16 * 24 * 32, "cout": 10, "times": 1,
          "grad": "none", "scope": "s"}]
 
     def forward(x, k1, k2, w):
@@ -263,9 +326,95 @@ def test_counts_against_cost_analysis_of_a_small_forward():
              (16 * 24 * 32, 10))]
     cost = jax.jit(forward).lower(*args).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-    want = flops.step_flops_per_image(layers, 1)
+    want = flops.step_flops_per_image(layers)
     # XLA leaves out the taps that fall on SAME padding: within 10 %
     assert cost["flops"] == pytest.approx(want, rel=0.10)
+
+
+def test_a_row_may_state_its_own_flops_and_bytes():
+    """The door beside the kinds: a row with no ``kind`` carries the
+    forward ``flops`` and ``bytes`` its family computed, and is counted
+    like any other with its passes and its times a sample."""
+    own = {"name": "scan", "scope": "mixer", "flops": 1.5e9, "bytes": 4e6,
+           "times": 8192, "grad": "both"}
+    assert flops.forward_flops(own) == 1.5e9
+    assert flops.forward_bytes(own) == 4e6
+    assert flops.step_flops_per_image([own]) == 1.5e9 * 3 * 8192
+    peak = {"flops_per_s": 1e12, "bytes_per_s": 1e9}
+    least, bound = flops.least_seconds_per_image([own], peak, "mixer")
+    assert least == pytest.approx(max(1.5e9 / 1e12, 4e6 / 1e9) * 3 * 8192)
+    assert bound == "memory"
+    # a row's own count wins over its kind's
+    conv = flops.conv("c", "mixer", 4, 4, 1, 1, (2, 2), 1, "none")
+    assert flops.forward_flops(dict(conv, flops=7.0)) == 7.0
+    assert flops.forward_bytes(dict(conv, bytes=9.0)) == 9.0
+
+
+@pytest.mark.parametrize("row", [
+    {"name": "x", "kind": "attention", "times": 1, "grad": "both"},
+    {"name": "x", "times": 1, "grad": "both"}])
+def test_a_row_of_no_known_kind_and_no_count_of_its_own_is_refused(row):
+    with pytest.raises(ValueError, match="unknown kind"):
+        flops.forward_flops(row)
+    with pytest.raises(ValueError, match="unknown kind"):
+        flops.forward_bytes(dict(row, flops=1.0))
+
+
+def _data(name):
+    with open(os.path.join(ROOT, "tests", "benchmark", "data", name)) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", sorted(
+    _data("parent_layer_tables.json")["configs"]))
+def test_layer_table_is_the_parents_row_for_row(name):
+    """The family now builds the whole table; it has to be the table
+    ``flops.layer_table`` of the commit before built (recorded from it:
+    ``data/parent_layer_tables.json``), row for row and key for key, and
+    the sums the readers take of it the same numbers."""
+    want = _data("parent_layer_tables.json")["configs"][name]
+    config = _config(name)
+    traffic = bench_run._json("traffic", f"{want['traffic']}.json")
+    table = flops.layer_table(config, traffic)
+    assert len(table) == len(want["rows"])
+    for got, row in zip(table, want["rows"]):
+        assert got == row, row["name"]
+    assert flops.step_flops_per_image(table) == want["step_flops_per_image"]
+    assert (flops.step_flops_per_image(table, "backbone")
+            == want["backbone_flops_per_image"])
+    least, bound = flops.least_seconds_per_image(
+        table, flops.peaks("TPU v5 lite"), "backbone")
+    assert least == want["backbone_least_seconds_per_image"]
+    assert bound == want["backbone_bound"]
+
+
+_PARENT = _data("parent_readers.json")
+
+
+@pytest.fixture(scope="module")
+def parent_result():
+    """The driver result the parent's readers were run on: the recorded
+    trace, reduced, under the recorded counters and memory."""
+    neutral = _recorded()
+    return dict(_PARENT["result"], trace=trace.Reduced(
+        neutral, steps=neutral["steps"], chips=1))
+
+
+@pytest.mark.parametrize("cell", sorted(_PARENT["values"]))
+@pytest.mark.parametrize("metric", _PARENT["asked"])
+def test_reader_reads_what_the_parents_read(parent_result, cell, metric):
+    """Every reader the parent had, on the recorded trace with the parent's
+    ``ctx``: the same number as the commit before to the last bit, and
+    nothing where it gave nothing (the span readers, which need a live
+    program)."""
+    want = _PARENT["values"][cell]
+    one = dict(BENCH, per_layer=[m for m in BENCH["per_layer"]
+                                 if m["name"] == metric])
+    got = bench_run.metrics_of(dict(parent_result),
+                               bench_run.load_cell(cell), one, True)
+    assert set(got) == {metric} & set(want)
+    if metric in want:
+        assert got[metric]["value"] == want[metric]
 
 
 def test_unknown_device_kind_has_no_peaks():
@@ -298,15 +447,45 @@ def test_manifest_names_units_and_sources():
         assert 0 < m["bound"] <= 0.1
 
 
+DETECTOR_SCOPED = {
+    "backbone.device_ms", "backbone_roofline", "proposal.device_ms",
+    "nms.device_ms", "roi_align.device_ms", "roi_head.device_ms",
+    "proposal_target.device_ms", "anchor_target.device_ms"}
+
+
+def test_metrics_of_the_detectors_scopes_list_the_detector_cells(
+        parent_result):
+    """A metric read from a scope only the detectors' step names is asked
+    of their cells alone; what any cell through the fit loop can report
+    lists none and is asked of all.  Every listed cell exists."""
+    cells = {w["name"] for w in BENCH["workloads"]}
+    listed = {m["name"]: m["workloads"] for m in
+              BENCH["end_to_end"] + BENCH["per_layer"] if "workloads" in m}
+    assert DETECTOR_SCOPED <= set(listed)
+    for name, where in listed.items():
+        assert set(where) <= cells and len(set(where)) == len(where), name
+        if name in DETECTOR_SCOPED:
+            assert where == ["r101-coco.train", "vgg16-voc07.train"], name
+    # a cell that is not listed is not asked: no reader runs for it
+    other = dict(bench_run.load_cell("vgg16-voc07.train"), name="other.train")
+    got = bench_run.metrics_of(dict(parent_result), other, BENCH, True)
+    assert got and not set(got) & DETECTOR_SCOPED
+
+
 def test_every_name_has_its_file_and_every_file_its_name():
     bdir = os.path.join(ROOT, "benchmark")
     cells = {w["name"] for w in BENCH["workloads"]}
     assert cells == {f[:-5] for f in os.listdir(os.path.join(bdir, "workloads"))}
     configs = {c["name"] for c in BENCH["configs"]}
     assert configs == {f[:-5] for f in os.listdir(os.path.join(bdir, "configs"))}
+    # a file whose name starts with ``_`` is a helper families share
     families = {f[:-3] for f in os.listdir(os.path.join(bdir, "families"))
-                if f.endswith(".py") and f != "__init__.py"}
+                if f.endswith(".py") and not f.startswith("_")}
     assert families == {_config(c)["network"]["family"] for c in configs}
+    for f in families:
+        mod = flops.family({"family": f})
+        assert callable(mod.layers) and mod.STAGES and all(
+            isinstance(s, str) for s in mod.STAGES), f
     readers = {f[:-3] for f in os.listdir(os.path.join(bdir, "metrics"))
                if f.endswith(".py")}
     assert readers == {m["name"] for m in BENCH["per_layer"]}
@@ -324,6 +503,8 @@ def test_every_name_has_its_file_and_every_file_its_name():
         c = next(c for c in BENCH["configs"] if c["name"] == w["config"])
         assert c["file"] == f"benchmark/configs/{w['config']}.json"
         assert c["reduced"] == cell["config"]["reduced"]
+        if cell["driver"] != "train":
+            continue   # another driver kind compares numbers of its own
         known = {f"{k}_s{i}" for k in ("loss", "rpn_loss")
                  for i in range(1, cell["check"]["steps"] + 1)} | {
             "grad_worst", "grad_median", "first_delta_worst",
@@ -333,7 +514,9 @@ def test_every_name_has_its_file_and_every_file_its_name():
         assert all(0 < v < 1 for v in limits.values()), limits
 
 
-@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+@pytest.mark.parametrize("name", [
+    c["name"] for c in BENCH["configs"]
+    if _config(c["name"])["network"]["family"] in ("resnet", "vgg")])
 def test_config_file_states_what_the_program_runs(name):
     """The reference reads its sizes from the configuration's file, never
     from the program: the two must say the same."""

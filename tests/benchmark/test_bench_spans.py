@@ -10,6 +10,7 @@ import pytest
 from bench_tiny import ROOT
 from benchmark import hostspans, trace, window
 from benchmark import run as bench_run
+from benchmark.families import _detector
 
 BENCH = bench_run.manifest()
 MS = 1e6                   # ns
@@ -272,7 +273,7 @@ def test_spans_come_from_the_programs_buffer():
 def test_new_reader_returns_nothing_without_a_trace(name):
     ctx = {"trace": None, "counters": {}, "peak_bytes": 0, "bytes_limit": 0,
            "window": {"slowest_ms_per_step": 200.0}, "layers": [],
-           "rois": 128, "chips": 1, "cell": {},
+           "stages": _detector.STAGES, "chips": 1, "cell": {},
            "hostspans.events": _events(_recorded()[1])}
     assert bench_run.read_metric(name, ctx) is None
     # and without spans, on the recorded trace of the program before the
@@ -280,7 +281,7 @@ def test_new_reader_returns_nothing_without_a_trace(name):
     # (``jit(roi_align)`` is a path component too) is read
     red, _ = _recorded()
     ctx = {"trace": red, "cell": {"traffic": {"warmup_steps": WARMUP}},
-           "hostspans.events": None}
+           "stages": _detector.STAGES, "hostspans.events": None}
     value = bench_run.read_metric(name, ctx)
     assert (value is not None) == (name in (
         "step.unscoped_ms", "roi_align.device_ms",
@@ -289,11 +290,11 @@ def test_new_reader_returns_nothing_without_a_trace(name):
 
 def test_stages_and_the_unscoped_rest_add_up_to_the_step():
     red, _ = _recorded(sync_gap_ms=None, wait_gap_ms=None)
-    ctx = {"trace": red}
+    ctx = {"trace": red, "stages": _detector.STAGES}
     step_ms = bench_run.read_metric("step.device_ms", ctx)
     unscoped = bench_run.read_metric("step.unscoped_ms", ctx)
     stages = [1e3 * (red.scope_s(s) or 0.0) / red.steps
-              for s in hostspans.STAGES]
+              for s in _detector.STAGES]
     assert 0 < unscoped < 0.05 * step_ms
     # ops on one device do not overlap by more than rounding
     assert unscoped + sum(stages) == pytest.approx(step_ms, rel=1e-3)
@@ -313,7 +314,7 @@ def test_stages_and_the_unscoped_rest_add_up_to_the_step():
             "programs": [["jit_step(1)", 0.0, 120.0],
                          ["jit_step(1)", 200.0, 120.0]]}]},
         steps=1, chips=1)
-    ctx = {"trace": small}
+    ctx = {"trace": small, "stages": _detector.STAGES}
     assert bench_run.read_metric("roi_align.device_ms", ctx) == \
         pytest.approx(70e-6)
     assert bench_run.read_metric("roi_head.device_ms", ctx) == \
@@ -327,9 +328,14 @@ def test_every_new_metric_names_its_source_and_layer():
     layers = {m["layer"] for m in BENCH["per_layer"]
               if m["name"] not in NEW}
     for m in BENCH["per_layer"]:
-        if m["name"] in NEW:
+        # (of the detectors' cells: a later family's readers name layers
+        # and cells of their own)
+        if m["name"] in NEW and "r101-coco.train" in m.get(
+                "workloads", ["r101-coco.train"]):
             assert m["layer"] in layers, m
-            assert "workloads" not in m
+            # the detector's own scopes are asked of the detector cells
+            # alone; what any cell through the fit loop leaves, of all
+            assert ("workloads" in m) == m["name"].endswith(".device_ms"), m
             assert m["moves"] == ("setup_s" if m["name"].startswith(
                 ("setup.", "compile.")) else "train_imgs_per_s")
-    assert len(NEW) == 18
+    assert len(NEW) >= 18
