@@ -34,6 +34,9 @@ class TrainConfig:
     end2end: bool = True           # ref: END2END
     flip: bool = True              # ref: FLIP — append horizontally flipped roidb
     shuffle: bool = True           # ref: SHUFFLE
+    # sequence families (network.family != "detector"): tokens a row of the
+    # batch; ``batch_images`` then counts sequences per device
+    seq_len: int = 0
     # configlint: disable=CL201 ref ASPECT_GROUPING mirrored 1:1; grouping is realized structurally by the landscape/portrait buckets (BucketConfig)
     aspect_grouping: bool = True   # ref: ASPECT_GROUPING — group wide/tall images
 
@@ -160,6 +163,43 @@ class NetworkConfig:
     # kernel's param shape, so it is a profile_step A/B lever
     # (``--pad_stem``), not a checkpoint-compatible default.  0 = off.
     stem_channel_pad: int = 0
+    # -- sequence-model families (models/nemotron_h.py) -----------------------
+    # "detector" = the Faster R-CNN families above; "nemotron_h" = a hybrid
+    # stack of Mamba-2 ('M'), attention ('*') and routed-expert ('E')
+    # blocks, one letter a block in ``layer_pattern`` (the published
+    # hybrid_override_pattern, or the part of it this chip runs).  Widths
+    # keep their published names.  The family also chooses the loss, the
+    # loader and the optimizer (core/train.py, data/tokens.py,
+    # core/optim.py).
+    family: str = "detector"
+    layer_pattern: str = ""
+    hidden_size: int = 0
+    vocab_size: int = 0                 # rows of the vocabulary held here
+    norm_eps: float = 1e-5
+    # residual writers start at 0.02 / sqrt(2 * init_layers): the published
+    # depth, whatever part of it ``layer_pattern`` keeps
+    init_layers: int = 0
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 0                 # published n_groups
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    num_attention_heads: int = 0
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    attn_block_q: int = 256             # queries a block (ops/attention.py)
+    n_routed_experts: int = 0           # the router's width
+    # (first, count): the experts this chip holds of n_routed_experts
+    experts_held: Tuple[int, int] = (0, 0)
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # rows kept for the held experts, over the assignments expected under
+    # even routing (ops/moe.py — row_capacity); beyond it rows overflow
+    moe_capacity_factor: float = 2.0
 
     @property
     def num_anchors(self) -> int:
@@ -872,6 +912,60 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         fixed_params_shared=("conv1", "conv2"),
         compute_dtype="float32",
     ),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B (model_type nemotron_h) at its
+    # published widths, whole: 52 blocks, all 128 experts, the whole
+    # vocabulary.  A chip's share of it is this preset with layer_pattern,
+    # experts_held and vocab_size overridden (benchmark/configs/).
+    "nemotron_h": dict(
+        name="nemotron_h", family="nemotron_h", fixed_params=(),
+        layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        init_layers=52, hidden_size=2688, vocab_size=131072, norm_eps=1e-5,
+        mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128,
+        ssm_groups=8, conv_kernel=4, chunk_size=128,
+        num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+        n_routed_experts=128, experts_held=(0, 128), num_experts_per_tok=6,
+        moe_intermediate_size=1856, moe_shared_expert_intermediate_size=3712,
+        routed_scaling_factor=2.5, norm_topk_prob=True,
+    ),
+    # test-only miniature of the same family: every mechanism, CPU-sized
+    "nemotron_h_tiny": dict(
+        name="nemotron_h_tiny", family="nemotron_h", fixed_params=(),
+        layer_pattern="ME*E", init_layers=4, hidden_size=64, vocab_size=256,
+        mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=16,
+        ssm_groups=2, conv_kernel=4, chunk_size=16,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        attn_block_q=32,
+        n_routed_experts=8, experts_held=(2, 2), num_experts_per_tok=2,
+        moe_intermediate_size=48, moe_shared_expert_intermediate_size=96,
+        routed_scaling_factor=2.5, norm_topk_prob=True,
+        # 4 x the even share is every assignment a token can make to the
+        # two held experts: a bound that cannot be exceeded
+        moe_capacity_factor=4.0,
+        compute_dtype="float32",
+    ),
+}
+
+# What a network preset fixes outside its own section: the sequence family
+# trains with AdamW (core/optim.py reads momentum as beta1, clip_gradient as
+# the global-norm clip, wd as the decoupled decay of matrices), a constant
+# lr after the optional warm-up, and rows that are sequences.
+_NETWORK_SECTIONS: Mapping[str, Mapping[str, Mapping[str, Any]]] = {
+    "nemotron_h": {
+        # lr: what a linear warm-up over a few thousand steps to a peak of
+        # a few 1e-4 gives in its first steps, which is where a run from
+        # random weights is; Adam moves every weight by lr a step whatever
+        # the gradient, and at 1e-4 the router's logits shift by tenths a
+        # step (PERF.md section 6, PR 34)
+        "default": dict(e2e_lr=1e-6, e2e_lr_step="", wd=0.1,
+                        clip_gradient=1.0, momentum=0.9, e2e_epoch=1),
+        "train": dict(seq_len=8192, batch_images=2, flip=False),
+    },
+    "nemotron_h_tiny": {
+        "default": dict(e2e_lr=3e-3, e2e_lr_step="", wd=0.1,
+                        clip_gradient=1.0, momentum=0.9, e2e_epoch=1,
+                        frequent=4),
+        "train": dict(seq_len=64, batch_images=2, flip=False),
+    },
 }
 
 _DATASETS: Mapping[str, Mapping[str, Any]] = {
@@ -918,6 +1012,16 @@ _DATASETS: Mapping[str, Mapping[str, Any]] = {
         dataset_path="data/synthetic_stream",
         num_classes=81,
     ),
+    # token sources for the sequence families (data/tokens.py): a file of
+    # ids (<dataset_path>/<image_set>.npy), or ids drawn from the seed
+    "tokens": dict(
+        name="tokens", image_set="train", test_image_set="test",
+        dataset_path="data/tokens", num_classes=0,
+    ),
+    "synthetic_tokens": dict(
+        name="synthetic_tokens", image_set="train", test_image_set="test",
+        dataset_path="data/synthetic_tokens", num_classes=0,
+    ),
 }
 
 # Per-dataset bucket presets (TPU addition): synthetic canvases are
@@ -952,6 +1056,8 @@ def generate_config(network: str = "resnet101", dataset: str = "PascalVOC",
     )
     if dataset in _DATASET_BUCKETS:
         cfg = cfg.replace_in("bucket", **_DATASET_BUCKETS[dataset])
+    for section, kw in _NETWORK_SECTIONS.get(network, {}).items():
+        cfg = cfg.replace_in(section, **kw)
     by_section: dict = {}
     for key, val in overrides.items():
         if "__" not in key:

@@ -94,6 +94,25 @@ def frozen_mask(params, fixed_prefixes: Iterable[str]):
     return jax.tree_util.tree_map_with_path(trainable, params)
 
 
+ADAM_B2, ADAM_EPS = 0.95, 1e-8
+
+
+def adamw(cfg: Config, sched: optax.Schedule) -> optax.GradientTransformation:
+    """The sequence families' optimizer: clip the whole gradient to a global
+    norm of ``default.clip_gradient``, Adam (beta1 ``default.momentum``,
+    beta2 0.95, eps 1e-8, float32 moments), decoupled weight decay
+    ``default.wd`` on matrices alone (leaves of two or more axes: norm
+    scales, biases and the state-space vectors are not decayed), the
+    schedule's lr.  The clip needs every gradient before any update, so
+    the update cannot fuse into the gradients' producers."""
+    return optax.chain(
+        optax.clip_by_global_norm(cfg.default.clip_gradient),
+        optax.adamw(sched, b1=cfg.default.momentum, b2=ADAM_B2, eps=ADAM_EPS,
+                    weight_decay=cfg.default.wd,
+                    mask=lambda params: jax.tree.map(
+                        lambda p: p.ndim >= 2, params)))
+
+
 def make_optimizer(
     cfg: Config,
     params,
@@ -102,7 +121,9 @@ def make_optimizer(
     lr_step: str | None = None,
     frozen_prefixes: Sequence[str] | None = None,
 ) -> optax.GradientTransformation:
-    """SGD(momentum, wd) with step decay and FIXED_PARAMS freezing.
+    """SGD(momentum, wd) with step decay and FIXED_PARAMS freezing for the
+    detectors; :func:`adamw` on the same schedule for a sequence family
+    (``cfg.network.family``; nothing of it is frozen).
 
     ``params`` is only used to build the freeze mask pytree.
     """
@@ -114,6 +135,8 @@ def make_optimizer(
                         cfg.default.lr_factor,
                         warmup_step=cfg.default.warmup_step,
                         warmup_lr=cfg.default.warmup_lr)
+    if cfg.network.family != "detector":
+        return adamw(cfg, sched)
     # momentum accumulator dtype: bfloat16 halves optimizer-state HBM and
     # bandwidth (config.default.momentum_dtype — TPU addition; float32 =
     # exact reference semantics); unknown spellings raise

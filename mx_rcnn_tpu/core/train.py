@@ -89,6 +89,13 @@ class RCNNBatch(NamedTuple):
     rois_valid: jnp.ndarray
 
 
+class TokenBatch(NamedTuple):
+    """Batch of a sequence family (``data/tokens.py``): ids (N, S) int32,
+    every row a full sequence of the vocabulary held here."""
+
+    ids: jnp.ndarray
+
+
 def _rpn_losses(model: FasterRCNN, rpn_cls, rpn_box, anchors, batch,
                 key: jax.Array, cfg: Config):
     """Anchor targets + the two RPN losses (shared by e2e and RPN-only
@@ -318,10 +325,38 @@ def loss_and_metrics_rcnn(  # graphlint: jit (traced via LOSS_FNS)
     return total, {**metrics, "loss": total}
 
 
+def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
+    model,
+    params,
+    batch_stats,
+    batch: TokenBatch,
+    key: jax.Array,
+    cfg: Config,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Next-token loss of a sequence family (``models/nemotron_h.py``) on
+    ``(N, S)`` ids, and the routed-expert counters of the step: mean
+    assignments per token that fell on held experts (mean over the expert
+    layers), the worst layer's largest expert load over its mean,
+    the rows not computed (must be 0), and the rows of every held expert
+    (layers x held; a log line shows its mean)."""
+    loss, aux = model.apply({"params": params}, batch.ids)
+    sizes = aux["sizes"].astype(jnp.float32)
+    per_layer = sizes.sum(-1)
+    return loss, {
+        "loss": loss,
+        "moe_assignments_per_token": per_layer.mean() / batch.ids.size,
+        "moe_load_max_over_mean": jnp.max(
+            sizes.max(-1) * sizes.shape[-1] / jnp.maximum(per_layer, 1.0)),
+        "moe_overflow": aux["overflow"].sum().astype(jnp.float32),
+        "moe_expert_rows": sizes,
+    }
+
+
 LOSS_FNS = {
     "e2e": loss_and_metrics,
     "rpn": loss_and_metrics_rpn,
     "rcnn": loss_and_metrics_rcnn,
+    "lm": loss_and_metrics_lm,
 }
 
 
@@ -335,6 +370,11 @@ def init_variables(
     Returns (params, batch_stats).  (Ref analog: ``load_param`` + the
     Normal-init of new layers in ``train_end2end.py``; pretrained weights
     are grafted on top via ``utils/pretrained.py``.)"""
+    if not isinstance(model, FasterRCNN):
+        # a sequence family traces itself on a shape of its own
+        # graphlint: disable=GL302 one-shot init program
+        return jax.jit(model.init_variables)(key)
+
     def _init(key):
         images = jnp.zeros(image_shape, jnp.float32)
         variables = model.init(key, images, method=model.features)

@@ -243,7 +243,9 @@ class FasterRCNN(nn.Module):
 
 
 def build_model(cfg: Config, quant_phase: str = "apply") -> FasterRCNN:
-    """Construct the model from a Config (ref generate_config wiring).
+    """Construct the model from a Config (ref generate_config wiring); for a
+    sequence family (``cfg.network.family``) that family's own stack
+    (``models/nemotron_h.py``).
 
     ``quant_phase`` only matters when ``cfg.quant.enabled``:
     ``'apply'`` builds the quantized-inference model (needs the
@@ -255,6 +257,10 @@ def build_model(cfg: Config, quant_phase: str = "apply") -> FasterRCNN:
     from mx_rcnn_tpu.config import validate_dtype_string
     from mx_rcnn_tpu.ops.quant import spec_from_config
 
+    if cfg.network.family != "detector":
+        from mx_rcnn_tpu.models.nemotron_h import build_lm
+
+        return build_lm(cfg)
     validate_dtype_string(cfg.network.compute_dtype,
                           "network__compute_dtype")
     quant = (spec_from_config(cfg.quant, phase=quant_phase)

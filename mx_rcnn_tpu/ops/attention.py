@@ -1,0 +1,87 @@
+"""Causal grouped-query attention computed in blocks of queries.
+
+A block of ``block_q`` queries sees only the keys up to its own end, so the
+products above the diagonal are never formed and the score matrix of a
+block is (block_q, keys so far): the whole (S, S) matrix per head never
+exists.  Each block is a ``jax.checkpoint``: the backward pass recomputes a
+block's scores instead of keeping every block's.  Scores and softmax are
+float32; the two products take operands in ``q.dtype``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+
+@jax.custom_vjp
+def softmax_rows(s):
+    """Softmax along the last axis, forward and transpose, with every row
+    reduction (the maximum, the sum, the transpose's dot) behind an
+    ``optimization_barrier``.  Without it the TPU compiler rewrites
+    ``s - max(s)`` as a reduce-window whose window is the whole row, at a
+    cost quadratic in the row: 47 ms for one block of 256 queries against
+    8192 keys, where the pass over its 0.5 GB takes 1.3 ms (PERF.md section
+    6, PR 34)."""
+    return _softmax_fwd(s)[0]
+
+
+def _softmax_fwd(s):
+    m = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
+    e = jnp.exp(s - m)
+    p = e / jax.lax.optimization_barrier(jnp.sum(e, axis=-1, keepdims=True))
+    return p, p
+
+
+def _softmax_bwd(p, g):
+    # the transpose is traced on its own: name the op, so that its device
+    # time is found under attention's scope wherever it is traced from
+    with jax.named_scope("attention"):
+        t = jax.lax.optimization_barrier(
+            jnp.sum(g * p, axis=-1, keepdims=True))
+        return (p * (g - t),)
+
+
+softmax_rows.defvjp(_softmax_fwd, _softmax_bwd)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def _block(qb, kb, vb, lo: int):
+    """qb (B, G, R, Q, D) at positions lo..lo+Q; kb, vb (B, G, K, D) at
+    positions 0..K with K = lo + Q."""
+    d = qb.shape[-1]
+    s = jnp.einsum("bgrqd,bgkd->bgrqk", qb, kb,
+                   preferred_element_type=jnp.float32) * (d ** -0.5)
+    qpos = lo + jnp.arange(qb.shape[3])
+    kpos = jnp.arange(kb.shape[2])
+    s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
+    p = softmax_rows(s)
+    return jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(vb.dtype), vb,
+                      preferred_element_type=jnp.float32).astype(qb.dtype)
+
+
+def causal_gqa(q, k, v, block_q: int = 256):
+    """q (B, S, Hq, D); k, v (B, S, Hkv, D) with Hq a multiple of Hkv (query
+    head j reads key-value head j // (Hq // Hkv)).  Scale D^-1/2, no
+    positional term.  Returns (B, S, Hq, D) in ``q.dtype``.
+
+    Heads go major and positions next to the head size before the blocks
+    are cut, so that a block's scores are plain batched (rows, D) x (D,
+    keys) products whose keys are the minor axis: the softmax then reduces
+    along the minor axis (``softmax_rows``)."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} key-value heads")
+    block_q = min(block_q, s)
+    if s % block_q:
+        raise ValueError(f"sequence {s} is no multiple of block_q {block_q}")
+    qg = q.reshape(b, s, hkv, hq // hkv, d).transpose(0, 2, 3, 1, 4)
+    kg, vg = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    outs = [_block(qg[:, :, :, lo:lo + block_q], kg[:, :, :lo + block_q],
+                   vg[:, :, :lo + block_q], lo)
+            for lo in range(0, s, block_q)]
+    out = jnp.concatenate(outs, axis=3)            # (B, G, R, S, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, d)

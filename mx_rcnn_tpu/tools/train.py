@@ -27,6 +27,7 @@ from mx_rcnn_tpu.core.fit import fit
 from mx_rcnn_tpu.core.train import setup_training
 from mx_rcnn_tpu.data import (AnchorLoader, cache_from_config,
                               decode_pool_from_config, load_gt_roidb)
+from mx_rcnn_tpu.data.tokens import TokenLoader, load_token_source
 from mx_rcnn_tpu.models import build_model
 from mx_rcnn_tpu.obs import trace as obs_trace
 from mx_rcnn_tpu.utils.checkpoint import restore_state
@@ -160,9 +161,16 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     are thin variations of ``train_net`` the same way).
     ``proposals``: per-roidb-record proposal arrays (required for 'rcnn').
     ``init_from``: (prefix, epoch) checkpoint to initialize params and
-    batch_stats from (stage chaining; optimizer state starts fresh).
+    batch_stats from (stage chaining; optimizer state starts fresh), or the
+    weights themselves handed over in memory: a mapping with ``params`` (and
+    ``batch_stats`` where the model keeps any) whose leaves may live on the
+    device already — no file is written or read.
     ``roidb`` may be injected (the alternate driver does); when None it is
-    loaded from ``cfg.dataset``.
+    loaded from ``cfg.dataset``.  For a sequence family
+    (``cfg.network.family``, e.g. ``nemotron_h``) it is the token source,
+    an ``(n, S)`` array of ids (``data/tokens.py``), the loader is a
+    ``TokenLoader``, ``mode`` is ``'lm'`` whatever was passed, and
+    everything from the stager and ``fit`` on is the detectors' code.
     ``resume``: restore the newest state under ``prefix`` — a SIGTERM
     interrupt checkpoint (mid-epoch, step-exact) if present, else the
     highest epoch checkpoint.  ``resume="auto"`` additionally VERIFIES
@@ -209,7 +217,11 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     if end_epoch is None:
         end_epoch = cfg.default.e2e_epoch
     t_loader = time.perf_counter()
-    if roidb is None:
+    if cfg.network.family != "detector":
+        mode = "lm"
+        if roidb is None:
+            roidb = load_token_source(cfg, seed)
+    elif roidb is None:
         _, roidb = load_gt_roidb(cfg, training=True, **(dataset_kw or {}))
     logger.info("[%s] training on %d roidb images", mode, len(roidb))
 
@@ -220,13 +232,13 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     bh0, bw0 = cfg.bucket.shapes[0]
     image_bytes = bh0 * bw0 * 3
     batch_bytes = n_total * image_bytes
-    decode_pool = decode_pool_from_config(cfg, n_images=len(roidb),
-                                          image_bytes=image_bytes,
-                                          batch_bytes=batch_bytes)
+    decode_pool = None if mode == "lm" else decode_pool_from_config(
+        cfg, n_images=len(roidb), image_bytes=image_bytes,
+        batch_bytes=batch_bytes)
     # with a decode pool the cache lives IN the workers (loader.py —
     # decode_pool_from_config splits the RAM budget across them); a
     # parent-side cache would be dead weight the pool path never consults
-    cache = (None if decode_pool is not None
+    cache = (None if decode_pool is not None or mode == "lm"
              else cache_from_config(cfg, n_images=len(roidb),
                                     image_bytes=image_bytes,
                                     batch_bytes=batch_bytes))
@@ -243,7 +255,10 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     loader_kw = dict(batch_images=n_total, shuffle=cfg.train.shuffle,
                      seed=seed, cache=cache, decode_pool=decode_pool,
                      shard=shard)
-    if mode == "rcnn":
+    if mode == "lm":
+        loader = TokenLoader(roidb, cfg, batch_images=n_total,
+                             shuffle=cfg.train.shuffle, seed=seed)
+    elif mode == "rcnn":
         from mx_rcnn_tpu.data.loader import ROIIter
 
         if proposals is None:
@@ -297,9 +312,15 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
         from mx_rcnn_tpu.utils.checkpoint import load_param
 
         with obs_trace.span("setup.load", source="init_from"):
-            p, s = load_param(*init_from)
-            state = state._replace(params=p, batch_stats=s)
-        logger.info("initialized params from %s epoch %d", *init_from)
+            if isinstance(init_from, dict):
+                state = state._replace(
+                    params=init_from["params"],
+                    batch_stats=init_from.get("batch_stats", {}))
+            else:
+                p, s = load_param(*init_from)
+                state = state._replace(params=p, batch_stats=s)
+        logger.info("initialized params from %s",
+                    "memory" if isinstance(init_from, dict) else init_from)
     data_cursor = None
     t_resume = time.perf_counter()
     if resume == "auto" and begin_epoch == 0:
@@ -501,10 +522,12 @@ def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser(
         description="Train Faster R-CNN end-to-end (ref train_end2end.py)")
     p.add_argument("--network", default="resnet101",
-                   choices=["vgg", "resnet50", "resnet101", "tiny"])
+                   choices=["vgg", "resnet50", "resnet101", "tiny",
+                            "nemotron_h", "nemotron_h_tiny"])
     p.add_argument("--dataset", default="PascalVOC",
                    choices=["PascalVOC", "coco", "synthetic",
-                            "synthetic_hard", "synthetic_stream"])
+                            "synthetic_hard", "synthetic_stream",
+                            "tokens", "synthetic_tokens"])
     p.add_argument("--image_set", default=None,
                    help="e.g. 2007_trainval or 2007_trainval+2012_trainval")
     p.add_argument("--root_path", default=None)
