@@ -15,7 +15,10 @@ are random, made from a seed; the dataset is generated from a seed.
   -> evidence the NMS kernel ran compiled: 'auto' resolves to 'pallas' at
      the recipe's proposal shape, the compiled kernel's keep masks equal the
      jnp sweep's at K=6144 and K=12032, and the compiled train step's HLO
-     holds a ``tpu_custom_call``.
+     holds a ``tpu_custom_call``
+  -> the grouped products' kernels (``ops/gmm_pallas.py``), compiled at the
+     language-model cell's shapes, against ``lax.ragged_dot``: the held
+     experts' output and the cotangents of ``x``, ``w_up``, ``w_down``.
 
 Every phase failure is fatal.  With no TPU the script exits non-zero before
 compiling anything and prints no result.  Stdout is two JSON lines: the
@@ -125,6 +128,69 @@ def _sweep_parity(k: int, compiled: bool) -> Dict:
     return {"k": k, "kept": int(want.sum()), "equal": True}
 
 
+def _grouped_parity(compiled: bool) -> Dict:
+    """The held experts' layer with the grouped products' Pallas kernels
+    against the same layer over ``lax.ragged_dot``: output and the
+    cotangents of ``x``, ``w_up`` and ``w_down``, bfloat16 rows, largest
+    gap over the oracle's largest value.  ``compiled`` runs the kernels
+    through Mosaic at the language-model cell's shapes (16384 tokens,
+    12288 rows, 2688 x 1856, 8 of 128 experts held); otherwise in the
+    interpreter at a small size."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops import moe
+
+    tokens, hidden, width, count, experts, top_k = (
+        (16384, 2688, 1856, 8, 128, 6) if compiled else (256, 80, 48, 4, 16, 2))
+    keys = jax.random.split(jax.random.PRNGKey(35), 5)
+    x = jax.random.normal(keys[0], (tokens, hidden), jnp.bfloat16)
+    w_up = 0.02 * jax.random.normal(keys[1], (count, hidden, width))
+    w_down = 0.02 * jax.random.normal(keys[2], (count, width, hidden))
+    ct = jax.random.normal(keys[3], (tokens, hidden))
+    idx, weight = moe.route(
+        x, 0.02 * jax.random.normal(keys[4], (hidden, experts)), 0.0, top_k,
+        2.5, True)
+    routed = moe.held_assignments(
+        idx, weight, (0, count),
+        moe.row_capacity(tokens, top_k, experts, count, 2.0))
+
+    def kernels(*a):
+        return moe.held_experts(a[0], routed, a[1], a[2],
+                                interpret=not compiled)
+
+    def oracle(x, w_up, w_down):
+        keep = routed.valid[:, None]
+
+        def product(rows, w):
+            return jax.lax.ragged_dot(rows, w.astype(rows.dtype),
+                                      routed.group_sizes,
+                                      preferred_element_type=jnp.float32)
+
+        h = product(jnp.where(keep, x[routed.token], 0), w_up)
+        y = product(jnp.square(jax.nn.relu(h)).astype(x.dtype), w_down)
+        y = jnp.where(keep, y * routed.weight[:, None], 0.0)
+        return jnp.zeros(x.shape, jnp.float32).at[routed.token].add(y)
+
+    def both(fn):
+        out, pull = jax.vjp(fn, x, w_up, w_down)
+        return (out,) + pull(ct)
+
+    got, want = jax.jit(lambda: both(kernels))(), jax.jit(
+        lambda: both(oracle))()
+    gaps = {}
+    for name, g, w in zip(("y", "d_x", "d_w_up", "d_w_down"), got, want):
+        g, w = (np.asarray(a, np.float32) for a in (g, w))
+        gaps[name] = float(np.abs(g - w).max() / np.abs(w).max())
+    _check(0 < int(routed.valid.sum()) < routed.valid.size
+           and int(routed.overflow) == 0, "degenerate grouped parity input")
+    _check(max(gaps.values()) < 0.02,
+           f"grouped product kernels disagree with lax.ragged_dot: {gaps}")
+    return {"rows": int(routed.valid.size), "held": int(routed.valid.sum()),
+            "relative_gap": gaps}
+
+
 def run_smoke(train_argv: Sequence[str], *, expect_platform: str,
               parity_sizes: Sequence[int]) -> Dict:
     """Drive the main path once and return the result record.
@@ -165,6 +231,7 @@ def run_smoke(train_argv: Sequence[str], *, expect_platform: str,
 
     # ---- kernel: compiled parity against the oracle -----------------------
     parity = [_sweep_parity(k, compiled=on_tpu) for k in parity_sizes]
+    grouped = _grouped_parity(compiled=on_tpu)
 
     # ---- train: the normal entry points -----------------------------------
     from mx_rcnn_tpu.ft.integrity import latest_valid_checkpoint
@@ -291,6 +358,7 @@ def run_smoke(train_argv: Sequence[str], *, expect_platform: str,
         "state_devices": state_devices,
         "nms_backend": nms_backend,
         "nms_parity": parity,
+        "grouped_parity": grouped,
         "tpu_custom_calls_in_step": custom_calls,
         "test_mode": {"scores_shape": list(scores_b.shape),
                       "detections_kept": int(keep_b.sum()),

@@ -4,8 +4,12 @@ The router keeps its published width: every token scores all ``E`` experts
 and chooses ``top_k`` of them.  This chip holds the ``count`` experts
 ``first .. first + count`` (``held``) and computes their part of the
 result: the assignments that fall on held experts are sorted by expert,
-their token rows gathered, two grouped products (``lax.ragged_dot``) run
-over them, and the weighted rows are scatter-added back to their tokens.
+their token rows gathered, two grouped products run over them, and the
+weighted rows are scatter-added back to their tokens.  On a TPU the
+products, and through a ``custom_vjp`` the four of their backward pass,
+are the row-tiled Mosaic kernels of ``ops/gmm_pallas.py``; on any other
+platform they are ``lax.ragged_dot`` and its autodiff (a Mosaic kernel
+lowers nowhere else; the tests compare the two).
 What the absent experts would add is left out, and nothing stands in for
 the chips that hold them or for the exchange with them.
 
@@ -103,17 +107,31 @@ def relu2_ffn(x, w_up, w_down):
                    preferred_element_type=jnp.float32)
 
 
-def held_experts(x, routed: Routed, w_up, w_down):
+def grouped_product(rows, w, group_sizes, interpret: bool = False):
+    """rows (C, K) sorted by group; w (count, K, N), cast to ``rows``'
+    dtype for the product -> (C, N) float32, accumulated in float32.  On a
+    TPU the kernels of ``ops/gmm_pallas.py`` (``interpret`` runs them in
+    the Pallas interpreter instead: what a test passes, on any platform);
+    elsewhere ``lax.ragged_dot``.  The platform is read at trace time."""
+    with jax.named_scope("moe_grouped"):
+        if interpret or jax.default_backend() == "tpu":
+            from mx_rcnn_tpu.ops.gmm_pallas import grouped_matmul
+
+            return grouped_matmul(rows, w, group_sizes, None, interpret)
+        return jax.lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes,
+                                  preferred_element_type=jnp.float32)
+
+
+def held_experts(x, routed: Routed, w_up, w_down, interpret: bool = False):
     """The held experts' part of the layer's output, (T, H) float32.
     x (T, H); w_up (count, H, F); w_down (count, F, H).  Rows past the last
     held assignment enter as zeros and leave as zeros, whatever the grouped
-    product writes there."""
+    product (``grouped_product``: a Mosaic kernel on a TPU, ``ragged_dot``
+    elsewhere) writes there."""
     keep = routed.valid[:, None]
     xs = jnp.where(keep, x[routed.token], 0)
-    h = jax.lax.ragged_dot(xs, w_up.astype(x.dtype), routed.group_sizes,
-                           preferred_element_type=jnp.float32)
+    h = grouped_product(xs, w_up, routed.group_sizes, interpret)
     h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
-    y = jax.lax.ragged_dot(h, w_down.astype(x.dtype), routed.group_sizes,
-                           preferred_element_type=jnp.float32)
+    y = grouped_product(h, w_down, routed.group_sizes, interpret)
     y = jnp.where(keep, y * routed.weight[:, None], 0.0)
     return jnp.zeros(x.shape, jnp.float32).at[routed.token].add(y)
