@@ -14,7 +14,7 @@ NATIVE_LIB := $(NATIVE_DIR)/libmxrcnn_native.so
 NATIVE_SRC := $(NATIVE_DIR)/src/nms.cc $(NATIVE_DIR)/src/maskapi.cc
 
 .PHONY: all native lint test test-all test-gate serve-smoke ft-smoke \
-	obs-smoke perf-smoke elastic-smoke data-smoke fleet-smoke \
+	obs-smoke elastic-smoke data-smoke fleet-smoke \
 	quant-smoke threadlint-smoke bulk-smoke crashsim-smoke \
 	health-smoke crosshost-smoke wirefuzz-smoke sim-smoke \
 	rollout-smoke trace-smoke wire-smoke clean
@@ -86,18 +86,6 @@ obs-smoke:
 # live fleet exits 0.  ~30 s.
 health-smoke:
 	env JAX_PLATFORMS=cpu python -m mx_rcnn_tpu.tools.obs smoke --check
-
-# perf-tooling smoke (docs/PERF.md "Round-6"): CPU-backend sanity run of
-# the stage profiler on the tiny model (N=2 unrolled chains) — fails
-# unless every stage times finite, NO timed pass retraces (jit cache
-# miss), the chain self-check holds (sum of stages ~ full step), and the
-# per-stage gauges land in the obs registry.  Guards the queued
-# script/perf_r6.sh battery: the chip capture must not be the first time
-# the tool runs.  ~1 min warm.
-perf-smoke:
-	env JAX_PLATFORMS=cpu python -m mx_rcnn_tpu.tools.profile_step \
-		--network tiny --dataset synthetic --shape 128x160 \
-		--batch_images 2 --iters 2 --check
 
 # quantized-inference smoke (docs/PERF.md "Quantized inference"): train
 # the tiny model briefly, then assert the quant acceptance shape — fp
@@ -274,8 +262,8 @@ elastic-smoke:
 # these for round-gate evidence; test-all stays green without them.
 # the linters run first: a hygiene violation fails the gate in seconds
 # instead of after 30 minutes of training; serve-smoke next (~30 s),
-# then the perf-tooling smoke (~1 min), the observability smoke
-# (~1 min), the fleet-health smoke (health-smoke, ~30 s), the
+# then the observability smoke (~1 min), the fleet-health smoke
+# (health-smoke, ~30 s), the
 # streaming input-plane smoke (data-smoke, ~30 s), the
 # serving-fleet smoke (fleet-smoke, ~2 min), the cross-host fleet
 # smoke (crosshost-smoke, ~2 min), the bulk kill+resume
@@ -288,7 +276,7 @@ elastic-smoke:
 # the v2 wire data-plane A/B (wire-smoke, ~1 min)
 test-gate: lint crashsim-smoke wirefuzz-smoke trace-smoke sim-smoke \
 		wire-smoke \
-		serve-smoke perf-smoke obs-smoke health-smoke data-smoke \
+		serve-smoke obs-smoke health-smoke data-smoke \
 		fleet-smoke crosshost-smoke bulk-smoke quant-smoke ft-smoke \
 		elastic-smoke rollout-smoke threadlint-smoke
 	python -m pytest tests/ -x -q -m "gate"

@@ -317,7 +317,7 @@ def run_smoke(train_argv: Sequence[str], *, expect_platform: str,
     if args.num_devices == 1:
         from mx_rcnn_tpu.core.optim import make_optimizer
         from mx_rcnn_tpu.core.train import make_train_step
-        from mx_rcnn_tpu.tools.profile_step import make_batch
+        from mx_rcnn_tpu.data.synthetic import make_batch
 
         t0 = time.perf_counter()
         tx = make_optimizer(cfg, state.params, len(losses), base_lr=args.lr,

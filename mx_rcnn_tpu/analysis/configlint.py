@@ -98,7 +98,8 @@ class _Visitor(ast.NodeVisitor):
     # -- scopes -------------------------------------------------------------
 
     def visit_FunctionDef(self, node):
-        scope = dict(self.scope_aliases[0])
+        # a closure sees the aliases of the function around it
+        scope = dict(self.scope_aliases[-1])
         # a parameter annotated with a section CLASS is that section
         # (``def spec_from_config(qcfg: QuantConfig)``)
         for a in node.args.posonlyargs + node.args.args + \

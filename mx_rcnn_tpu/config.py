@@ -71,34 +71,6 @@ class TrainConfig:
     # -- TPU additions -------------------------------------------------------
     max_gt_boxes: int = 100        # static pad for per-image gt boxes
     gt_append: bool = True         # append gt boxes to sampled ROI pool (ref does)
-    # rematerialize backbone activations in the backward pass
-    # (jax.checkpoint): trades recompute FLOPs for HBM capacity/bandwidth —
-    # numerically identical gradients (pinned by test); enables larger
-    # per-chip batches when activations are the memory wall
-    remat_backbone: bool = False
-    # ROIAlign backend for the TRAIN step: 'auto'/'jnp' → the einsum pair
-    # (measured FASTER in the full step: the fused kernel wins isolated
-    # but pays ~13 ms in custom-call boundary layout copies + lost XLA
-    # fusion — ops/roi_pool.py roi_align_batched docstring has the
-    # numbers); 'blocked' → the ROI-chunked einsum pair (bit-equal
-    # forward, live (R,·,·,C) intermediate shrunk by roi_align_chunk/R,
-    # stays inside the XLA program so it pays none of the custom-call
-    # tax — the r6 lever, full-step A/B queued in script/perf_r6.sh);
-    # 'pallas' → the experimental VMEM-fused kernel
-    roi_align_backend: str = "auto"
-    # ROI block size for the 'blocked' backend (ignored by the others):
-    # 64 splits the production 256-ROI batch into 4 chunks → ~70 MB live
-    # intermediate instead of ~280 MB
-    roi_align_chunk: int = 64
-    # proposal-stage NMS composition: True → the batched nms_batch path
-    # (ops/nms.py — when the jnp sweep backend is selected this is ONE
-    # cross-image tile sweep per step, decision-exact vs the per-image
-    # sweep; when the auto-guards select the Pallas kernel on TPU, the
-    # kernel still runs per image under vmap); False → vmap of per-image
-    # nms calls (the pre-r6 composition, the A/B arm for
-    # script/perf_r6.sh leg 3, which forces the jnp backend to actually
-    # engage the cross-image sweep)
-    nms_batched: bool = True
 
 
 @dataclass(frozen=True)
@@ -160,8 +132,8 @@ class NetworkConfig:
     # zero-pad the stem's 3 input channels up to this count before conv0
     # (4 aligns the channel axis; padded channels are exact zeros so the
     # output is bit-identical — pinned by test).  Changes the conv0
-    # kernel's param shape, so it is a profile_step A/B lever
-    # (``--pad_stem``), not a checkpoint-compatible default.  0 = off.
+    # kernel's param shape, so it is not a checkpoint-compatible default;
+    # no cell has measured it (ROADMAP D5).  0 = off.
     stem_channel_pad: int = 0
     # -- sequence-model families (models/nemotron_h.py) -----------------------
     # "detector" = the Faster R-CNN families above; "nemotron_h" = a hybrid
@@ -295,8 +267,7 @@ class BucketConfig:
     ``--set bucket__shapes='[[640,1024],[1024,640]]'`` (anchors and bucket
     padding regenerate from the feature shape automatically; pinned by
     tests/test_anchors.py).  Whether the alignment win beats the pixel tax
-    is a measured chip decision: script/perf_r6.sh leg 4 runs the A/B and
-    docs/PERF.md "Round-6" records the adopt-or-refuse verdict.
+    has not been measured: no cell runs the 640 bucket (ROADMAP D5).
     """
 
     scale: int = 600            # ref: SCALES[0][0] — target short side

@@ -166,16 +166,10 @@ def _rcnn_losses(model: FasterRCNN, variables, feat, rois, rois_valid,
             rois, rois_valid, batch.gt_boxes, batch.gt_classes,
             batch.gt_valid, jax.random.split(k_prop, n))
 
-    # 'auto' resolves to the einsum pair — the fused Pallas kernel wins
-    # isolated but loses ~13 ms to custom-call boundary costs in the full
-    # step (see ops/roi_pool.py roi_align_batched); 'blocked' runs the
-    # same pair ROI-chunked (bit-equal forward, intermediate shrunk by
-    # roi_align_chunk/R); 'pallas' opts into the kernel
-    backend = None if tr.roi_align_backend == "auto" else tr.roi_align_backend
     with jax.named_scope("roi_align"):
         pooled = roi_align_batched(
-            feat, pt.rois, model.pooled_size, 1.0 / model.feat_stride,
-            backend=backend, chunk=tr.roi_align_chunk)  # (N, B, ph, pw, C)
+            feat, pt.rois, model.pooled_size,
+            1.0 / model.feat_stride)  # (N, B, ph, pw, C)
     flat = pooled.reshape((-1,) + pooled.shape[2:])
     with jax.named_scope("roi_head"):
         cls_logits, bbox_deltas = model.apply(
@@ -204,20 +198,6 @@ def _rcnn_losses(model: FasterRCNN, variables, feat, rois, rois_valid,
     return cls_loss, bbox_loss, metrics
 
 
-def _backbone_features(model: FasterRCNN, variables, batch, cfg: Config):
-    """Backbone forward shared by the three training objectives; with
-    ``cfg.train.remat_backbone`` the activations are rematerialized in the
-    backward pass (jax.checkpoint) — numerically identical gradients,
-    HBM for FLOPs (pinned equal by test)."""
-
-    def f(v, images):
-        return model.apply(v, images, batch.im_info, method=model.features)
-
-    if cfg.train.remat_backbone:
-        f = jax.checkpoint(f)
-    return f(variables, batch.images)
-
-
 def loss_and_metrics(  # graphlint: jit (traced via LOSS_FNS inside the step)
     model: FasterRCNN,
     params,
@@ -232,14 +212,15 @@ def loss_and_metrics(  # graphlint: jit (traced via LOSS_FNS inside the step)
     k_anchor, k_rcnn = jax.random.split(key)
 
     # named_scope on each stage: jax.profiler traces then attribute device
-    # time per stage (benchmark/metrics/<scope>.device_ms.py,
-    # tools/profile_step.py --trace_summary).  Scopes are metadata: no
-    # jit boundary, the compiled step keeps its instructions and fusions.
+    # time per stage (benchmark/metrics/<scope>.device_ms.py).  Scopes are
+    # metadata: no jit boundary, the compiled step keeps its instructions
+    # and fusions.
     # Inside them: anchor_target (rpn_losses), nms_sweep (proposal, in
     # ops/nms.py), proposal_target / roi_align / roi_head (rcnn_losses);
     # beside them in make_train_step: grad_sync, optimizer
     with jax.named_scope("backbone"):
-        feat = _backbone_features(model, variables, batch, cfg)
+        feat = model.apply(variables, batch.images, batch.im_info,
+                           method=model.features)
     with jax.named_scope("rpn_head"):
         rpn_cls, rpn_box = model.apply(variables, feat,
                                        method=model.rpn_raw)
@@ -262,7 +243,6 @@ def loss_and_metrics(  # graphlint: jit (traced via LOSS_FNS inside the step)
         # decision-exact vs vmap(propose), pinned by tests/test_proposal.py
         rois, _, rois_valid = propose_batch(
             fg_scores, rpn_box_sg, anchors, batch.im_info,
-            batched_nms=tr.nms_batched,
             pre_nms_top_n=tr.rpn_pre_nms_top_n,
             post_nms_top_n=tr.rpn_post_nms_top_n,
             nms_thresh=tr.rpn_nms_thresh,
@@ -290,7 +270,8 @@ def loss_and_metrics_rpn(  # graphlint: jit (traced via LOSS_FNS)
     Shares ``_rpn_losses`` with the e2e objective."""
     variables = {"params": params, "batch_stats": batch_stats}
     with jax.named_scope("backbone"):
-        feat = _backbone_features(model, variables, batch, cfg)
+        feat = model.apply(variables, batch.images, batch.im_info,
+                           method=model.features)
     with jax.named_scope("rpn_head"):
         rpn_cls, rpn_box = model.apply(variables, feat,
                                        method=model.rpn_raw)
@@ -316,7 +297,8 @@ def loss_and_metrics_rcnn(  # graphlint: jit (traced via LOSS_FNS)
     ``_rcnn_losses`` with the e2e objective."""
     variables = {"params": params, "batch_stats": batch_stats}
     with jax.named_scope("backbone"):
-        feat = _backbone_features(model, variables, batch, cfg)
+        feat = model.apply(variables, batch.images, batch.im_info,
+                           method=model.features)
     with jax.named_scope("rcnn_losses"):
         cls_loss, bbox_loss, metrics = _rcnn_losses(
             model, variables, feat, batch.rois, batch.rois_valid, batch,

@@ -398,3 +398,38 @@ class StreamSyntheticDataset(SyntheticDataset):
         # validate for the other
         base = super()._spec_signature()
         return f"{zlib.crc32(b'stream', int(base, 16)):08x}"
+
+
+def make_batch(cfg, batch_images, h, w, seed=0, raw=False):
+    """Synthetic training batch; ``raw=True`` emits the uint8 image layout
+    the production loader ships (device-side normalization path)."""
+    import jax.numpy as jnp
+
+    from mx_rcnn_tpu.core.train import Batch
+
+    rng = np.random.RandomState(seed)
+    g = cfg.train.max_gt_boxes
+    n_gt = 8
+    gt_boxes = np.zeros((batch_images, g, 4), np.float32)
+    gt_classes = np.zeros((batch_images, g), np.int32)
+    gt_valid = np.zeros((batch_images, g), bool)
+    for i in range(batch_images):
+        xy = rng.uniform(0, [w * 0.8, h * 0.8], (n_gt, 2))
+        wh = rng.uniform(0.05, 0.4, (n_gt, 2)) * [w, h]
+        gt_boxes[i, :n_gt, :2] = xy
+        gt_boxes[i, :n_gt, 2:] = np.minimum(xy + wh, [w - 1, h - 1])
+        gt_classes[i, :n_gt] = rng.randint(1, cfg.dataset.num_classes, n_gt)
+        gt_valid[i, :n_gt] = True
+    if raw:
+        images = jnp.asarray(
+            rng.randint(0, 256, (batch_images, h, w, 3)), jnp.uint8)
+    else:
+        images = jnp.asarray(rng.randn(batch_images, h, w, 3), jnp.float32)
+    return Batch(
+        images=images,
+        im_info=jnp.tile(jnp.array([[float(h), float(w), 1.0]]),
+                         (batch_images, 1)),
+        gt_boxes=jnp.asarray(gt_boxes),
+        gt_classes=jnp.asarray(gt_classes),
+        gt_valid=jnp.asarray(gt_valid),
+    )

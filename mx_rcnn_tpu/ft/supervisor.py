@@ -393,7 +393,7 @@ def measure_snapshot_overhead(steps: int = 96, snapshot_every: int = 32,
     from mx_rcnn_tpu.core.train import make_train_step, setup_training
     from mx_rcnn_tpu.ft.snapshot import AsyncSnapshotter, SyncSnapshotter
     from mx_rcnn_tpu.models import build_model
-    from mx_rcnn_tpu.tools.profile_step import make_batch
+    from mx_rcnn_tpu.data.synthetic import make_batch
 
     cfg = generate_config("tiny", "PascalVOC")
     cfg = cfg.replace_in("train", rpn_pre_nms_top_n=256,

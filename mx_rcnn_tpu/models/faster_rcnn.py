@@ -31,7 +31,7 @@ from mx_rcnn_tpu.ops.anchors import generate_shifted_anchors
 from mx_rcnn_tpu.ops.normalize import normalize_images
 from mx_rcnn_tpu.ops.proposal import propose_batch
 from mx_rcnn_tpu.ops.quant import QuantSpec
-from mx_rcnn_tpu.ops.roi_pool import roi_align
+from mx_rcnn_tpu.ops.roi_pool import roi_align_batched
 
 Dtype = Any
 
@@ -68,9 +68,9 @@ class FasterRCNN(nn.Module):
     # Padded channels are exactly zero so every conv sum is unchanged —
     # output BIT-identical to the 3-channel model given the same first-3
     # kernel channels (pinned by tests/test_quant.py); param shapes DO
-    # change (conv0 kernel grows an input channel), so this is a
-    # profile_step A/B lever (--pad_stem), not a checkpoint-compatible
-    # default.  0 = off.
+    # change (conv0 kernel grows an input channel), so this is not a
+    # checkpoint-compatible default; no cell has measured it (ROADMAP D5).
+    # 0 = off.
     stem_channel_pad: int = 0
 
     @property
@@ -163,12 +163,10 @@ class FasterRCNN(nn.Module):
     def _pool_and_classify(self, feat: jnp.ndarray, rois: jnp.ndarray):
         """ROIAlign + the per-ROI head of the two test forwards, under the
         train step's scope names; returns (cls_logits, deltas, R)."""
-        def pool_one(feat_i, rois_i):
-            return roi_align(feat_i, rois_i, self.pooled_size,
-                             1.0 / self.feat_stride)
-
         with jax.named_scope("roi_align"):
-            pooled = jax.vmap(pool_one)(feat, rois)  # (N, R, ph, pw, C)
+            pooled = roi_align_batched(
+                feat, rois, self.pooled_size,
+                1.0 / self.feat_stride)  # (N, R, ph, pw, C)
         n, r = pooled.shape[:2]
         flat = pooled.reshape((n * r,) + pooled.shape[2:])
         with jax.named_scope("roi_head"):

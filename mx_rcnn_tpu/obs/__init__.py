@@ -3,8 +3,8 @@ did this step/request spend its time?" across training, data loading,
 checkpointing and serving (ISSUE 4).
 
 Before this package the repo had three disjoint fragments: the serving
-histograms (``serve/metrics.py``), the XSpace decoder reachable only via
-``tools/profile_step.py`` (``utils/xplane.py``), and the ``Speedometer``
+histograms (``serve/metrics.py``), the XSpace decoder
+(``utils/xplane.py``), and the ``Speedometer``
 stdout line in ``core/fit.py`` — none of which could see each other or
 the ``ft/`` snapshot path.  Layers, bottom-up:
 

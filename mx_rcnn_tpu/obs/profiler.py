@@ -61,7 +61,7 @@ def rollup(trace_dir: str, depth: int = 1) -> Dict:
     Empty dict when no trace was captured; for traces over
     :data:`MAX_ROLLUP_BYTES` the parse is SKIPPED (a ``"skipped"`` note
     replaces the tables) — summarize offline with
-    ``tools/profile_step.summarize_trace``."""
+    ``utils/xplane.py``'s ``summarize_device_time(parse_xspace(pb))``."""
     pb = newest_xplane(trace_dir)
     if pb is None:
         return {}
@@ -69,8 +69,9 @@ def rollup(trace_dir: str, depth: int = 1) -> Dict:
     if size > MAX_ROLLUP_BYTES:
         note = (f"trace is {size >> 20} MB (> {MAX_ROLLUP_BYTES >> 20} MB "
                 "inline cap) — keep profile windows short; summarize "
-                "offline: python -c \"from mx_rcnn_tpu.tools.profile_step "
-                f"import summarize_trace; summarize_trace('{trace_dir}')\"")
+                "offline: python -c \"from mx_rcnn_tpu.utils.xplane import "
+                "parse_xspace, summarize_device_time; "
+                f"print(summarize_device_time(parse_xspace('{pb}')))\"")
         logger.warning("obs profiler: %s", note)
         return {"xplane": pb, "skipped": note,
                 "by_scope": {}, "by_op_class": {}}

@@ -20,8 +20,8 @@ log.  Every ``tools/train.py`` / ``tools/serve.py`` invocation with
   per event) would serialize the training/serving hot path behind disk
   latency, which the line-granular contract exists to avoid.
 * ``runs/<id>/summary.json`` — ONE final BENCH-compatible record
-  (``{"metric": ..., "value": ..., "measured": ...}`` like ``bench.py``
-  and ``tools/loadgen.py`` emit) plus the closing snapshot of the
+  (``{"metric": ..., "value": ..., "measured": ...}`` like
+  ``tools/loadgen.py`` emits) plus the closing snapshot of the
   process metrics registry, so a finished run is analyzable without
   re-parsing the event stream.  Unlike the event stream this IS an
   atomic document (one shot, read as a whole), so it goes through

@@ -18,7 +18,7 @@ from mx_rcnn_tpu.ops.nms import (nms, nms_batch, nms_mask,  # noqa: F401
                                  nms_mask_batch)
 from mx_rcnn_tpu.ops.proposal import propose, propose_batch  # noqa: F401
 from mx_rcnn_tpu.ops.roi_pool import (roi_align, roi_align_batched,  # noqa: F401
-                                      roi_align_blocked, roi_pool)
+                                      roi_pool)
 from mx_rcnn_tpu.ops.targets import anchor_target, proposal_target  # noqa: F401
 from mx_rcnn_tpu.ops.losses import (  # noqa: F401
     smooth_l1,

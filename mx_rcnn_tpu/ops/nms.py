@@ -45,11 +45,11 @@ from mx_rcnn_tpu.ops.boxes import bbox_overlaps
 # jax.distributed.initialize ordering multi-host needs
 _NEG = -1e10
 
-# Suppression-sweep backend: the Pallas kernel (ops/nms_pallas.py) keeps the
-# whole sweep in VMEM; the jnp sweep below is the oracle the tests compare
-# it against.  "auto" = the kernel on a TPU, the jnp sweep on any other
-# platform (see _resolve_backend — on a TPU it never gives way quietly).
-_BACKEND = "auto"
+# Suppression-sweep backends: the Pallas kernel (ops/nms_pallas.py) keeps
+# the whole sweep in VMEM; the jnp sweep below is the oracle the tests
+# compare it against.  ``backend=None`` or 'auto' = the kernel on a TPU, the
+# jnp sweep on any other platform (see _resolve_backend — on a TPU it never
+# gives way quietly).
 
 # the kernel's tile: whole 128-lane registers, independent of the padding
 # tile (greedy NMS is exact at any tile size)
@@ -66,19 +66,6 @@ _KERNEL_TILE = 128
 _KERNEL_MAX_K = 16384
 
 
-def set_nms_backend(name: str) -> None:
-    """Select 'auto' | 'pallas' | 'jnp' for subsequent traces.
-
-    NOTE: jitted callers cache per static-arg signature; pass an explicit
-    ``backend=`` to :func:`nms`/:func:`nms_mask` (as the tests do) to force
-    a retrace rather than flipping this global mid-run.
-    """
-    global _BACKEND
-    if name not in ("auto", "pallas", "jnp"):
-        raise ValueError(f"unknown NMS backend {name!r}")
-    _BACKEND = name
-
-
 def _resolve_backend(backend: Optional[str], k: int, tile: int) -> str:
     """'auto' → the sweep that runs for ``k`` padded boxes in tiles of
     ``tile``.  Off-TPU that is the jnp sweep.  On a TPU it is the kernel
@@ -88,7 +75,7 @@ def _resolve_backend(backend: Optional[str], k: int, tile: int) -> str:
     pass ``backend='jnp'`` to choose that one.  Inputs smaller than one
     tile (k < tile_size, so tile == k) have no tiling for the kernel to
     do and run the jnp single-tile sweep."""
-    b = backend or _BACKEND
+    b = backend or "auto"
     if b != "auto":
         return b
     if jax.default_backend() != "tpu":  # graphlint: disable=GL203 the platform name is a host string, fixed at trace time

@@ -108,31 +108,21 @@ def propose_batch(
     bbox_deltas: jnp.ndarray,
     anchors: jnp.ndarray,
     im_info: jnp.ndarray,
-    batched_nms: bool = True,
-    **kw,
+    *,
+    pre_nms_top_n: int = 6000,
+    post_nms_top_n: int = 300,
+    nms_thresh: float = 0.7,
+    min_size: int = 16,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Batched :func:`propose` over a leading batch axis.
 
     scores (B, N), bbox_deltas (B, N, 4), im_info (B, 3); anchors shared.
 
-    With ``batched_nms=True`` (the default — the r6 production path) the
-    per-image stages (decode/top-k/compaction) run under vmap but the NMS
-    sweep runs as ONE cross-image batched pass (:func:`nms_batch`),
+    The per-image stages (decode/top-k/compaction) run under vmap but the
+    NMS sweep runs as ONE cross-image batched pass (:func:`nms_batch`),
     decision-exact vs ``vmap(propose)`` (pinned by
-    ``tests/test_proposal.py``).  ``batched_nms=False`` restores the pure
-    vmap-of-propose composition — kept as the A/B arm for
-    ``tools/profile_step.py --nms_mode per_image``.
+    ``tests/test_proposal.py``).
     """
-    if not batched_nms:
-        fn = functools.partial(propose, **kw)
-        return jax.vmap(fn, in_axes=(0, 0, None, 0))(
-            scores, bbox_deltas, anchors, im_info)
-    pre_nms_top_n = kw.pop("pre_nms_top_n", 6000)
-    post_nms_top_n = kw.pop("post_nms_top_n", 300)
-    nms_thresh = kw.pop("nms_thresh", 0.7)
-    min_size = kw.pop("min_size", 16)
-    if kw:
-        raise TypeError(f"unknown propose_batch kwargs {sorted(kw)}")
     top_boxes, top_scores, top_valid = jax.vmap(
         lambda s, d, i: _decode_filter_topk(s, d, anchors, i,
                                             pre_nms_top_n, min_size)

@@ -65,8 +65,7 @@ def interp_matrices(rois: jnp.ndarray, ph: int, pw: int, h: int, w: int,
                     spatial_scale: float, sampling_ratio: int):
     """Per-ROI (wy (R, ph, H), wx (R, pw, W)) fp32 interpolation matrices
     — the ONE place the ROI corner scaling / min-size clamp / bilinear
-    weights are defined, shared by the einsum and Pallas backends so
-    their weights cannot drift apart (parity tests pin them equal)."""
+    weights are defined."""
     x1 = rois[:, 0].astype(jnp.float32) * spatial_scale
     y1 = rois[:, 1].astype(jnp.float32) * spatial_scale
     x2 = rois[:, 2].astype(jnp.float32) * spatial_scale
@@ -78,20 +77,6 @@ def interp_matrices(rois: jnp.ndarray, ph: int, pw: int, h: int, w: int,
     wx = jax.vmap(lambda s, b: _interp_matrix(s, b, pw, sampling_ratio, w))(
         x1, roi_w / pw)
     return wy, wx
-
-
-def _einsum_pair(features: jnp.ndarray, wy: jnp.ndarray, wx: jnp.ndarray,
-                 rows_first: bool, prec: str) -> jnp.ndarray:
-    """The two ROIAlign contractions — the ONE definition of contraction
-    order and dtype rules, shared by the monolithic einsum path and the
-    ROI-chunked blocked path so the backends are bit-equal by construction
-    (each output element reduces over the same axis in the same order
-    regardless of how many ROIs share the batch dimension)."""
-    if rows_first:
-        rows = jnp.einsum("rsh,hwc->rswc", wy, features, precision=prec)
-        return jnp.einsum("rswc,rtw->rstc", rows, wx, precision=prec)
-    cols = jnp.einsum("hwc,rtw->rhtc", features, wx, precision=prec)
-    return jnp.einsum("rhtc,rsh->rstc", cols, wy, precision=prec)
 
 
 @functools.partial(
@@ -135,127 +120,13 @@ def roi_align(
     prec = "highest" if dtype == jnp.float32 else "default"
     wy = wy.astype(dtype)
     wx = wx.astype(dtype)
-    pooled = _einsum_pair(features, wy, wx, ph * w <= h * pw, prec)
+    if ph * w <= h * pw:
+        rows = jnp.einsum("rsh,hwc->rswc", wy, features, precision=prec)
+        pooled = jnp.einsum("rswc,rtw->rstc", rows, wx, precision=prec)
+    else:
+        cols = jnp.einsum("hwc,rtw->rhtc", features, wx, precision=prec)
+        pooled = jnp.einsum("rhtc,rsh->rstc", cols, wy, precision=prec)
     return pooled.astype(dtype)
-
-
-# ---------------------------------------------------------------------------
-# ROI-chunked blocked ROIAlign (r6).
-#
-# The einsum pair above materializes the inter-matmul intermediate for ALL
-# ROIs at once — (R, s|h, w|t, C), ~280 MB bf16 at the production shape
-# (256 ROIs x 38x64x1024 features), measured 5.84 ms of the 26.44 ms train
-# step at ~2% MFU (docs/PERF.md r5 stage table): HBM-bandwidth-bound, not
-# compute-bound.  The Pallas kernel that fused the pair in VMEM LOST ~13 ms
-# in the full step to custom-call-boundary layout copies (PERF.md "Fused
-# ROIAlign kernel"), so this backend shrinks the intermediate while staying
-# INSIDE the XLA program: a `lax.map` over ROI chunks runs the same einsum
-# pair per chunk, so the live intermediate is chunk/R the size and XLA still
-# fuses across the op — no opaque boundary, no layout copies.
-#
-# Bit-equality with the einsum pair holds by construction for the forward
-# (both run `_einsum_pair`; the ROI axis is a batch axis, so chunking it
-# cannot change any per-element reduction).  The backward is a custom VJP
-# that blocks the transposed contractions the same way and accumulates the
-# feature cotangent across chunks in fp32; chunked accumulation is the same
-# sum in a different association, so backward parity is pinned exactly on
-# reduction-order-insensitive test vectors and to float tolerance on random
-# ones (tests/test_roi_pool.py).  Like the Pallas backend (and the reference
-# ROIPooling), ROIs are treated as non-differentiable data: the VJP returns
-# a zeros cotangent for them (training stop-gradients proposals anyway).
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blocked_pool(features, wy_c, wx_c, rows_first: bool, prec: str):
-    """features (H, W, C) + chunked fp-cast interp matrices
-    wy_c (n, c, ph, H) / wx_c (n, c, pw, W) → pooled (n, c, ph, pw, C)."""
-    return _blocked_pool_fwd(features, wy_c, wx_c, rows_first, prec)[0]
-
-
-def _blocked_pool_fwd(features, wy_c, wx_c, rows_first, prec):
-    def one_chunk(ws):
-        wy_i, wx_i = ws
-        return _einsum_pair(features, wy_i, wx_i, rows_first, prec)
-
-    pooled = jax.lax.map(one_chunk, (wy_c, wx_c))
-    return pooled, (features, wy_c, wx_c)
-
-
-def _blocked_pool_bwd(rows_first, prec, res, g):
-    features, wy_c, wx_c = res
-
-    def body(acc, xs):
-        wy_i, wx_i, g_i = xs
-        # the transposed contractions of _einsum_pair, per chunk — the
-        # backward's (R, ·, ·, C) cotangent intermediate shrinks by the
-        # chunk factor exactly like the forward's
-        if rows_first:
-            d_rows = jnp.einsum("rstc,rtw->rswc", g_i, wx_i, precision=prec)
-            part = jnp.einsum("rsh,rswc->hwc", wy_i, d_rows, precision=prec)
-        else:
-            d_cols = jnp.einsum("rstc,rsh->rhtc", g_i, wy_i, precision=prec)
-            part = jnp.einsum("rhtc,rtw->hwc", d_cols, wx_i, precision=prec)
-        return acc + part.astype(jnp.float32), None
-
-    d_feat, _ = jax.lax.scan(
-        body, jnp.zeros(features.shape, jnp.float32), (wy_c, wx_c, g))
-    # interp matrices are non-differentiable data here (rois carry no
-    # gradient — see the block comment above)
-    return (d_feat.astype(features.dtype), jnp.zeros_like(wy_c),
-            jnp.zeros_like(wx_c))
-
-
-_blocked_pool.defvjp(_blocked_pool_fwd, _blocked_pool_bwd)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("output_size", "spatial_scale", "sampling_ratio",
-                     "chunk"),
-)
-def roi_align_blocked(
-    features: jnp.ndarray,
-    rois: jnp.ndarray,
-    output_size: Tuple[int, int] = (14, 14),
-    spatial_scale: float = 1.0 / 16.0,
-    sampling_ratio: int = 2,
-    chunk: int = 64,
-) -> jnp.ndarray:
-    """ROI-chunked blocked ROIAlign over a single image's feature map.
-
-    Same contract as :func:`roi_align` (bit-equal forward, same dtype
-    rules) with the (R, ·, ·, C) intermediate shrunk by the chunk factor;
-    ``chunk`` is the ROI block size (``cfg.train.roi_align_chunk``).  The
-    ROI count is padded to a chunk multiple with zero-boxes and sliced
-    back, so odd counts are fine; gradients w.r.t. ``rois`` are zeros
-    (see the block comment above).
-    """
-    ph, pw = output_size
-    h, w, _ = features.shape
-    dtype = features.dtype
-    r = rois.shape[0]
-
-    wy, wx = interp_matrices(rois, ph, pw, h, w, spatial_scale,
-                             sampling_ratio)
-    prec = "highest" if dtype == jnp.float32 else "default"
-    wy = wy.astype(dtype)
-    wx = wx.astype(dtype)
-
-    c = max(1, min(int(chunk), r))
-    pad = (-r) % c
-    if pad:
-        wy = jnp.concatenate(
-            [wy, jnp.zeros((pad,) + wy.shape[1:], wy.dtype)], axis=0)
-        wx = jnp.concatenate(
-            [wx, jnp.zeros((pad,) + wx.shape[1:], wx.dtype)], axis=0)
-    n = (r + pad) // c
-    pooled = _blocked_pool(features,
-                           wy.reshape((n, c) + wy.shape[1:]),
-                           wx.reshape((n, c) + wx.shape[1:]),
-                           ph * w <= h * pw, prec)
-    pooled = pooled.reshape((n * c, ph, pw) + features.shape[-1:])
-    return pooled[:r].astype(dtype)
 
 
 def roi_align_batched(
@@ -264,47 +135,17 @@ def roi_align_batched(
     output_size: Tuple[int, int] = (14, 14),
     spatial_scale: float = 1.0 / 16.0,
     sampling_ratio: int = 2,
-    backend: str = None,
-    chunk: int = 64,
 ) -> jnp.ndarray:
-    """Batched ROIAlign with backend dispatch.
+    """:func:`roi_align` over a batch of images: features (N, H, W, C),
+    rois (N, R, 4) → (N, R, ph, pw, C).  The one pooled-feature path of
+    the train step and the test forward.
 
-    features (N, H, W, C), rois (N, R, 4) → (N, R, ph, pw, C).
-
-    ``backend``: 'jnp' (the einsum pair above, vmapped — the DEFAULT),
-    'blocked' (the ROI-chunked :func:`roi_align_blocked`, bit-equal
-    forward with the live intermediate shrunk by ``chunk``/R — the
-    XLA-visible alternative to the custom-call kernel) or 'pallas' (the
-    VMEM-fused kernel in ``ops/roi_align_pallas.py``).  All backends
-    build their bilinear weights with the same ``_interp_matrix``, so
-    they agree up to matmul rounding (the blocked backend agrees
-    bit-for-bit).  ``chunk`` is only read by 'blocked'.
-
-    Why jnp is the default even on TPU (r5, measured on a v5e): isolated,
-    the fused kernel wins the forward (3.8 vs 4.1 ms) but still loses
-    fwd+bwd by ~2 ms (12.1 vs 10.1, after fixing a VMEM-spill that
-    initially made it 2x slower), and it loses
-    ~13 ms inside the full train step (38.6 vs 25.0 ms) — the opaque
-    custom-call boundary forces layout copies of the ~100 MB pooled /
-    cotangent tensors and blocks XLA's fusion across the op, costing far
-    more than the ~280 MB HBM intermediate it removes.  Kept behind the
-    flag (cfg.train.roi_align_backend) with parity tests as measured
-    groundwork; docs/PERF.md "Fused ROIAlign kernel" has the full record.
+    What a future kernel must beat: it has to stay inside the XLA program.
+    A custom call that fused the einsum pair in VMEM won the forward alone
+    and lost the step, because the opaque boundary forced layout copies of
+    the pooled tensor and its cotangent and blocked fusion across the op
+    (docs/PERF.md "ROIAlign backends").
     """
-    if backend is None:
-        backend = "jnp"
-    if backend == "pallas":
-        from mx_rcnn_tpu.ops.roi_align_pallas import roi_align_pallas
-
-        return roi_align_pallas(features, rois, output_size, spatial_scale,
-                                sampling_ratio)
-    if backend == "blocked":
-        return jax.vmap(
-            lambda f, r: roi_align_blocked(f, r, output_size, spatial_scale,
-                                           sampling_ratio, chunk)
-        )(features, rois)
-    if backend != "jnp":
-        raise ValueError(f"unknown roi_align backend {backend!r}")
     return jax.vmap(
         lambda f, r: roi_align(f, r, output_size, spatial_scale,
                                sampling_ratio)

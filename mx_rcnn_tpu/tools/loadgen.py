@@ -5,7 +5,7 @@ No reference equivalent.  Replays synthetic images against an IN-PROCESS
 :class:`~mx_rcnn_tpu.serve.engine.ServingEngine` (no network in the
 measurement path — the HTTP front end is exercised by its own tests) and
 emits ONE BENCH-style JSON line so serving performance enters the
-measured-evidence pipeline like `bench.py` does for training:
+measured-evidence pipeline:
 
     {"metric": "serve_imgs_per_sec", "value": ..., "measured": true,
      "offline_imgs_per_sec": ..., "ratio_vs_offline": ...,

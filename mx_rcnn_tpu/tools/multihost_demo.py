@@ -48,7 +48,7 @@ def worker(args) -> None:
     from mx_rcnn_tpu.core.train import setup_training
     from mx_rcnn_tpu.models import build_model
     from mx_rcnn_tpu.parallel.dp import make_dp_train_step
-    from mx_rcnn_tpu.tools.profile_step import make_batch
+    from mx_rcnn_tpu.data.synthetic import make_batch
 
     pid = jax.process_index()
     print(f"[p{pid}] devices: local={jax.local_device_count()} "

@@ -23,7 +23,7 @@ Field numbers follow ``tensorflow/core/profiler/protobuf/xplane.proto``
 The decoded form is plain dicts/lists; ``summarize_device_time`` rolls
 per-op durations up by ``jax.named_scope`` component (extracted from the
 op metadata's source scope stats), which is what
-``tools/profile_step.py --trace_summary`` prints.
+``obs/profiler.py::rollup`` puts into a run record.
 """
 
 from __future__ import annotations

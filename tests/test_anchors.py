@@ -51,8 +51,8 @@ def test_shifted_anchor_count_stride8():
 def test_sublane_bucket_640x1024_regenerates_valid_anchors():
     """r6 bucket experiment: switching the bucket to 640x1024 (40x64
     stride-16 grid — 40 is a whole number of 8-row sublanes, unlike the
-    default 38) must regenerate anchors automatically and validly; the
-    config override path is what script/perf_r6.sh leg 4 exercises."""
+    default 38) must regenerate anchors automatically and validly
+    through the config override path."""
     from mx_rcnn_tpu.config import generate_config
     from mx_rcnn_tpu.data.image import choose_bucket
 

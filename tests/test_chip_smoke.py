@@ -1,4 +1,4 @@
-"""Bring-up contract on CPU: ``chip_smoke.py`` and ``bench.py`` refuse a
+"""Bring-up contract on CPU: ``chip_smoke.py`` refuses a
 machine without a TPU (non-zero, no result line, before any compile), the
 smoke's own function passes its rehearsal at the tiny network's size, and
 the compile cache lands where the one rule in ``mx_rcnn_tpu/runtime.py``
@@ -150,15 +150,6 @@ def test_chip_smoke_script_alone_in_a_directory_fails(tmp_path):
     assert res.stdout.strip() == ""
 
 
-def test_bench_without_tpu_exits_nonzero_with_no_stale_figure():
-    res = _run([sys.executable, "bench.py"])
-    assert res.returncode != 0
-    assert res.stdout.strip() == ""
-    both = res.stdout + res.stderr
-    assert "degraded" not in both and "last_verified" not in both
-    assert "platform='cpu'" in res.stderr
-
-
 _RESOLVE = (
     "import os, json; from mx_rcnn_tpu import runtime; "
     "before = os.environ.get(runtime.CACHE_ENV); "
@@ -171,10 +162,12 @@ _RESOLVE = (
 
 def test_cache_env_set_wins_and_is_left_alone(tmp_path):
     """JAX_COMPILATION_CACHE_DIR set: the cache is written there, the
-    variable is not rewritten, and nothing appears in the checkout."""
+    variable is not rewritten, and nothing of the child's appears in the
+    checkout (other xdist workers compile into the default directory
+    meanwhile, so only the names the child wrote are looked for)."""
     placed = str(tmp_path / "placed")
     default = runtime.DEFAULT_CACHE_DIR
-    before = set(os.listdir(default)) if os.path.isdir(default) else None
+    before = set(os.listdir(default)) if os.path.isdir(default) else set()
     res = _run([sys.executable, "-c", _RESOLVE],
                env_set={runtime.CACHE_ENV: placed})
     assert res.returncode == 0, res.stderr
@@ -182,8 +175,8 @@ def test_cache_env_set_wins_and_is_left_alone(tmp_path):
     assert out["dir"] == out["config"] == placed
     assert out["before"] == out["after"] == placed
     assert out["entries"] >= 1
-    after = set(os.listdir(default)) if os.path.isdir(default) else None
-    assert after == before
+    after = set(os.listdir(default)) if os.path.isdir(default) else set()
+    assert not (after - before) & set(os.listdir(placed))
 
 
 def test_cache_env_unset_is_one_fixed_gitignored_path_in_the_checkout():

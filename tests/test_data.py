@@ -480,6 +480,25 @@ def test_config_from_args_set_overrides():
             network="tiny", dataset="synthetic", set=["badkey"]))
 
 
+@pytest.mark.parametrize("key,val", [
+    ("train__roi_align_backend", "blocked"), ("train__roi_align_chunk", 32),
+    ("train__remat_backbone", True), ("train__nms_batched", False)])
+def test_removed_levers_are_refused_by_name(key, val):
+    """The decided levers are gone from the config, not ignored: an
+    override or a ``--set`` of one fails and names the field."""
+    import argparse
+
+    from mx_rcnn_tpu.config import generate_config
+    from mx_rcnn_tpu.tools.train import config_from_args
+
+    field = key.split("__", 1)[1]
+    with pytest.raises(TypeError, match=field):
+        generate_config("tiny", "synthetic", **{key: val})
+    with pytest.raises(TypeError, match=field):
+        config_from_args(argparse.Namespace(
+            network="tiny", dataset="synthetic", set=[f"{key}={val}"]))
+
+
 def test_set_override_type_coercion():
     """--set values coerce to the field's declared type; bad types are
     rejected loudly (the string 'false' must never become a truthy flag)."""

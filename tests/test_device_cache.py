@@ -11,7 +11,7 @@ from mx_rcnn_tpu.core.train import make_train_step, setup_training
 from mx_rcnn_tpu.data.device_cache import (DeviceEpochCache, build_caches,
                                            make_cached_step)
 from mx_rcnn_tpu.models import build_model
-from mx_rcnn_tpu.tools.profile_step import make_batch
+from mx_rcnn_tpu.data.synthetic import make_batch
 
 
 def _tiny_setup(n_batches=3):

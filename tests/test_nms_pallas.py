@@ -147,22 +147,6 @@ def test_pallas_sweep_is_causal(tile_i):
         assert not np.array_equal(got, base)    # the change did reach later
 
 
-def test_set_nms_backend_validation():
-    import importlib
-
-    # ops/__init__ re-exports the nms FUNCTION over the module name
-    nms_mod = importlib.import_module("mx_rcnn_tpu.ops.nms")
-
-    before = nms_mod._BACKEND
-    try:
-        with pytest.raises(ValueError, match="unknown NMS backend"):
-            nms_mod.set_nms_backend("cuda")
-        nms_mod.set_nms_backend("jnp")
-        assert nms_mod._BACKEND == "jnp"
-    finally:
-        nms_mod.set_nms_backend(before)
-
-
 def test_resolve_backend_guards(monkeypatch):
     """On a TPU 'auto' is the kernel, and an input the kernel cannot take
     is an error, never a quiet jnp; off-TPU 'auto' is the jnp sweep."""

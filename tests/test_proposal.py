@@ -83,8 +83,8 @@ def test_propose_batch_batched_nms_decision_exact_vs_vmap():
     kw = dict(pre_nms_top_n=200, post_nms_top_n=30, nms_thresh=0.7,
               min_size=4)
 
-    per_image = jax.jit(lambda s, d, i: propose_batch(
-        s, d, anchors, i, batched_nms=False, **kw))
+    per_image = jax.jit(lambda s, d, i: jax.vmap(
+        lambda s1, d1, i1: propose(s1, d1, anchors, i1, **kw))(s, d, i))
     batched = jax.jit(lambda s, d, i: propose_batch(
         s, d, anchors, i, **kw))
     a = per_image(b_scores, b_deltas, b_info)

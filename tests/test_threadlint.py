@@ -532,6 +532,22 @@ def test_configlint_follows_alias_and_getattr(tmp_path):
     assert len(bad) == 1 and "serve.wattermark" in bad[0].message
 
 
+def test_configlint_alias_reaches_a_closure(tmp_path):
+    """A function nested in another reads through the outer one's alias,
+    as Python does (core/train.py's ``tr = cfg.train`` is read inside
+    ``one_img``)."""
+    findings = _configlint_snippet(tmp_path, """\
+        def f(cfg):
+            s = cfg.serve
+
+            def g():
+                return s.wattermark      # CL101 through the outer alias
+            return g
+        """)
+    bad = [f for f in findings if f.code == "CL101"]
+    assert len(bad) == 1 and "serve.wattermark" in bad[0].message
+
+
 def test_configlint_getattr_key_matching_a_section_name(tmp_path):
     """Regression (code-review r10): a typo'd 2-arg getattr whose key
     happens to equal a SECTION name ('data') must still be CL101."""

@@ -193,28 +193,6 @@ def test_lr_schedule_warmup_and_decay():
     np.testing.assert_allclose(float(plain(200)), 0.001, rtol=1e-6)
 
 
-def test_remat_backbone_identical_gradients():
-    """remat_backbone=True must produce the SAME gradients as the plain
-    path (jax.checkpoint recomputes, it does not approximate) — the knob
-    is a pure memory/FLOPs trade (VERDICT r03 weak #1 MFU lever)."""
-    from mx_rcnn_tpu.core.train import loss_and_metrics
-
-    cfg, model, tx, state = tiny_setup()
-    cfg_r = cfg.replace_in("train", remat_backbone=True)
-    batch = make_batch(1, 128, seed=3)
-
-    def grads(c):
-        return jax.jit(jax.grad(
-            lambda p: loss_and_metrics(model, p, state.batch_stats, batch,
-                                       KEY, c)[0]))(state.params)
-
-    g_plain = grads(cfg)
-    g_remat = grads(cfg_r)
-    for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_remat)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-
-
 def test_bf16_momentum_state_and_training():
     """momentum_dtype='bfloat16' halves the accumulator dtype (checked in
     opt_state) and trains to a loss trajectory close to fp32 momentum —
