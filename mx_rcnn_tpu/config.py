@@ -160,7 +160,10 @@ class NetworkConfig:
     num_attention_heads: int = 0
     num_key_value_heads: int = 0
     head_dim: int = 0
-    attn_block_q: int = 256             # queries a block (ops/attention.py)
+    # queries a block of ops/attention.py's blocked path (off the TPU, or
+    # shapes its kernels do not tile); the TPU kernels' blocks are constants
+    # of ops/attention_pallas.py and read no field
+    attn_block_q: int = 256
     n_routed_experts: int = 0           # the router's width
     # (first, count): the experts this chip holds of n_routed_experts
     experts_held: Tuple[int, int] = (0, 0)

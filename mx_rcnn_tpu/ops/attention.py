@@ -1,11 +1,21 @@
-"""Causal grouped-query attention computed in blocks of queries.
+"""Causal grouped-query attention: ``softmax(q . k^T / sqrt(D) + causal
+mask) . v``, scores and softmax in float32, the two products on operands in
+``q.dtype`` accumulated in float32.  One result, two ways to it, and
+``causal_gqa`` takes the one the platform and the shapes allow, read at
+trace time:
 
-A block of ``block_q`` queries sees only the keys up to its own end, so the
-products above the diagonal are never formed and the score matrix of a
-block is (block_q, keys so far): the whole (S, S) matrix per head never
-exists.  Each block is a ``jax.checkpoint``: the backward pass recomputes a
-block's scores instead of keeping every block's.  Scores and softmax are
-float32; the two products take operands in ``q.dtype``.
+* on a TPU, where head size and sequence tile (``attention_pallas.tiles``:
+  head size a multiple of 128 lanes, sequence a multiple of the kernels'
+  blocks), the Mosaic kernels of ``ops/attention_pallas.py``: score tiles,
+  running maximum and sum and the accumulator stay in VMEM, forward and
+  backward;
+* anywhere else (the CPU tests; ``nemotron_h_tiny``'s head size 16 on any
+  platform) the blocked path below.  A block of ``block_q`` queries sees
+  only the keys up to its own end, so the products above the diagonal are
+  never formed and the score matrix of a block is (block_q, keys so far):
+  the whole (S, S) matrix per head never exists.  Each block is a
+  ``jax.checkpoint``: the backward pass recomputes a block's scores
+  instead of keeping every block's.
 """
 
 from __future__ import annotations
@@ -62,12 +72,34 @@ def _block(qb, kb, vb, lo: int):
                       preferred_element_type=jnp.float32).astype(qb.dtype)
 
 
-def causal_gqa(q, k, v, block_q: int = 256):
+def _kernels_take(q, k, interpret: bool) -> bool:
+    """The Mosaic kernels run these operands: a TPU (or the interpreter)
+    and shapes they tile.  Platform and shapes are read at trace time."""
+    from mx_rcnn_tpu.ops import attention_pallas
+
+    _, s, hq, d = q.shape
+    return ((interpret or jax.default_backend() == "tpu")
+            and hq % k.shape[2] == 0 and attention_pallas.tiles(s, d))
+
+
+def causal_gqa(q, k, v, block_q: int = 256, interpret: bool = False):
     """q (B, S, Hq, D); k, v (B, S, Hkv, D) with Hq a multiple of Hkv (query
     head j reads key-value head j // (Hq // Hkv)).  Scale D^-1/2, no
     positional term.  Returns (B, S, Hq, D) in ``q.dtype``.
 
-    Heads go major and positions next to the head size before the blocks
+    On a TPU (``interpret`` runs the kernels in the Pallas interpreter
+    instead: what a test passes, on any platform), with shapes the kernels
+    tile, ``attention_pallas.flash_causal_gqa``; else the blocked path in
+    blocks of ``block_q`` queries, which the kernels take no notice of."""
+    if _kernels_take(q, k, interpret):
+        from mx_rcnn_tpu.ops.attention_pallas import flash_causal_gqa
+
+        return flash_causal_gqa(q, k, v, None, interpret)
+    return _blocked_causal_gqa(q, k, v, block_q)
+
+
+def _blocked_causal_gqa(q, k, v, block_q: int):
+    """Heads go major and positions next to the head size before the blocks
     are cut, so that a block's scores are plain batched (rows, D) x (D,
     keys) products whose keys are the minor axis: the softmax then reduces
     along the minor axis (``softmax_rows``)."""
