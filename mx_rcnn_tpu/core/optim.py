@@ -97,14 +97,17 @@ def frozen_mask(params, fixed_prefixes: Iterable[str]):
 ADAM_B2, ADAM_EPS = 0.95, 1e-8
 
 
-def adamw(cfg: Config, sched: optax.Schedule) -> optax.GradientTransformation:
+def adamw(cfg: Config, sched: optax.Schedule, params=None,
+          frozen_prefixes=None) -> optax.GradientTransformation:
     """The sequence families' optimizer: clip the whole gradient to a global
     norm of ``default.clip_gradient``, Adam (beta1 ``default.momentum``,
     beta2 0.95, eps 1e-8, float32 moments), decoupled weight decay
     ``default.wd`` on matrices alone (leaves of two or more axes: norm
     scales, biases and the state-space vectors are not decayed), the
     schedule's lr.  The clip needs every gradient before any update, so
-    the update cannot fuse into the gradients' producers."""
+    the update cannot fuse into the gradients' producers.  Nothing of a
+    sequence family is frozen: ``params`` and ``frozen_prefixes`` are the
+    family table's signature (``families.py``) and are not read."""
     return optax.chain(
         optax.clip_by_global_norm(cfg.default.clip_gradient),
         optax.adamw(sched, b1=cfg.default.momentum, b2=ADAM_B2, eps=ADAM_EPS,
@@ -113,30 +116,12 @@ def adamw(cfg: Config, sched: optax.Schedule) -> optax.GradientTransformation:
                         lambda p: p.ndim >= 2, params)))
 
 
-def make_optimizer(
-    cfg: Config,
-    params,
-    steps_per_epoch: int,
-    base_lr: float | None = None,
-    lr_step: str | None = None,
-    frozen_prefixes: Sequence[str] | None = None,
-) -> optax.GradientTransformation:
-    """SGD(momentum, wd) with step decay and FIXED_PARAMS freezing for the
-    detectors; :func:`adamw` on the same schedule for a sequence family
-    (``cfg.network.family``; nothing of it is frozen).
-
-    ``params`` is only used to build the freeze mask pytree.
-    """
-    base_lr = cfg.default.e2e_lr if base_lr is None else base_lr
-    lr_step = cfg.default.e2e_lr_step if lr_step is None else lr_step
-    if frozen_prefixes is None:
-        frozen_prefixes = cfg.network.fixed_params
-    sched = lr_schedule(base_lr, parse_lr_step(lr_step), steps_per_epoch,
-                        cfg.default.lr_factor,
-                        warmup_step=cfg.default.warmup_step,
-                        warmup_lr=cfg.default.warmup_lr)
-    if cfg.network.family != "detector":
-        return adamw(cfg, sched)
+def sgd_frozen(cfg: Config, sched: optax.Schedule, params,
+               frozen_prefixes: Sequence[str]
+               ) -> optax.GradientTransformation:
+    """The detectors' optimizer: SGD(momentum, wd) on the schedule with the
+    reference's elementwise clip and FIXED_PARAMS freezing; ``params`` is
+    only used to build the freeze mask pytree."""
     # momentum accumulator dtype: bfloat16 halves optimizer-state HBM and
     # bandwidth (config.default.momentum_dtype — TPU addition; float32 =
     # exact reference semantics); unknown spellings raise
@@ -157,3 +142,31 @@ def make_optimizer(
         optax.masked(sgd, mask),
         optax.masked(optax.set_to_zero(), jax.tree.map(lambda t: not t, mask)),
     )
+
+
+def make_optimizer(
+    cfg: Config,
+    params,
+    steps_per_epoch: int,
+    base_lr: float | None = None,
+    lr_step: str | None = None,
+    frozen_prefixes: Sequence[str] | None = None,
+) -> optax.GradientTransformation:
+    """The family's optimizer (``families.py``) on the step-decay schedule:
+    :func:`sgd_frozen` for the detectors, :func:`adamw` for a sequence
+    family.
+
+    ``params`` is only used to build the freeze mask pytree.
+    """
+    from mx_rcnn_tpu import families
+
+    base_lr = cfg.default.e2e_lr if base_lr is None else base_lr
+    lr_step = cfg.default.e2e_lr_step if lr_step is None else lr_step
+    if frozen_prefixes is None:
+        frozen_prefixes = cfg.network.fixed_params
+    sched = lr_schedule(base_lr, parse_lr_step(lr_step), steps_per_epoch,
+                        cfg.default.lr_factor,
+                        warmup_step=cfg.default.warmup_step,
+                        warmup_lr=cfg.default.warmup_lr)
+    return families.of(cfg).get("optimizer")(cfg, sched, params,
+                                             frozen_prefixes)

@@ -240,10 +240,17 @@ class FasterRCNN(nn.Module):
         )
 
 
-def build_model(cfg: Config, quant_phase: str = "apply") -> FasterRCNN:
-    """Construct the model from a Config (ref generate_config wiring); for a
-    sequence family (``cfg.network.family``) that family's own stack
-    (``models/nemotron_h.py``).
+def build_model(cfg: Config, quant_phase: str = "apply"):
+    """The model of ``cfg.network.family``, by that family's builder in the
+    family table (``families.py``): :func:`build_detector` for the
+    detectors, a sequence family's own stack otherwise."""
+    from mx_rcnn_tpu import families
+
+    return families.of(cfg).get("build")(cfg, quant_phase)
+
+
+def build_detector(cfg: Config, quant_phase: str = "apply") -> FasterRCNN:
+    """Construct the detector from a Config (ref generate_config wiring).
 
     ``quant_phase`` only matters when ``cfg.quant.enabled``:
     ``'apply'`` builds the quantized-inference model (needs the
@@ -255,10 +262,6 @@ def build_model(cfg: Config, quant_phase: str = "apply") -> FasterRCNN:
     from mx_rcnn_tpu.config import validate_dtype_string
     from mx_rcnn_tpu.ops.quant import spec_from_config
 
-    if cfg.network.family != "detector":
-        from mx_rcnn_tpu.models.nemotron_h import build_lm
-
-        return build_lm(cfg)
     validate_dtype_string(cfg.network.compute_dtype,
                           "network__compute_dtype")
     quant = (spec_from_config(cfg.quant, phase=quant_phase)

@@ -329,12 +329,12 @@ class Dims:
     moe_capacity_factor: float
 
 
-def build_lm(cfg: Config) -> NemotronH:
+def build_lm(cfg: Config, quant_phase: str = "apply") -> NemotronH:
+    """The family table's builder (``families.py``); a sequence family has
+    no quantized form and ``quant_phase`` is not read."""
     from mx_rcnn_tpu.config import validate_dtype_string
 
     validate_dtype_string(cfg.network.compute_dtype, "network__compute_dtype")
-    if cfg.network.family != "nemotron_h":
-        raise ValueError(f"no sequence family {cfg.network.family!r}")
     if "E" not in cfg.network.layer_pattern:
         raise ValueError("a nemotron_h pattern holds at least one 'E' block")
     n = cfg.network

@@ -21,13 +21,12 @@ import time
 
 import jax
 
-from mx_rcnn_tpu import runtime
+from mx_rcnn_tpu import families, runtime
 from mx_rcnn_tpu.config import Config, generate_config
 from mx_rcnn_tpu.core.fit import fit
 from mx_rcnn_tpu.core.train import setup_training
 from mx_rcnn_tpu.data import (AnchorLoader, cache_from_config,
                               decode_pool_from_config, load_gt_roidb)
-from mx_rcnn_tpu.data.tokens import TokenLoader, load_token_source
 from mx_rcnn_tpu.models import build_model
 from mx_rcnn_tpu.obs import trace as obs_trace
 from mx_rcnn_tpu.utils.checkpoint import restore_state
@@ -166,9 +165,10 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     ``batch_stats`` where the model keeps any) whose leaves may live on the
     device already — no file is written or read.
     ``roidb`` may be injected (the alternate driver does); when None it is
-    loaded from ``cfg.dataset``.  For a sequence family
-    (``cfg.network.family``, e.g. ``nemotron_h``) it is the token source,
-    an ``(n, S)`` array of ids (``data/tokens.py``), the loader is a
+    loaded from ``cfg.dataset``.  The family table (``families.py``) says
+    what else differs by ``cfg.network.family``: for a sequence family
+    (``nemotron_h``, ``ling_flash``) ``roidb`` is the token source, an
+    ``(n, S)`` array of ids (``data/tokens.py``), the loader is a
     ``TokenLoader``, ``mode`` is ``'lm'`` whatever was passed, and
     everything from the stager and ``fit`` on is the detectors' code.
     ``resume``: restore the newest state under ``prefix`` — a SIGTERM
@@ -217,10 +217,12 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     if end_epoch is None:
         end_epoch = cfg.default.e2e_epoch
     t_loader = time.perf_counter()
-    if cfg.network.family != "detector":
-        mode = "lm"
-        if roidb is None:
-            roidb = load_token_source(cfg, seed)
+    family = families.of(cfg)
+    images = family.row == "image"
+    if family.mode is not None:
+        mode = family.mode
+    if roidb is None and family.source is not None:
+        roidb = family.get("source")(cfg, seed)
     elif roidb is None:
         _, roidb = load_gt_roidb(cfg, training=True, **(dataset_kw or {}))
     logger.info("[%s] training on %d roidb images", mode, len(roidb))
@@ -232,13 +234,13 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     bh0, bw0 = cfg.bucket.shapes[0]
     image_bytes = bh0 * bw0 * 3
     batch_bytes = n_total * image_bytes
-    decode_pool = None if mode == "lm" else decode_pool_from_config(
+    decode_pool = None if not images else decode_pool_from_config(
         cfg, n_images=len(roidb), image_bytes=image_bytes,
         batch_bytes=batch_bytes)
     # with a decode pool the cache lives IN the workers (loader.py —
     # decode_pool_from_config splits the RAM budget across them); a
     # parent-side cache would be dead weight the pool path never consults
-    cache = (None if decode_pool is not None or mode == "lm"
+    cache = (None if decode_pool is not None or not images
              else cache_from_config(cfg, n_images=len(roidb),
                                     image_bytes=image_bytes,
                                     batch_bytes=batch_bytes))
@@ -255,9 +257,9 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     loader_kw = dict(batch_images=n_total, shuffle=cfg.train.shuffle,
                      seed=seed, cache=cache, decode_pool=decode_pool,
                      shard=shard)
-    if mode == "lm":
-        loader = TokenLoader(roidb, cfg, batch_images=n_total,
-                             shuffle=cfg.train.shuffle, seed=seed)
+    if family.loader is not None:
+        loader = family.get("loader")(roidb, cfg, batch_images=n_total,
+                                      shuffle=cfg.train.shuffle, seed=seed)
     elif mode == "rcnn":
         from mx_rcnn_tpu.data.loader import ROIIter
 
@@ -523,7 +525,8 @@ def parse_args(argv=None) -> argparse.Namespace:
         description="Train Faster R-CNN end-to-end (ref train_end2end.py)")
     p.add_argument("--network", default="resnet101",
                    choices=["vgg", "resnet50", "resnet101", "tiny",
-                            "nemotron_h", "nemotron_h_tiny"])
+                            "nemotron_h", "nemotron_h_tiny",
+                            "ling_flash", "ling_flash_tiny"])
     p.add_argument("--dataset", default="PascalVOC",
                    choices=["PascalVOC", "coco", "synthetic",
                             "synthetic_hard", "synthetic_stream",
