@@ -34,8 +34,8 @@ class TrainConfig:
     end2end: bool = True           # ref: END2END
     flip: bool = True              # ref: FLIP — append horizontally flipped roidb
     shuffle: bool = True           # ref: SHUFFLE
-    # sequence families (network.family != "detector"): tokens a row of the
-    # batch; ``batch_images`` then counts sequences per device
+    # sequence families (``families.py``: row "sequence"): tokens a row of
+    # the batch; ``batch_images`` then counts sequences per device
     seq_len: int = 0
     # configlint: disable=CL201 ref ASPECT_GROUPING mirrored 1:1; grouping is realized structurally by the landscape/portrait buckets (BucketConfig)
     aspect_grouping: bool = True   # ref: ASPECT_GROUPING — group wide/tall images
@@ -135,14 +135,17 @@ class NetworkConfig:
     # kernel's param shape, so it is not a checkpoint-compatible default;
     # no cell has measured it (ROADMAP D5).  0 = off.
     stem_channel_pad: int = 0
-    # -- sequence-model families (models/nemotron_h.py) -----------------------
+    # -- sequence-model families (models/nemotron_h.py, models/ling_flash.py)
     # "detector" = the Faster R-CNN families above; "nemotron_h" = a hybrid
     # stack of Mamba-2 ('M'), attention ('*') and routed-expert ('E')
     # blocks, one letter a block in ``layer_pattern`` (the published
-    # hybrid_override_pattern, or the part of it this chip runs).  Widths
-    # keep their published names.  The family also chooses the loss, the
-    # loader and the optimizer (core/train.py, data/tokens.py,
-    # core/optim.py).
+    # hybrid_override_pattern, or the part of it this chip runs);
+    # "ling_flash" = layers of a mixer, 'K' a gated-delta-rule (KDA) or 'L'
+    # a latent-attention (MLA) one, one letter a layer, each followed by a
+    # dense SwiGLU (the first ``first_k_dense_replace`` layers kept here)
+    # or a routed-expert MLP.  Widths keep their published names.  The
+    # family also chooses the builder, the loss, the loader and the
+    # optimizer: the one table of ``families.py``.
     family: str = "detector"
     layer_pattern: str = ""
     hidden_size: int = 0
@@ -175,6 +178,27 @@ class NetworkConfig:
     # rows kept for the held experts, over the assignments expected under
     # even routing (ops/moe.py — row_capacity); beyond it rows overflow
     moe_capacity_factor: float = 2.0
+    # -- ling_flash alone ------------------------------------------------------
+    # leading layers kept here whose MLP is dense (SwiGLU ``intermediate_size``)
+    first_k_dense_replace: int = 0
+    intermediate_size: int = 0
+    # group-limited routing: the router's outputs in ``n_group`` groups, a
+    # group's score the sum of its two best, ``topk_group`` groups kept
+    # (0 = plain top-k over all experts, as nemotron_h routes)
+    n_group: int = 0
+    topk_group: int = 0
+    # latent attention: width of the compressed key-value latent, and a
+    # head's query/key widths without and with the rotary term, its value
+    # width; ``head_dim`` is the KDA head's (keys and values alike)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    # the KDA gate's lower bound a position (kda_safe_gate): the log-decay
+    # is ``kda_lower_bound * sigmoid(.)``, so a sub-chunk of 16 positions
+    # decays by exp(16 * kda_lower_bound) at most, inside float32's range
+    kda_lower_bound: float = -5.0
 
     @property
     def num_anchors(self) -> int:
@@ -917,6 +941,44 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         moe_capacity_factor=4.0,
         compute_dtype="float32",
     ),
+    # Ling-3.0-flash-VL's language stack (inclusionAI; config.json's keys)
+    # at its published widths, whole: 42 layers, five KDA to one MLA in
+    # every six (MLA where (i + 1) % 6 == 0), two leading dense layers, 512
+    # experts in 8 groups.  A chip's share is this preset with
+    # layer_pattern, first_k_dense_replace, experts_held and vocab_size
+    # overridden (benchmark/configs/ling3-flash-6l-ep64.json).
+    "ling_flash": dict(
+        name="ling_flash", family="ling_flash", fixed_params=(),
+        layer_pattern="".join("L" if (i + 1) % 6 == 0 else "K"
+                              for i in range(42)),
+        first_k_dense_replace=2, init_layers=42, hidden_size=2560,
+        vocab_size=157184, norm_eps=1e-6, intermediate_size=6144,
+        num_attention_heads=32, head_dim=128, conv_kernel=4, chunk_size=64,
+        kda_lower_bound=-5.0, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_theta=6e6,
+        n_routed_experts=512, experts_held=(0, 512), num_experts_per_tok=8,
+        n_group=8, topk_group=4, moe_intermediate_size=768,
+        moe_shared_expert_intermediate_size=768, routed_scaling_factor=2.5,
+        norm_topk_prob=True,
+    ),
+    # test-only miniature of the same family: every mechanism, CPU-sized
+    # (dense-KDA, KDA-E, MLA-E, KDA-E; 16 experts in 4 groups, 2 kept, 2 held)
+    "ling_flash_tiny": dict(
+        name="ling_flash_tiny", family="ling_flash", fixed_params=(),
+        layer_pattern="KKLK", first_k_dense_replace=1, init_layers=4,
+        hidden_size=64, vocab_size=256, norm_eps=1e-6, intermediate_size=96,
+        num_attention_heads=4, head_dim=16, conv_kernel=4, chunk_size=16,
+        kda_lower_bound=-5.0, kv_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_theta=6e6, attn_block_q=32,
+        n_routed_experts=16, experts_held=(4, 2), num_experts_per_tok=2,
+        n_group=4, topk_group=2, moe_intermediate_size=32,
+        moe_shared_expert_intermediate_size=32, routed_scaling_factor=2.5,
+        norm_topk_prob=True,
+        # 8 x the even share is every assignment a token can make to the
+        # two held experts: a bound that cannot be exceeded
+        moe_capacity_factor=8.0,
+        compute_dtype="float32",
+    ),
 }
 
 # What a network preset fixes outside its own section: the sequence family
@@ -941,6 +1003,10 @@ _NETWORK_SECTIONS: Mapping[str, Mapping[str, Mapping[str, Any]]] = {
         "train": dict(seq_len=64, batch_images=2, flip=False),
     },
 }
+# ling_flash trains by the recipe nemotron_h states, for the reasons it states
+_NETWORK_SECTIONS = {**_NETWORK_SECTIONS,
+                     "ling_flash": _NETWORK_SECTIONS["nemotron_h"],
+                     "ling_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
 
 _DATASETS: Mapping[str, Mapping[str, Any]] = {
     "PascalVOC": dict(

@@ -125,9 +125,9 @@ def _sync_metrics(window: List[Dict], step: int) -> Dict[str, float]:
         jax.block_until_ready(window[-1])
         if sp is None:
             return _mean_metrics(window)
-        t_ready = time.time_ns()
+        t_ready = time.monotonic_ns()
         avg = _mean_metrics(window)
-        sp.args["fetch_us"] = (time.time_ns() - t_ready) / 1e3
+        sp.args["fetch_us"] = (time.monotonic_ns() - t_ready) / 1e3
         return avg
 
 

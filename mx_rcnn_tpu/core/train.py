@@ -315,21 +315,26 @@ def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
     key: jax.Array,
     cfg: Config,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Next-token loss of a sequence family (``models/nemotron_h.py``) on
+    """Next-token loss of a sequence family (``models/nemotron_h.py``,
+    ``models/ling_flash.py``) on
     ``(N, S)`` ids, and the routed-expert counters of the step: mean
     assignments per token that fell on held experts (mean over the expert
     layers), the worst layer's largest expert load over its mean,
     the rows not computed (must be 0), and the rows of every held expert
-    (layers x held; a log line shows its mean)."""
+    (layers x held; a log line shows its mean); beside them whatever
+    further scalar counters the family's stack returns, under their own
+    names (``models/ling_flash.py``: ``kda_chunk_log_decay_min``)."""
     loss, aux = model.apply({"params": params}, batch.ids)
-    sizes = aux["sizes"].astype(jnp.float32)
+    sizes = aux.pop("sizes").astype(jnp.float32)
+    overflow = aux.pop("overflow")
     per_layer = sizes.sum(-1)
     return loss, {
+        **aux,
         "loss": loss,
         "moe_assignments_per_token": per_layer.mean() / batch.ids.size,
         "moe_load_max_over_mean": jnp.max(
             sizes.max(-1) * sizes.shape[-1] / jnp.maximum(per_layer, 1.0)),
-        "moe_overflow": aux["overflow"].sum().astype(jnp.float32),
+        "moe_overflow": overflow.sum().astype(jnp.float32),
         "moe_expert_rows": sizes,
     }
 

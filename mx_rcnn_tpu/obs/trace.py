@@ -21,8 +21,9 @@ Export is the chrome trace event format (load in Perfetto /
 * request lifecycles → ``ph:"b"/"e"`` async events keyed by trace id;
 * :func:`device_trace_events` decodes an ``*.xplane.pb`` (via
   ``utils/xplane.py``) into the same format so host + device merge into
-  ONE timeline (``merge_device_trace``).  Host timestamps use
-  ``time.time_ns()`` (unix epoch); the xplane's lines count from the
+  ONE timeline (``merge_device_trace``).  Host timestamps are on the unix
+  epoch (the wall clock as read once at import, counted on from there by
+  the monotonic clock: ``_now_us``); the xplane's lines count from the
   profiler session's start (``XLine.timestamp_ns + offset_ps`` is
   nanoseconds since then, on the v5e as on the CPU — PERF.md, PR 31),
   and the file states that start on the epoch clock as
@@ -100,11 +101,19 @@ def _emit(ev: dict) -> None:
         _events.append(ev)
 
 
+# the unix epoch at the monotonic clock's zero, read once at import
+_EPOCH_NS = time.time_ns() - time.monotonic_ns()
+
+
 def _now_us() -> float:
-    # wall clock, not perf_counter: an xplane file states its session's
-    # start on this clock (session_start_ns), so device lines can be laid
-    # beside host events; as a float of epoch us a stamp is good to 0.25 us
-    return time.time_ns() / 1e3
+    # epoch us, so that an xplane file's session start (session_start_ns,
+    # stated on the wall clock) lays device lines beside host events; but
+    # counted by the monotonic clock from one reading of the wall clock at
+    # import: ``time.time_ns()`` itself may be stepped back under a running
+    # process (a VM's time sync under load), and a span would then start
+    # before the one before it on its own thread ended.  As a float of
+    # epoch us a stamp is good to 0.25 us.
+    return (_EPOCH_NS + time.monotonic_ns()) / 1e3
 
 
 def epoch_us() -> int:

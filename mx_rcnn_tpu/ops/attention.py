@@ -8,7 +8,8 @@ trace time:
   head size a multiple of 128 lanes, sequence a multiple of the kernels'
   blocks), the Mosaic kernels of ``ops/attention_pallas.py``: score tiles,
   running maximum and sum and the accumulator stay in VMEM, forward and
-  backward;
+  backward; heads of another width, or with values narrower than their
+  queries (latent attention), enter padded with zeros;
 * anywhere else (the CPU tests; ``nemotron_h_tiny``'s head size 16 on any
   platform) the blocked path below.  A block of ``block_q`` queries sees
   only the keys up to its own end, so the products above the diagonal are
@@ -72,29 +73,51 @@ def _block(qb, kb, vb, lo: int):
                       preferred_element_type=jnp.float32).astype(qb.dtype)
 
 
-def _kernels_take(q, k, interpret: bool) -> bool:
-    """The Mosaic kernels run these operands: a TPU (or the interpreter)
-    and shapes they tile.  Platform and shapes are read at trace time."""
+def _kernel_width(q, k, v, interpret: bool) -> int:
+    """The head size at which the Mosaic kernels run these operands, or 0
+    where they do not: a TPU (or the interpreter) and, with query/key and
+    value widths rounded up together to the 128 lanes, shapes the kernels
+    tile.  Platform and shapes are read at trace time."""
     from mx_rcnn_tpu.ops import attention_pallas
 
     _, s, hq, d = q.shape
-    return ((interpret or jax.default_backend() == "tpu")
-            and hq % k.shape[2] == 0 and attention_pallas.tiles(s, d))
+    wide = -(-max(d, v.shape[-1]) // 128) * 128
+    takes = ((interpret or jax.default_backend() == "tpu")
+             and hq % k.shape[2] == 0 and attention_pallas.tiles(s, wide))
+    return wide if takes else 0
+
+
+def _widened(t, wide: int):
+    return jnp.pad(t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
 
 
 def causal_gqa(q, k, v, block_q: int = 256, interpret: bool = False):
-    """q (B, S, Hq, D); k, v (B, S, Hkv, D) with Hq a multiple of Hkv (query
-    head j reads key-value head j // (Hq // Hkv)).  Scale D^-1/2, no
-    positional term.  Returns (B, S, Hq, D) in ``q.dtype``.
+    """q (B, S, Hq, D); k (B, S, Hkv, D); v (B, S, Hkv, Dv) with Hq a
+    multiple of Hkv (query head j reads key-value head j // (Hq // Hkv)).
+    Scale D^-1/2, no positional term.  Returns (B, S, Hq, Dv) in
+    ``q.dtype``.  ``Dv`` may differ from ``D`` (latent attention: 192
+    against 128).
 
     On a TPU (``interpret`` runs the kernels in the Pallas interpreter
     instead: what a test passes, on any platform), with shapes the kernels
-    tile, ``attention_pallas.flash_causal_gqa``; else the blocked path in
-    blocks of ``block_q`` queries, which the kernels take no notice of."""
-    if _kernels_take(q, k, interpret):
+    tile, ``attention_pallas.flash_causal_gqa``; heads that are no multiple
+    of the 128 lanes, or whose value width is not their query/key width,
+    enter it padded with zeros to one width ``W`` (zeros add nothing to a
+    score, and the padded value columns are cut off again), the queries
+    times ``sqrt(W / D)`` so that the kernels' ``W^-1/2`` is ``D^-1/2``.
+    Else the blocked path in blocks of ``block_q`` queries, which the
+    kernels take no notice of."""
+    wide = _kernel_width(q, k, v, interpret)
+    if wide:
         from mx_rcnn_tpu.ops.attention_pallas import flash_causal_gqa
 
-        return flash_causal_gqa(q, k, v, None, interpret)
+        d, dv = q.shape[-1], v.shape[-1]
+        if wide == d == dv:
+            return flash_causal_gqa(q, k, v, None, interpret)
+        scaled = (q.astype(jnp.float32) * (wide / d) ** 0.5).astype(q.dtype)
+        out = flash_causal_gqa(_widened(scaled, wide), _widened(k, wide),
+                               _widened(v, wide), None, interpret)
+        return out[..., :dv]
     return _blocked_causal_gqa(q, k, v, block_q)
 
 
@@ -115,5 +138,5 @@ def _blocked_causal_gqa(q, k, v, block_q: int):
     outs = [_block(qg[:, :, :, lo:lo + block_q], kg[:, :, :lo + block_q],
                    vg[:, :, :lo + block_q], lo)
             for lo in range(0, s, block_q)]
-    out = jnp.concatenate(outs, axis=3)            # (B, G, R, S, D)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, d)
+    out = jnp.concatenate(outs, axis=3)            # (B, G, R, S, Dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, v.shape[-1])

@@ -1,11 +1,13 @@
 """A routed-expert layer that is told which experts it holds.
 
 The router keeps its published width: every token scores all ``E`` experts
-and chooses ``top_k`` of them.  This chip holds the ``count`` experts
+and chooses ``top_k`` of them, among all or among those of the groups a
+group-limited router keeps (``limit_to_groups``).  This chip holds the ``count`` experts
 ``first .. first + count`` (``held``) and computes their part of the
 result: the assignments that fall on held experts are sorted by expert,
-their token rows gathered, two grouped products run over them, and the
-weighted rows are scatter-added back to their tokens.  On a TPU the
+their token rows gathered, two grouped products (three for a gated
+expert) run over them, and the weighted rows are scatter-added back to
+their tokens.  On a TPU the
 products, and through a ``custom_vjp`` the four of their backward pass,
 are the row-tiled Mosaic kernels of ``ops/gmm_pallas.py``; on any other
 platform they are ``lax.ragged_dot`` and its autodiff (a Mosaic kernel
@@ -23,7 +25,7 @@ is ``tokens * min(top_k, count)`` it cannot be exceeded.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,15 +60,34 @@ def row_capacity(tokens: int, top_k: int, n_experts: int, count: int,
     return int(min(bound, -(-int(factor * expected) // 128) * 128))
 
 
-def route(x, w_router, bias, top_k: int, scale: float, norm_topk: bool):
+def limit_to_groups(biased, n_group: int, topk_group: int):
+    """Group-limited choice: ``biased`` (T, E) in ``n_group`` groups of
+    consecutive experts, a group's score the sum of its two best, the
+    ``topk_group`` best groups kept (the lower index on a tie, as
+    ``lax.top_k`` breaks it); the other groups' entries become -inf."""
+    t, e = biased.shape
+    grouped = biased.reshape(t, n_group, e // n_group)
+    score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+    _, kept = jax.lax.top_k(score, topk_group)
+    keep = (kept[:, :, None] == jnp.arange(n_group)).any(1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(t, e)
+
+
+def route(x, w_router, bias, top_k: int, scale: float, norm_topk: bool,
+          groups: Optional[Tuple[int, int]] = None):
     """x (T, H) -> (idx (T, k) int32, weight (T, k) float32).  Logits and
-    scores in float32; the choice is by ``scores + bias``, the weights are
-    the unbiased scores of the chosen (divided by their sum with
-    ``norm_topk``) times ``scale``."""
+    scores in float32; the choice is by ``scores + bias`` — among all
+    experts, or with ``groups = (n_group, topk_group)`` among the experts of
+    the groups :func:`limit_to_groups` keeps — the weights are the unbiased
+    scores of the chosen (divided by their sum with ``norm_topk``) times
+    ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(scores + bias, top_k)
+    biased = scores + bias
+    if groups is not None:
+        biased = limit_to_groups(biased, *groups)
+    _, idx = jax.lax.top_k(biased, top_k)
     weight = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
         weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
@@ -107,6 +128,15 @@ def relu2_ffn(x, w_up, w_down):
                    preferred_element_type=jnp.float32)
 
 
+def swiglu_ffn(x, w_gate, w_up, w_down):
+    """down(silu(gate(x)) * up(x)), no bias, no clamp; products accumulate
+    in float32, the result is float32."""
+    g = jnp.dot(x, w_gate.astype(x.dtype), preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up.astype(x.dtype), preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype),
+                   w_down.astype(x.dtype), preferred_element_type=jnp.float32)
+
+
 def grouped_product(rows, w, group_sizes, interpret: bool = False):
     """rows (C, K) sorted by group; w (count, K, N), cast to ``rows``'
     dtype for the product -> (C, N) float32, accumulated in float32.  On a
@@ -122,16 +152,24 @@ def grouped_product(rows, w, group_sizes, interpret: bool = False):
                                   preferred_element_type=jnp.float32)
 
 
-def held_experts(x, routed: Routed, w_up, w_down, interpret: bool = False):
+def held_experts(x, routed: Routed, w_up, w_down, interpret: bool = False,
+                 w_gate=None):
     """The held experts' part of the layer's output, (T, H) float32.
-    x (T, H); w_up (count, H, F); w_down (count, F, H).  Rows past the last
-    held assignment enter as zeros and leave as zeros, whatever the grouped
-    product (``grouped_product``: a Mosaic kernel on a TPU, ``ragged_dot``
-    elsewhere) writes there."""
+    x (T, H); w_up (count, H, F); w_down (count, F, H): an expert is
+    ``down(relu(up x)^2)``, or with ``w_gate`` (count, H, F) the gated
+    ``down(silu(gate x) * up x)``, a third grouped product.  Rows past the
+    last held assignment enter as zeros and leave as zeros, whatever the
+    grouped product (``grouped_product``: a Mosaic kernel on a TPU,
+    ``ragged_dot`` elsewhere) writes there."""
     keep = routed.valid[:, None]
     xs = jnp.where(keep, x[routed.token], 0)
     h = grouped_product(xs, w_up, routed.group_sizes, interpret)
-    h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
-    y = grouped_product(h, w_down, routed.group_sizes, interpret)
+    if w_gate is None:
+        h = jnp.square(jax.nn.relu(h))
+    else:
+        h = h * jax.nn.silu(
+            grouped_product(xs, w_gate, routed.group_sizes, interpret))
+    y = grouped_product(h.astype(x.dtype), w_down, routed.group_sizes,
+                        interpret)
     y = jnp.where(keep, y * routed.weight[:, None], 0.0)
     return jnp.zeros(x.shape, jnp.float32).at[routed.token].add(y)

@@ -106,8 +106,8 @@ def test_causal_gqa_told_to_interpret_runs_the_kernels_at_their_blocks():
 @pytest.mark.parametrize("s,d", [
     pytest.param(attention_pallas.BLOCK_Q + 128, 128,
                  id="sequence_off_the_block"),
-    pytest.param(2 * attention_pallas.BLOCK_Q, 64,
-                 id="head_size_off_the_lanes"),
+    pytest.param(attention_pallas.BLOCK_Q + 128, 64,
+                 id="sequence_off_the_block_and_head_off_the_lanes"),
 ])
 def test_shapes_the_kernels_do_not_tile_take_the_blocked_path(s, d):
     assert not attention_pallas.tiles(s, d)
@@ -120,6 +120,33 @@ def test_shapes_the_kernels_do_not_tile_take_the_blocked_path(s, d):
     # the blocked path's own demand stands as it was
     with pytest.raises(ValueError, match="no multiple of block_q"):
         causal_gqa(*(a[:, :s - 7] for a in args), 128, interpret=True)
+
+
+@pytest.mark.parametrize("d,dv", [
+    pytest.param(192, 128, id="latent_attention_192_against_128"),
+    pytest.param(64, 64, id="head_size_64"),
+])
+def test_heads_off_the_lanes_enter_the_kernels_padded(d, dv):
+    """Query/key and value widths are rounded up together to the lanes and
+    padded with zeros, the queries rescaled so that the kernels' scale is
+    the true width's; the kernels themselves take no such head."""
+    s = 2 * attention_pallas.BLOCK_Q
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, k = (jax.random.normal(key, (1, s, 2, d)) for key in ks[:2])
+    v, ct = (jax.random.normal(key, (1, s, 2, dv)) for key in ks[2:])
+    assert not attention_pallas.tiles(s, d)
+    text = str(jax.make_jaxpr(
+        lambda *a: causal_gqa(*a, interpret=True))(q, k, v))
+    assert text.count("pallas_call[") == 1
+    got = _vjp(lambda *a: causal_gqa(*a, interpret=True), q, k, v, ct)
+    want = _vjp(_full, q, k, v, ct)
+    assert got[0].shape == (1, s, 2, dv)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape and _rel(g, w) < 1e-5, (name, _rel(g, w))
+    # off the TPU and not told to interpret: the blocked path, to the same
+    blocked = _vjp(lambda *a: causal_gqa(*a, 128), q, k, v, ct)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), blocked, want):
+        assert _rel(g, w) < 1e-5, (name, _rel(g, w))
 
 
 def test_a_sequence_whose_dk_dv_outgrow_vmem_does_not_tile():
