@@ -608,6 +608,8 @@ def fit(
                 return state
         return state
     finally:
+        if lowerings is not None:
+            lowerings.mark_step(None)  # a later lowering is no step's
         if stager is not None:
             stager.close()  # early return/error: release the stage thread
         if prof is not None:

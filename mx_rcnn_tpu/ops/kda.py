@@ -41,7 +41,11 @@ sub-chunk are then a matrix product.
 
 Everything that holds a decay is float32; the matrix products take their
 operands in ``q.dtype`` (bfloat16 on the chip) and accumulate in float32;
-the triangular inverse is float32 at the highest precision.
+the triangular inverse is float32 throughout: on a TPU forward substitution
+on the vector unit with the chunk-heads in the lanes (the Mosaic kernel of
+``ops/kda_pallas.py``, whose backward is ``-T^T dT T^T``, two float32
+products at the highest precision), elsewhere products at the highest
+precision with autodiff's backward (``inv_unit_lower``).
 """
 
 from __future__ import annotations
@@ -53,12 +57,38 @@ SUB = 16
 _HI = jax.lax.Precision.HIGHEST
 
 
-def inv_unit_lower(m):
-    """Inverse of unit-lower-triangular matrices (..., n, n), float32: blocks
-    of 16 by the finite Neumann product ``(I - A)(I + A^2)(I + A^4)(I +
-    A^8)`` (``A`` strictly lower, ``A^16 = 0``), larger ones by halves,
-    ``[[a, 0], [c, d]]^-1 = [[a^-1, 0], [-d^-1 c a^-1, d^-1]]``, so that
-    no power beyond the fifteenth of a block is ever formed."""
+def _kernel_takes(n: int, dtype, interpret: bool) -> bool:
+    """A TPU (or the interpreter) and matrices the kernel eliminates:
+    platform and shape are read at trace time."""
+    from mx_rcnn_tpu.ops import kda_pallas
+
+    return ((interpret or jax.default_backend() == "tpu")
+            and kda_pallas.takes(n, dtype))
+
+
+def inv_unit_lower(m, interpret: bool = False):
+    """Inverse of unit-lower-triangular matrices (..., n, n), float32.  One
+    result, two ways to it, chosen at trace time from the platform and
+    ``n``: on a TPU (``interpret`` runs the kernel in the Pallas interpreter
+    instead: what a test passes, on any platform), for an ``n`` that is a
+    multiple of 8, row-by-row substitution with the matrices in the lanes
+    (``kda_pallas.inv_unit_lower``); else ``inv_unit_lower_jnp``."""
+    if _kernel_takes(m.shape[-1], m.dtype, interpret):
+        from mx_rcnn_tpu.ops.kda_pallas import inv_unit_lower as by_kernel
+
+        return by_kernel(m, interpret)
+    return inv_unit_lower_jnp(m)
+
+
+def inv_unit_lower_jnp(m):
+    """The inverse as matrix products, for any ``n``: blocks of 16 by the
+    finite Neumann product ``(I - A)(I + A^2)(I + A^4)(I + A^8)`` (``A``
+    strictly lower, ``A^16 = 0``), larger ones by halves, ``[[a, 0], [c,
+    d]]^-1 = [[a^-1, 0], [-d^-1 c a^-1, d^-1]]``, so that no power beyond
+    the fifteenth of a block is ever formed.  Float32 at the highest
+    precision (six passes of a TPU's MXU on blocks that fill an eighth of a
+    lane tile: 1.8 ms for the 2048 matrices of a part, PERF.md section 6,
+    PR 39); autodiff is its backward."""
     n = m.shape[-1]
     if n <= 16:
         eye = jnp.eye(n, dtype=m.dtype)
@@ -69,15 +99,15 @@ def inv_unit_lower(m):
             inv = inv + jnp.matmul(inv, power, precision=_HI)
         return inv
     h = n // 2
-    a = inv_unit_lower(m[..., :h, :h])
-    d = inv_unit_lower(m[..., h:, h:])
+    a = inv_unit_lower_jnp(m[..., :h, :h])
+    d = inv_unit_lower_jnp(m[..., h:, h:])
     c = -jnp.matmul(jnp.matmul(d, m[..., h:, :h], precision=_HI), a,
                     precision=_HI)
     top = jnp.concatenate([a, jnp.zeros_like(m[..., :h, h:])], -1)
     return jnp.concatenate([top, jnp.concatenate([c, d], -1)], -2)
 
 
-def _within_chunks(q, k, v, g, beta, chunk: int, sub: int):
+def _within_chunks(q, k, v, g, beta, chunk: int, sub: int, interpret: bool):
     """Everything of the rule that needs no other chunk, for a batch of
     sequences: (A_qk (B, NC, H, L, L), W (B, NC, H, L, K), U_0 float32
     (B, NC, H, L, V), K exp(G_L - G), Q exp G, exp G_L (B, NC, H, K) float32,
@@ -130,8 +160,8 @@ def _within_chunks(q, k, v, g, beta, chunk: int, sub: int):
         a_kk = jnp.where(pos[:, None] > pos[None, :], scores(k_s), 0.0)
 
     with jax.named_scope("kda_solve"):
-        t_low = inv_unit_lower(
-            jnp.eye(chunk, dtype=f32) + beta_l * a_kk).astype(dtype)
+        t_low = inv_unit_lower(jnp.eye(chunk, dtype=f32) + beta_l * a_kk,
+                               interpret).astype(dtype)
         decay_in = jnp.exp(g_in)
         w = jnp.matmul(t_low, (flat(k6) * decay_in * beta_l).astype(dtype),
                        preferred_element_type=f32).astype(dtype)
@@ -143,13 +173,15 @@ def _within_chunks(q, k, v, g, beta, chunk: int, sub: int):
             jnp.min(g_end))
 
 
-def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = SUB):
+def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = SUB,
+                interpret: bool = False):
     """q, k (B, S, H, K) as the rule takes them (normalised, ``q`` scaled);
     v (B, S, H, V); g (B, S, H, K) float32 log-decay in ``[-80 / sub, 0]``;
     beta (B, S, H) float32.  Returns (o (B, S, H, V) in ``q.dtype``, the
     most negative within-chunk cumulative log-decay, a float32 scalar
     without gradient).  ``S`` must be a multiple of ``chunk`` and ``chunk``
-    of ``sub`` (a chunk shorter than ``sub`` is one sub-chunk).
+    of ``sub`` (a chunk shorter than ``sub`` is one sub-chunk).  ``interpret``
+    is ``inv_unit_lower``'s.
 
     Its float32 intermediates are a dozen arrays of the size of ``g``: a
     caller short of memory hands over one sequence at a time
@@ -162,7 +194,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = SUB):
         raise ValueError(f"sequence {s} / chunk {chunk} / sub-chunk {sub} "
                          "do not divide")
     a_qk, w, u0, k_d, q_g, chunk_decay, g_min = _within_chunks(
-        q, k, v, g, beta, chunk, sub)
+        q, k, v, g, beta, chunk, sub, interpret)
 
     with jax.named_scope("kda_states"):
         def carry(state, inp):

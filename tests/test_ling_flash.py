@@ -13,6 +13,8 @@ products sum a chunk of them and pass through the inverse, so its outputs
 are held to 5e-2 of the output's scale.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,7 @@ import pytest
 
 from mx_rcnn_tpu.config import generate_config
 from mx_rcnn_tpu.models import ling_flash
+from mx_rcnn_tpu.ops import kda_pallas
 from mx_rcnn_tpu.ops import moe as moe_ops
 from mx_rcnn_tpu.ops.kda import inv_unit_lower, kda_chunked
 
@@ -72,14 +75,21 @@ def _recurrence(q, k, v, g, beta):
     return jax.vmap(lambda *a: ref.delta_rule(*a, 16))(q, k, v, g, beta)
 
 
-@pytest.mark.parametrize("chunk,pinned", [
-    (4, False), (16, False), (64, False), (16, True), (64, True)],
+@pytest.mark.parametrize("chunk,pinned,kernel", [
+    (4, False, False), (16, False, False), (64, False, False),
+    (16, True, False), (64, True, False), (64, False, True)],
     ids=["chunk4", "chunk16", "chunk64_sub16", "chunk16_at_the_bound",
-         "chunk64_at_the_bound"])
-def test_chunked_delta_rule_is_the_recurrence(chunk, pinned):
+         "chunk64_at_the_bound", "chunk64_interpreted_kernel"])
+def test_chunked_delta_rule_is_the_recurrence(chunk, pinned, kernel):
+    """``kernel``: the triangular inverse by the Mosaic kernel of
+    ``ops/kda_pallas.py`` in the Pallas interpreter, its own backward in
+    the gradients: what the rule runs on a TPU."""
     args = _rule_inputs(0, pinned=pinned)
     want = jax.jit(_recurrence)(*args)
-    got, g_min = jax.jit(kda_chunked, static_argnums=5)(*args, chunk)
+    rule = functools.partial(kda_chunked, interpret=kernel)
+    assert ("pallas_call" in str(jax.make_jaxpr(
+        lambda *a: rule(*a, chunk))(*args))) == kernel
+    got, g_min = jax.jit(rule, static_argnums=5)(*args, chunk)
     scale = float(jnp.abs(want).max())
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5 * scale)
@@ -89,7 +99,7 @@ def test_chunked_delta_rule_is_the_recurrence(chunk, pinned):
     assert (float(g_min) < -300) == (pinned and chunk == 64)
     cot = jax.random.normal(jax.random.PRNGKey(9), want.shape)
     grads = jax.jit(jax.grad(
-        lambda *a: jnp.sum(kda_chunked(*a, chunk)[0] * cot),
+        lambda *a: jnp.sum(rule(*a, chunk)[0] * cot),
         argnums=(0, 1, 2, 3, 4)))(*args)
     wants = jax.jit(jax.grad(lambda *a: jnp.sum(_recurrence(*a) * cot),
                              argnums=(0, 1, 2, 3, 4)))(*args)
@@ -117,13 +127,30 @@ def test_delta_rule_refuses_a_sequence_its_chunk_does_not_divide():
         kda_chunked(*_rule_inputs(0, s=48), 24)
 
 
-@pytest.mark.parametrize("n", [1, 2, 5, 16, 64])
-def test_inverse_of_unit_lower_triangular(n):
+@pytest.mark.parametrize("n,kernel", [
+    (n, kernel) for n in [1, 2, 5, 8, 16, 64] for kernel in (False, True)
+    if not kernel or kda_pallas.takes(n)])
+def test_inverse_of_unit_lower_triangular(n, kernel):
+    """``kernel``: by the interpreted Mosaic kernel, for every ``n`` it
+    takes; else the ``jnp`` form, which is what the choice makes off the
+    TPU.  Both are float32 and as near the inverse (4e-7 of its largest
+    entry, 585 at ``n = 64``); what differs is which equation keeps the
+    small residual.  Substitution solves ``M T = I`` row by row — the
+    equation the rule needs, ``M (T b) = b`` — and at ``n = 64`` leaves 6e-5
+    there and 3.1e-4 in ``T M = I``; the product form leaves 1.9e-4 either
+    way.  Each is held to the one tolerance in the equation it solves, and
+    both to the inverse itself."""
     a = jnp.tril(jax.random.normal(jax.random.PRNGKey(n), (3, n, n)), -1)
     m = jnp.eye(n) + 0.5 * a
-    np.testing.assert_allclose(inv_unit_lower(m) @ m,
+    assert ("pallas_call" in str(jax.make_jaxpr(
+        lambda x: inv_unit_lower(x, kernel))(m))) == kernel
+    inv = inv_unit_lower(m, kernel)
+    np.testing.assert_allclose(m @ inv if kernel else inv @ m,
                                np.broadcast_to(np.eye(n), (3, n, n)),
                                atol=3e-4)
+    want = np.linalg.inv(np.asarray(m, np.float64))
+    np.testing.assert_allclose(inv, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
 
 
 # ---- the mixers against the reference --------------------------------------------
@@ -428,7 +455,8 @@ def test_the_step_names_its_scopes():
     text = jax.jit(make_train_step(model, cfg, tx, mode="lm")).lower(
         state, TokenBatch(jax.ShapeDtypeStruct((2, 64), jnp.int32)),
         jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
-    for scope in ("embed", "kda_mixer/mixer/", "kda_scan/kda_scores", "mla",
+    for scope in ("embed", "kda_mixer/mixer/", "kda_scan/kda_scores",
+                  "kda_scan/kda_solve", "mla",
                   "dense_mlp", "moe/mlp/moe_route", "moe/mlp/moe_experts",
                   "moe_grouped", "lm_head", "optimizer"):
         assert scope in text, scope
