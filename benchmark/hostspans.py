@@ -201,15 +201,31 @@ def attribute(reduced, events: Sequence[dict], warmup_steps: int
 
 
 def unscoped_s(reduced, stages: Sequence[str]) -> Optional[float]:
-    """Device seconds (mean over chips) of ops under none of ``stages``,
-    the scopes the cell's family names (its ``STAGES``)."""
+    """Device seconds (mean over chips) in which an op ran and none under
+    ``stages``, the scopes the cell's family names (its ``STAGES``): the
+    union of all the ops' intervals less the union of the staged ops'.
+
+    Taken from the intervals and not op by op, because a loop's body is not
+    named as its loop is: the ``while`` op of a ``lax.map`` / ``lax.scan``
+    lies under its stage and, on the same device line, around its body's
+    ops in time, but the copies and fusions the compiler makes inside the
+    body carry no name path, and under a ``jax.checkpoint`` a body's path
+    begins at ``closed_call`` with no ``jit(step)/.../<stage>`` before it.
+    The body so counts with its loop: it is unscoped only where that
+    ``while`` is, and the stages' union plus this is the step's busy time
+    exactly."""
     if reduced is None or not reduced.devices:
         return None
     inside = re.compile(r"(^|[/(])(" + "|".join(map(re.escape, stages))
                         + r")([/)]|$)")
-    per = [trace_mod.union_ns([(s, s + d) for _, path, s, d in dev["ops"]
-                               if not inside.search(path)]) * 1e-9
-           for dev in reduced.devices]
+    per = []
+    for dev in reduced.devices:
+        every = [(s, s + d) for _, _, s, d in dev["ops"]]
+        staged = [(s, s + d) for _, path, s, d in dev["ops"]
+                  if inside.search(path)]
+        # in ns before the scale: the recorded trace's reading to the bit
+        per.append((trace_mod.union_ns(every) - trace_mod.union_ns(staged))
+                   * 1e-9)
     return sum(per) / len(per)
 
 

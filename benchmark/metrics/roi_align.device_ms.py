@@ -1,6 +1,6 @@
 """Device milliseconds per step of ops under the ``roi_align`` scope
-(ROIAlign inside ``rcnn_losses``, whichever backend
-``train.roi_align_backend`` resolves to, forward and transpose)."""
+(ROIAlign inside ``rcnn_losses``: the four interpolation einsums, forward and
+transpose)."""
 
 from benchmark import hostspans
 

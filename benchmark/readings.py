@@ -9,9 +9,11 @@ at the cell's own size, through the same ``compare_training`` a run uses.
 * upper readings: on each of ``--control-seeds`` the plain reference in
   float32 beside what is put in the program's place: the reference in
   ``float8`` (the control, a plain cast where the configuration has
-  bfloat16), in ``float8_scaled`` (the careful 8-bit recipe) and with half
-  of every batch left out (``half``).  A state left unchanged, or leaves
-  left unmoved, read 1 by the measure and need no run.
+  bfloat16), in ``float8_scaled`` (the careful 8-bit recipe: since PR 40
+  no limit of ``r101-coco.train`` stands under its readings, PERF.md
+  section 2 names it under "Cannot see") and with half of every batch left
+  out (``half``).  A state left unchanged, or leaves left unmoved, read 1
+  by the measure and need no run.
 
 One process reads everything, so each program compiles once.  Rows go to
 standard output and to ``chiprun_out/readings_<cell>_<time>.json``; PERF.md

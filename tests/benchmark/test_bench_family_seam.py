@@ -5,12 +5,21 @@ adds a family that is no detector (two matrix products a token with stated
 ``flops`` and ``bytes``, ``times`` the tokens of a sequence, scopes of its
 own; no image size, no ROIs, no anchors), its configuration, traffic and
 workload files, a driver kind that returns a canned result over the recorded
-chip trace, one reader, and entries appended to the manifest; then runs the
-copy's own ``benchmark/run.py``.  Every file it writes has to be new and
+chip trace, three readers, and entries appended to the manifest; then runs
+the copy's own ``benchmark/run.py``.  Every file it writes has to be new and
 every file it copied has to be as the repo has it: an edit the stub would
 need to ``run.py``, ``flops.py``, ``hostspans.py`` or a reader fails here.
+
+The accepted cells' tests have to take it too.  Each ``test_bench_*.py``
+beside this file keeps what it asserts of the manifest in functions that
+take the manifest and lists them as ``MANIFEST_CHECKS``; the last test here
+runs them all against the stub's manifest.  So a cell's test may assert that
+the accepted entries come first, in order, and never how many follow: one
+that pins a total or a tail fails here, in the PR that writes it.
 """
 
+import glob
+import importlib
 import json
 import os
 import re
@@ -76,14 +85,33 @@ def run(cell, *, seed, seconds, trace, t_start):
     return dict(canned["result"], trace=reduced)
 '''
 
-READER = '''"""Device milliseconds per step under the stub's ``mixer`` scope."""
+READER = '''"""Device milliseconds per step under the stub's ``%s`` scope."""
 
 
 def read(ctx):
     t = ctx["trace"]
-    sec = t.scope_s("mixer") if t else None
+    sec = t.scope_s("%s") if t else None
     return None if not sec else 1e3 * sec / t.steps
 '''
+
+COUNTER = '''"""Sequences an optimizer step, as the driver's result states them."""
+
+
+def read(ctx):
+    return ctx.get("images_per_step")
+'''
+
+# the stub's three per_layer entries, as a further family would append them
+ENTRIES = [
+    {"name": "mixer.device_ms", "unit": "ms", "better": "lower",
+     "source": "device_trace", "layer": "models",
+     "moves": "train_imgs_per_s", "workloads": [CELL]},
+    {"name": "mlp.device_ms", "unit": "ms", "better": "lower",
+     "source": "device_trace", "layer": "models",
+     "moves": "train_imgs_per_s", "workloads": [CELL]},
+    {"name": "stub.rows_per_step", "unit": "rows", "better": "higher",
+     "source": "program_counter", "layer": "fit loop and input plane",
+     "moves": "train_imgs_per_s", "workloads": [CELL]}]
 
 RESULT = {
     "correct": True, "attempted": 80, "failed": 0,
@@ -127,7 +155,10 @@ def stub_tree(tmp_path_factory):
     copied = _files(bdir)
     _write_new(os.path.join(bdir, "families", "stub.py"), FAMILY)
     _write_new(os.path.join(bdir, "drivers", "canned.py"), DRIVER)
-    _write_new(os.path.join(bdir, "metrics", "mixer.device_ms.py"), READER)
+    for scope in ("mixer", "mlp"):
+        _write_new(os.path.join(bdir, "metrics", f"{scope}.device_ms.py"),
+                   READER % (scope, scope))
+    _write_new(os.path.join(bdir, "metrics", "stub.rows_per_step.py"), COUNTER)
     _write_new(os.path.join(bdir, "configs", "stub-lm.json"), json.dumps({
         "name": "stub-lm", "reduced": [], "network": {"family": "stub"},
         "hidden_size": HIDDEN, "intermediate_size": WIDE}))
@@ -146,10 +177,7 @@ def stub_tree(tmp_path_factory):
     bench["workloads"].append({"name": CELL, "config": "stub-lm",
                                "traffic": "stub-tokens", "chips": 1,
                                "why": why})
-    bench["per_layer"].append({
-        "name": "mixer.device_ms", "unit": "ms", "better": "lower",
-        "source": "device_trace", "layer": "models",
-        "moves": "train_imgs_per_s", "workloads": [CELL]})
+    bench["per_layer"] += ENTRIES
     with open(os.path.join(top, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return top, copied, bench
@@ -204,8 +232,10 @@ def test_a_new_family_is_new_files(stub_tree):
                            "parent_readers.json")) as f:
         detectors = json.load(f)["values"]["vgg16-voc07.train"]
     assert got["step.unscoped_ms"] > 5 * detectors["step.unscoped_ms"]
-    assert got["mixer.device_ms"] == pytest.approx(
-        1e3 * red.scope_s("mixer") / red.steps, rel=1e-12)
+    for scope in ("mixer", "mlp"):
+        assert got[f"{scope}.device_ms"] == pytest.approx(
+            1e3 * red.scope_s(scope) / red.steps, rel=1e-12)
+    assert got["stub.rows_per_step"] == BATCH
     # the device's readers and the driver's counters speak for any family
     assert got["device.idle_pct"] == pytest.approx(
         100.0 * (1 - red.busy_s() / red.window_s), rel=1e-9)
@@ -243,8 +273,8 @@ def test_a_new_familys_cell_reports_the_end_to_end_metrics(stub_tree):
 
 
 def test_the_stub_changed_no_file_the_benchmark_has(stub_tree):
-    """Run last of this module: after both runs every copied file is still
-    what the repo holds, the stub's six files are all that is new, and the
+    """After both runs every copied file is still
+    what the repo holds, the stub's eight files are all that is new, and the
     manifest's own entries stand as they were, the stub's appended."""
     top, copied, bench = stub_tree
     now = _files(os.path.join(top, "benchmark"))
@@ -252,9 +282,64 @@ def test_the_stub_changed_no_file_the_benchmark_has(stub_tree):
     assert {k: now[k] for k in copied} == copied == repo
     assert sorted(set(now) - set(copied)) == [
         "configs/stub-lm.json", "drivers/canned.py", "families/stub.py",
-        "metrics/mixer.device_ms.py", "traffic/stub-tokens.json",
+        "metrics/mixer.device_ms.py", "metrics/mlp.device_ms.py",
+        "metrics/stub.rows_per_step.py", "traffic/stub-tokens.json",
         f"workloads/{CELL}.json"]
     for group in ("configs", "workloads", "per_layer", "end_to_end"):
         assert bench[group][:len(BENCH[group])] == BENCH[group]
     for key in ("command", "paths", "run_seconds"):
         assert bench[key] == BENCH[key]
+
+
+def _manifest_checks():
+    """(module, function) of every ``MANIFEST_CHECKS`` entry of the test
+    files beside this one.  A file that reads the manifest has to have the
+    list: what it asserts of the manifest is then run against the stub's."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    out = []
+    for path in sorted(glob.glob(os.path.join(here, "test_bench_*.py"))):
+        name = os.path.basename(path)[:-3]
+        with open(path) as f:
+            source = f.read()
+        if path == os.path.abspath(__file__) or not (
+                "manifest()" in source or "BENCHMARK.json" in source):
+            continue
+        mod = importlib.import_module(name)
+        assert hasattr(mod, "MANIFEST_CHECKS"), (
+            f"{name}.py reads the manifest: keep what it asserts of it in "
+            "functions that take the manifest, listed as MANIFEST_CHECKS")
+        out += [(name, fn) for fn in mod.MANIFEST_CHECKS]
+    return out
+
+
+def test_a_further_familys_entries_pass_every_accepted_cells_checks(
+        stub_tree):
+    """The manifest with a fifth family's configuration, cell and three
+    ``per_layer`` entries appended, its cell joining the list of a metric
+    whose scope it shares, passes everything the accepted cells' tests
+    assert of the manifest; a check that counts what follows does not."""
+    top, _, bench = stub_tree
+    bench = json.loads(json.dumps(bench))
+    shared = next(m for m in bench["per_layer"]
+                  if m["name"] == "optimizer.device_ms")
+    shared["workloads"].append(CELL)
+    checks = _manifest_checks()
+    assert {name for name, _ in checks} >= {
+        "test_bench_harness", "test_bench_spans", "test_bench_lm",
+        "test_bench_ling"}
+    for _, check in checks:
+        check(bench)
+    import test_bench_harness
+
+    test_bench_harness.every_name_has_its_file_and_every_file_its_name(
+        bench, os.path.join(top, "benchmark"))
+
+    # the form PR 38's test had: the last entries are mine, and so many
+    def pinned(bench):
+        names = [m["name"] for m in bench["per_layer"]]
+        mine = [m["name"] for m in BENCH["per_layer"]][-3:]
+        assert names[-3:] == mine and len(names) == len(BENCH["per_layer"])
+
+    pinned(BENCH)
+    with pytest.raises(AssertionError):
+        pinned(bench)

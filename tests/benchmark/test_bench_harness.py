@@ -266,8 +266,8 @@ def test_conv_and_dense_hand_counts():
     assert flops.step_flops_per_image([frozen]) == flops.forward_flops(conv)
 
 
-def _config(name):
-    with open(os.path.join(ROOT, "benchmark", "configs", f"{name}.json")) as f:
+def _config(name, bdir=os.path.join(ROOT, "benchmark")):
+    with open(os.path.join(bdir, "configs", f"{name}.json")) as f:
         return json.load(f)
 
 
@@ -427,24 +427,28 @@ def test_unknown_device_kind_has_no_peaks():
 
 # ---- manifest <-> files --------------------------------------------------
 
-def test_manifest_names_units_and_sources():
+def names_units_and_sources(bench):
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        names = [e["name"] for e in BENCH[group]]
+        names = [e["name"] for e in bench[group]]
         assert len(set(names)) == len(names)
-        for e in BENCH[group]:
+        for e in bench[group]:
             assert NAME.match(e["name"]), e["name"]
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+    for m in bench["end_to_end"] + bench["per_layer"]:
         assert UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for c in BENCH["configs"]:
+    for c in bench["configs"]:
         assert 1 <= len(c["source"]) <= 200 and "\n" not in c["source"]
-    for w in BENCH["workloads"]:
+    for w in bench["workloads"]:
         assert 1 <= len(w["why"]) <= 200
-    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
-    for m in BENCH["end_to_end"]:
+    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
+    for m in bench["end_to_end"]:
         assert 0 < m["bound"] <= 0.1
+
+
+def test_manifest_names_units_and_sources():
+    names_units_and_sources(BENCH)
 
 
 DETECTOR_SCOPED = {
@@ -453,48 +457,70 @@ DETECTOR_SCOPED = {
     "proposal_target.device_ms", "anchor_target.device_ms"}
 
 
+def listed_cells_exist_and_the_detectors_come_first(bench):
+    """Every listed cell exists, once; a metric of a scope only the
+    detectors' step names lists their two cells first."""
+    cells = {w["name"] for w in bench["workloads"]}
+    listed = {m["name"]: m["workloads"] for m in
+              bench["end_to_end"] + bench["per_layer"] if "workloads" in m}
+    assert DETECTOR_SCOPED <= set(listed)
+    for name, where in listed.items():
+        assert set(where) <= cells and len(set(where)) == len(where), name
+        if name in DETECTOR_SCOPED:
+            assert where[:2] == ["r101-coco.train", "vgg16-voc07.train"], name
+
+
 def test_metrics_of_the_detectors_scopes_list_the_detector_cells(
         parent_result):
     """A metric read from a scope only the detectors' step names is asked
     of their cells alone; what any cell through the fit loop can report
     lists none and is asked of all.  Every listed cell exists."""
-    cells = {w["name"] for w in BENCH["workloads"]}
-    listed = {m["name"]: m["workloads"] for m in
-              BENCH["end_to_end"] + BENCH["per_layer"] if "workloads" in m}
-    assert DETECTOR_SCOPED <= set(listed)
-    for name, where in listed.items():
-        assert set(where) <= cells and len(set(where)) == len(where), name
-        if name in DETECTOR_SCOPED:
-            assert where == ["r101-coco.train", "vgg16-voc07.train"], name
+    listed_cells_exist_and_the_detectors_come_first(BENCH)
     # a cell that is not listed is not asked: no reader runs for it
     other = dict(bench_run.load_cell("vgg16-voc07.train"), name="other.train")
     got = bench_run.metrics_of(dict(parent_result), other, BENCH, True)
     assert got and not set(got) & DETECTOR_SCOPED
 
 
-def test_every_name_has_its_file_and_every_file_its_name():
-    bdir = os.path.join(ROOT, "benchmark")
-    cells = {w["name"] for w in BENCH["workloads"]}
-    assert cells == {f[:-5] for f in os.listdir(os.path.join(bdir, "workloads"))}
-    configs = {c["name"] for c in BENCH["configs"]}
-    assert configs == {f[:-5] for f in os.listdir(os.path.join(bdir, "configs"))}
+def every_name_has_its_file_and_every_file_its_name(bench, bdir):
+    """``bdir``: the ``benchmark/`` directory the manifest ``bench``
+    belongs to."""
+    def listed(sub, ext):
+        return {f[:-len(ext)] for f in os.listdir(os.path.join(bdir, sub))
+                if f.endswith(ext)}
+
+    cells = {w["name"] for w in bench["workloads"]}
+    assert cells == listed("workloads", ".json")
+    configs = {c["name"] for c in bench["configs"]}
+    assert configs == listed("configs", ".json")
     # a file whose name starts with ``_`` is a helper families share
-    families = {f[:-3] for f in os.listdir(os.path.join(bdir, "families"))
-                if f.endswith(".py") and not f.startswith("_")}
-    assert families == {_config(c)["network"]["family"] for c in configs}
-    for f in families:
-        mod = flops.family({"family": f})
-        assert callable(mod.layers) and mod.STAGES and all(
-            isinstance(s, str) for s in mod.STAGES), f
-    readers = {f[:-3] for f in os.listdir(os.path.join(bdir, "metrics"))
-               if f.endswith(".py")}
-    assert readers == {m["name"] for m in BENCH["per_layer"]}
-    traffic = {f[:-5] for f in os.listdir(os.path.join(bdir, "traffic"))}
-    assert traffic == {w["traffic"] for w in BENCH["workloads"]}
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
-    for m in BENCH["per_layer"]:
+    families = {f for f in listed("families", ".py") if not f.startswith("_")}
+    assert families == {_config(c, bdir)["network"]["family"]
+                        for c in configs}
+    assert listed("metrics", ".py") == {m["name"] for m in bench["per_layer"]}
+    assert listed("traffic", ".json") == {w["traffic"]
+                                          for w in bench["workloads"]}
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
         assert m["moves"] in e2e
         assert set(m.get("workloads", cells)) <= cells
+
+
+# what ``test_bench_family_seam.py`` runs against a manifest with a further
+# family's entries appended: each takes the manifest (the third the
+# directory of its files too, and is called there by name)
+MANIFEST_CHECKS = [names_units_and_sources,
+                   listed_cells_exist_and_the_detectors_come_first]
+
+
+def test_every_name_has_its_file_and_every_file_its_name():
+    every_name_has_its_file_and_every_file_its_name(
+        BENCH, os.path.join(ROOT, "benchmark"))
+    for c in BENCH["configs"]:
+        mod = flops.family(_config(c["name"])["network"])
+        assert callable(mod.layers) and mod.STAGES and all(
+            isinstance(s, str) for s in mod.STAGES), c["name"]
+    configs = {c["name"] for c in BENCH["configs"]}
     for w in BENCH["workloads"]:
         cell = bench_run.load_cell(w["name"])
         assert cell["name"] == w["name"] and cell["chips"] == w["chips"]

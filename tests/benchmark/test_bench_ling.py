@@ -5,7 +5,9 @@ run is ``correct`` under the cell's own limits, each fault planted under
 the timed path or in the reference's place is not, nor is the float8
 control; the family's layer table against a hand count and the compiler's
 count; the configuration's file against the catalog's row and the
-program's preset; the cell's files are new files beside the accepted ones.
+program's preset; the cell's files are new files beside the accepted ones;
+the sequence readers it shares with the third cell list both and read its
+program's scopes.
 """
 
 import json
@@ -19,10 +21,12 @@ import pytest
 from bench_tiny_ling import CELL, tiny_ling_cell
 from benchmark import flops, ling_readings
 from benchmark import run as bench_run
+from benchmark import trace as trace_mod
 from benchmark.drivers import ling_train
 from benchmark.reference import ling_flash as ref
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+LM_CELL = "nemotron3-nano-9l-ep16.train-8k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 SEED = 2147483659
 
@@ -335,8 +339,33 @@ def test_the_cells_limits_each_have_a_reason():
     assert all(len(r) > 40 for r in check["reasons"].values())
 
 
-NEW = ["kda.device_ms", "kda_scan.device_ms", "mla.device_ms", "kda_roofline",
-       "mla_roofline", "kda.chunk_log_decay_min"]
+# PR 38's six readers, as the manifest has them at 42..47, and PR 40's three
+PR38 = ["kda.device_ms", "kda_scan.device_ms", "mla.device_ms",
+        "kda_roofline", "mla_roofline", "kda.chunk_log_decay_min"]
+NEW = PR38 + ["kda_solve.device_ms", "kda_scores.device_ms",
+              "dense_mlp.device_ms"]
+# the sequence readers of the third cell whose scopes and counters this
+# stack names letter for letter: they list both cells since PR 40
+SHARED = ["moe.device_ms", "moe_route.device_ms", "moe_experts.device_ms",
+          "moe_grouped.device_ms", "moe_roofline", "lm_head.device_ms",
+          "optimizer.device_ms", "moe.assignments_per_token",
+          "moe.load_max_over_mean", "moe.overflow"]
+
+
+def _entry(bench, name):
+    return next(m for m in bench["per_layer"] if m["name"] == name)
+
+
+def readers_list_the_cell(bench):
+    """This cell's own readers list it first; the ten it shares with the
+    third cell list that cell, then this one.  A later cell that names the
+    same scope comes after them."""
+    for name in NEW:
+        entry = _entry(bench, name)
+        assert entry["workloads"][:1] == [CELL], name
+        assert entry["moves"] == "train_imgs_per_s", name
+    for name in SHARED:
+        assert _entry(bench, name)["workloads"][:2] == [LM_CELL, CELL], name
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -344,12 +373,70 @@ def test_new_reader_lists_the_cell_and_returns_nothing_without_its_source(
         name):
     """A program without the scope or the counter gives the reader nothing
     to read: it returns nothing and does not raise."""
-    entry = next(m for m in bench_run.manifest()["per_layer"]
-                 if m["name"] == name)
-    assert entry["workloads"] == [CELL] and entry["moves"] == "train_imgs_per_s"
+    entry = _entry(bench_run.manifest(), name)
+    assert entry["workloads"][:1] == [CELL]
     ctx = {"trace": None, "counters": {}, "layers": [], "peak": {},
            "images_per_step": 2, "chips": 1}
     assert bench_run.read_metric(name, ctx) is None
+
+
+def _ops_of_the_stored_step(path):
+    """``trace.load`` for a CPU trace, which has no device line: the step
+    program the profiler stored (the one with most instructions under
+    ``kda_mixer``), every instruction a microsecond, one after another,
+    three executions.  The names and name paths are the program's own; the
+    times are nobody's."""
+    from benchmark import xplane
+
+    step = max(xplane.read_hlo_programs(path), key=lambda names: sum(
+        "kda_mixer" in v for v in names.values()))
+    rows = sorted(step.items())
+    span = 1e3 * len(rows)
+    return {"devices": [{
+        "name": "/device:TPU:0",
+        "ops": [[name, path, k * span + 1e3 * i, 1e3] for k in range(3)
+                for i, (name, path) in enumerate(rows)],
+        "programs": [["jit_step", k * span, span] for k in range(3)]}]}
+
+
+@pytest.fixture(scope="module")
+def traced_metrics(reference_once):
+    """The per-layer metrics of the tiny cell's traced run, under the
+    chip's peaks (a CPU has none in ``peaks.json``)."""
+    cached = jax.config.jax_enable_compilation_cache
+    # compiled here and now: a CPU executable read back from the persistent
+    # cache has lost the name stacks of its instructions
+    jax.config.update("jax_enable_compilation_cache", False)
+    real, trace_mod.load = trace_mod.load, _ops_of_the_stored_step
+    try:
+        cell = tiny_ling_cell()
+        result = ling_train.run_cell(cell, seed=SEED, seconds=0.3, trace=True,
+                                     t_start=time.perf_counter())
+    finally:
+        trace_mod.load = real
+        jax.config.update("jax_enable_compilation_cache", cached)
+    assert result["correct"], result["numbers"]
+    result["device"] = dict(result["device"], kind="TPU v5 lite")
+    got = bench_run.metrics_of(result, cell, bench_run.manifest(), True)
+    return {k: v["value"] for k, v in got.items()}
+
+
+@pytest.mark.parametrize("name", SHARED + NEW)
+def test_reader_reads_the_tiny_programs_scope_or_counter(traced_metrics,
+                                                         name):
+    """Every scope and counter the cell's listed readers read is one the
+    program names: on the traced tiny run each gives a number."""
+    value = traced_metrics[name]
+    if name == "moe.overflow":
+        assert value == 0.0
+    elif name == "kda.chunk_log_decay_min":
+        assert -80.0 < value < 0.0
+    else:
+        assert value > 0.0, name
+
+
+def test_shared_readers_list_both_sequence_cells():
+    readers_list_the_cell(bench_run.manifest())
 
 
 def test_counter_reader_reads_the_log_events_counter():
@@ -357,12 +444,10 @@ def test_counter_reader_reads_the_log_events_counter():
     assert bench_run.read_metric("kda.chunk_log_decay_min", ctx) == -3.25
 
 
-def test_the_new_cell_is_new_files_and_appended_entries():
-    """The seam of ``test_bench_family_seam.py`` for this cell: its files
-    are there under the names ``run.py`` finds them by, the manifest's
-    accepted entries come first and as they were counted, and the cell
-    takes the traffic file the accepted sequence cell has."""
-    bench = bench_run.manifest()
+def accepted_entries_come_first(bench):
+    """The manifest's accepted entries come first, in order: PR 38's
+    configuration and cell fourth, its six readers at 42..47.  How many
+    entries follow is the next PR's to say (PERF.md section 3)."""
     assert [c["name"] for c in bench["configs"]][:3] == [
         "r101-coco", "vgg16-voc07", "nemotron3-nano-9l-ep16"]
     assert bench["configs"][3]["name"] == "ling3-flash-6l-ep64"
@@ -370,10 +455,28 @@ def test_the_new_cell_is_new_files_and_appended_entries():
     assert bench["workloads"][3]["chips"] == 1
     assert bench["workloads"][3]["traffic"] == bench["workloads"][2]["traffic"]
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == NEW and len(names) == 42 + len(NEW)
-    assert not [m for m in bench["per_layer"][:42]
-                if CELL in m.get("workloads", [])]
-    assert bench["run_seconds"] == 10 and len(bench["end_to_end"]) == 2
+    assert names[42:48] == PR38
+    assert bench["run_seconds"] == 10
+    assert [m["name"] for m in bench["end_to_end"]][:2] == [
+        "train_imgs_per_s", "setup_s"]
+    # every metric asked of the cell has a reader's file
+    for m in bench["per_layer"]:
+        if "workloads" not in m or CELL in m["workloads"]:
+            assert os.path.exists(os.path.join(
+                ROOT, "benchmark", "metrics", m["name"] + ".py")), m["name"]
+
+
+# what ``test_bench_family_seam.py`` runs against a manifest with a further
+# family's entries appended: each takes the manifest
+MANIFEST_CHECKS = [readers_list_the_cell, accepted_entries_come_first]
+
+
+def test_the_new_cell_is_new_files_and_appended_entries():
+    """The seam of ``test_bench_family_seam.py`` for this cell: its files
+    are there under the names ``run.py`` finds them by, the manifest's
+    accepted entries come first and in order, and the cell takes the
+    traffic file the accepted sequence cell has."""
+    accepted_entries_come_first(bench_run.manifest())
     for path in ["configs/ling3-flash-6l-ep64.json", f"workloads/{CELL}.json",
                  "families/ling_flash.py", "drivers/ling_train.py",
                  "reference/ling_flash.py", "reference/ling_compare.py",
@@ -383,8 +486,3 @@ def test_the_new_cell_is_new_files_and_appended_entries():
     cell = bench_run.load_cell(CELL)
     assert cell["driver"] == "ling_train"
     assert cell["config"]["network"]["family"] == "ling_flash"
-    # every metric asked of the cell has a reader's file
-    for m in bench["per_layer"]:
-        if "workloads" not in m or CELL in m["workloads"]:
-            assert os.path.exists(os.path.join(
-                ROOT, "benchmark", "metrics", m["name"] + ".py")), m["name"]

@@ -324,10 +324,47 @@ def test_stages_and_the_unscoped_rest_add_up_to_the_step():
     assert bench_run.read_metric("nms.device_ms", ctx) is None
 
 
-def test_every_new_metric_names_its_source_and_layer():
-    layers = {m["layer"] for m in BENCH["per_layer"]
+def test_a_loops_body_counts_with_its_loop():
+    """A ``while`` op lies on the device line around its body's ops.  The
+    copies the compiler makes in a body carry no name path, and a body
+    under ``lax.map`` around a checkpoint is named from ``closed_call``,
+    with no stage in its path: both are the stage's where the ``while``
+    is, and unscoped where the ``while`` is."""
+    from benchmark import hostspans
+
+    ops = [["while.1", "jit(step)/jvp(Net)/l0/kda_mixer/while", 0.0, 100.0],
+           ["fusion.1", "closed_call/checkpoint/kda_scan/kda_solve/dot",
+            10.0, 30.0],
+           ["fusion.2", "closed_call/checkpoint/mul", 50.0, 20.0],
+           ["copy.2", "", 70.0, 20.0],
+           ["copy.1", "", 100.0, 7.0],
+           ["while.2", "jit(step)/while", 110.0, 20.0],
+           ["fusion.3", "closed_call/add", 112.0, 10.0],
+           ["fusion.4", "jit(step)/optimizer/add", 130.0, 5.0]]
+    red = trace.Reduced(
+        {"devices": [{"name": "d", "ops": ops + [
+            [n, p, s + 200.0, d] for n, p, s, d in ops],
+            "programs": [["jit_step(1)", 0.0, 140.0],
+                         ["jit_step(1)", 200.0, 140.0]]}]},
+        steps=1, chips=1)
+    stages = ("kda_mixer", "optimizer")
+    # the copy, and the unstaged loop with its body: not the staged one's
+    assert hostspans.unscoped_s(red, stages) == pytest.approx(27e-9)
+    ctx = {"trace": red, "stages": stages}
+    assert bench_run.read_metric("step.unscoped_ms", ctx) == \
+        pytest.approx(27e-6)
+    staged = sum(red.scope_s(s) for s in stages)
+    assert staged + hostspans.unscoped_s(red, stages) == pytest.approx(
+        red.busy_s()) == pytest.approx(132e-9)
+    # an inner scope is read through the body's own path
+    assert bench_run.read_metric("kda_solve.device_ms", ctx) == \
+        pytest.approx(30e-6)
+
+
+def new_metrics_name_source_and_layer(bench):
+    layers = {m["layer"] for m in bench["per_layer"]
               if m["name"] not in NEW}
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         # (of the detectors' cells: a later family's readers name layers
         # and cells of their own)
         if m["name"] in NEW and "r101-coco.train" in m.get(
@@ -338,4 +375,13 @@ def test_every_new_metric_names_its_source_and_layer():
             assert ("workloads" in m) == m["name"].endswith(".device_ms"), m
             assert m["moves"] == ("setup_s" if m["name"].startswith(
                 ("setup.", "compile.")) else "train_imgs_per_s")
+
+
+# what ``test_bench_family_seam.py`` runs against a manifest with a further
+# family's entries appended: each takes the manifest
+MANIFEST_CHECKS = [new_metrics_name_source_and_layer]
+
+
+def test_every_new_metric_names_its_source_and_layer():
+    new_metrics_name_source_and_layer(BENCH)
     assert len(NEW) >= 18
