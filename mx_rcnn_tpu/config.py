@@ -135,7 +135,8 @@ class NetworkConfig:
     # kernel's param shape, so it is not a checkpoint-compatible default;
     # no cell has measured it (ROADMAP D5).  0 = off.
     stem_channel_pad: int = 0
-    # -- sequence-model families (models/nemotron_h.py, models/ling_flash.py)
+    # -- sequence-model families (models/nemotron_h.py, models/ling_flash.py,
+    # models/joyai_flash.py)
     # "detector" = the Faster R-CNN families above; "nemotron_h" = a hybrid
     # stack of Mamba-2 ('M'), attention ('*') and routed-expert ('E')
     # blocks, one letter a block in ``layer_pattern`` (the published
@@ -143,7 +144,11 @@ class NetworkConfig:
     # "ling_flash" = layers of a mixer, 'K' a gated-delta-rule (KDA) or 'L'
     # a latent-attention (MLA) one, one letter a layer, each followed by a
     # dense SwiGLU (the first ``first_k_dense_replace`` layers kept here)
-    # or a routed-expert MLP.  Widths keep their published names.  The
+    # or a routed-expert MLP; "joyai_flash" = layers of a latent-attention
+    # mixer with a low-rank query alone (``layer_pattern`` all 'L'), the same
+    # two kinds of MLP, and after the last layer ``num_nextn_predict_layers``
+    # multi-token-prediction modules that share the stack's embedding and
+    # head.  Widths keep their published names.  The
     # family also chooses the builder, the loss, the loader and the
     # optimizer: the one table of ``families.py``.
     family: str = "detector"
@@ -178,13 +183,14 @@ class NetworkConfig:
     # rows kept for the held experts, over the assignments expected under
     # even routing (ops/moe.py — row_capacity); beyond it rows overflow
     moe_capacity_factor: float = 2.0
-    # -- ling_flash alone ------------------------------------------------------
+    # -- ling_flash and joyai_flash ----------------------------------------------
     # leading layers kept here whose MLP is dense (SwiGLU ``intermediate_size``)
     first_k_dense_replace: int = 0
     intermediate_size: int = 0
     # group-limited routing: the router's outputs in ``n_group`` groups, a
     # group's score the sum of its two best, ``topk_group`` groups kept
-    # (0 = plain top-k over all experts, as nemotron_h routes)
+    # (0, or one group of all = plain top-k over all experts, as nemotron_h
+    # and joyai_flash route)
     n_group: int = 0
     topk_group: int = 0
     # latent attention: width of the compressed key-value latent, and a
@@ -195,6 +201,17 @@ class NetworkConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_theta: float = 10000.0
+    # -- joyai_flash alone ------------------------------------------------------
+    # width of the low-rank query (q_a, RMSNorm, q_b); the rotary term pairs
+    # adjacent channels (2j, 2j + 1) where ``rope_interleave``, else
+    # (j, j + R/2)
+    q_lora_rank: int = 0
+    rope_interleave: bool = False
+    # multi-token-prediction modules after the last layer (0 or 1), and the
+    # weight of their loss beside the next-token loss
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.0
+    # -- ling_flash alone ------------------------------------------------------
     # the KDA gate's lower bound a position (kda_safe_gate): the log-decay
     # is ``kda_lower_bound * sigmoid(.)``, so a sub-chunk of 16 positions
     # decays by exp(16 * kda_lower_bound) at most, inside float32's range
@@ -979,7 +996,49 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         moe_capacity_factor=8.0,
         compute_dtype="float32",
     ),
+    # JoyAI-LLM-Flash (jdopensource; config.json's keys, DeepSeek-V3's
+    # modelling) at its published widths, whole: 40 layers of latent
+    # attention with a low-rank query, one leading dense layer, 256 experts
+    # routed ungrouped (n_group 1), one multi-token-prediction module.  A
+    # chip's share is this preset with layer_pattern, experts_held and
+    # vocab_size overridden (benchmark/configs/joyai-flash-5l-mtp-ep16.json).
+    "joyai_flash": dict(
+        name="joyai_flash", family="joyai_flash", fixed_params=(),
+        layer_pattern="L" * 40, first_k_dense_replace=1, init_layers=40,
+        hidden_size=2048, vocab_size=129280, norm_eps=1e-6,
+        intermediate_size=7168, num_attention_heads=32, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_theta=32e6, rope_interleave=True,
+        n_routed_experts=256, experts_held=(0, 256), num_experts_per_tok=8,
+        n_group=1, topk_group=1, moe_intermediate_size=768,
+        moe_shared_expert_intermediate_size=768, routed_scaling_factor=2.5,
+        norm_topk_prob=True, num_nextn_predict_layers=1,
+        # DeepSeek-V3's weight for most of its pre-training (0.1 after)
+        mtp_loss_weight=0.3,
+    ),
+    # test-only miniature of the same family: every mechanism, CPU-sized
+    # (MLA+dense, MLA+E, MLA+E and the module; 16 experts, 4 held, top 2)
+    "joyai_flash_tiny": dict(
+        name="joyai_flash_tiny", family="joyai_flash", fixed_params=(),
+        layer_pattern="LLL", first_k_dense_replace=1, init_layers=3,
+        hidden_size=64, vocab_size=256, norm_eps=1e-6, intermediate_size=96,
+        num_attention_heads=4, q_lora_rank=40, kv_lora_rank=24,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_theta=32e6, rope_interleave=True, attn_block_q=32,
+        n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=2,
+        n_group=1, topk_group=1, moe_intermediate_size=32,
+        moe_shared_expert_intermediate_size=32, routed_scaling_factor=2.5,
+        norm_topk_prob=True, num_nextn_predict_layers=1, mtp_loss_weight=0.3,
+        # 4 x the even share is every assignment a token can make to held
+        # experts (top 2 of them): a bound that cannot be exceeded
+        moe_capacity_factor=4.0,
+        compute_dtype="float32",
+    ),
 }
+
+# every preset ``generate_config`` builds a network from (the train CLI's
+# ``--network`` choices)
+NETWORK_NAMES = tuple(_NETWORKS)
 
 # What a network preset fixes outside its own section: the sequence family
 # trains with AdamW (core/optim.py reads momentum as beta1, clip_gradient as
@@ -1003,10 +1062,13 @@ _NETWORK_SECTIONS: Mapping[str, Mapping[str, Mapping[str, Any]]] = {
         "train": dict(seq_len=64, batch_images=2, flip=False),
     },
 }
-# ling_flash trains by the recipe nemotron_h states, for the reasons it states
+# ling_flash and joyai_flash train by the recipe nemotron_h states, for the
+# reasons it states
 _NETWORK_SECTIONS = {**_NETWORK_SECTIONS,
                      "ling_flash": _NETWORK_SECTIONS["nemotron_h"],
-                     "ling_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
+                     "ling_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"],
+                     "joyai_flash": _NETWORK_SECTIONS["nemotron_h"],
+                     "joyai_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
 
 _DATASETS: Mapping[str, Mapping[str, Any]] = {
     "PascalVOC": dict(

@@ -315,15 +315,18 @@ def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
     key: jax.Array,
     cfg: Config,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Next-token loss of a sequence family (``models/nemotron_h.py``,
-    ``models/ling_flash.py``) on
+    """The loss of a sequence family's stack (``models/nemotron_h.py``,
+    ``models/ling_flash.py``: the next-token loss; ``models/joyai_flash.py``:
+    that plus its multi-token-prediction module's, weighted) on
     ``(N, S)`` ids, and the routed-expert counters of the step: mean
     assignments per token that fell on held experts (mean over the expert
     layers), the worst layer's largest expert load over its mean,
     the rows not computed (must be 0), and the rows of every held expert
     (layers x held; a log line shows its mean); beside them whatever
     further scalar counters the family's stack returns, under their own
-    names (``models/ling_flash.py``: ``kda_chunk_log_decay_min``)."""
+    names (``models/ling_flash.py``: ``kda_chunk_log_decay_min``;
+    ``models/joyai_flash.py``: ``loss_main``, ``mtp_loss``).  Nothing here
+    knows a family."""
     loss, aux = model.apply({"params": params}, batch.ids)
     sizes = aux.pop("sizes").astype(jnp.float32)
     overflow = aux.pop("overflow")

@@ -12,6 +12,9 @@ against value width 128; ``ops/attention.py``).  The MLP of the first
 routed-expert layer with a group-limited sigmoid router that computes the
 part of its held experts plus the shared expert (``ops/moe.py``).  Both
 mixers end in a head-wise sigmoid gate before their output projection.
+``MLA``, ``DenseMLP``, ``GatedMoE`` and ``Block`` are also the
+``joyai_flash`` family's (``models/joyai_flash.py``), whose latent attention
+differs in four fields of ``MLA``.
 After the last layer a final RMSNorm and an untied head; the loss is the
 mean next-token cross-entropy over the vocabulary held here.  Activations
 and the residual stream are in ``dtype`` (bfloat16 on the chip), parameters
@@ -28,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -67,18 +70,37 @@ def l2_normalise(x):
                                 + L2_EPS)).astype(x.dtype)
 
 
-def rotary(x, theta: float):
-    """Rotary position term over the last axis of x (B, S, ..., R), the
-    pairs (j, j + R/2) (rotate-half), position ``t`` of axis 1, angles in
-    float32."""
+def _turns(x, theta: float):
+    """cos and sin of the angle pair ``j`` of x (B, S, ..., R) turns by at
+    position ``t`` of axis 1, ``t * theta^(-2j/R)``, in float32 and shaped
+    to broadcast against (B, S, ..., R/2)."""
     s, r = x.shape[1], x.shape[-1]
     inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv
     shape = (1, s) + (1,) * (x.ndim - 3) + (r // 2,)
-    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    return jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+
+
+def rotary(x, theta: float):
+    """Rotary position term over the last axis of x (B, S, ..., R), the
+    pairs (j, j + R/2) (rotate-half: ``ling_flash``), position ``t`` of
+    axis 1, angles in float32."""
+    cos, sin = _turns(x, theta)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, -1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            -1).astype(x.dtype)
+
+
+def rotary_interleaved(x, theta: float):
+    """``rotary`` with the adjacent channels (2j, 2j + 1) as pair ``j``
+    (``rope_interleave``: ``joyai_flash``): the same function of the
+    columns permuted 2j -> j, 2j + 1 -> j + R/2."""
+    cos, sin = _turns(x, theta)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2,
+                                                          2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     -1).reshape(x.shape).astype(x.dtype)
 
 
 class KDAMixer(nn.Module):
@@ -168,6 +190,16 @@ class KDAMixer(nn.Module):
 
 
 class MLA(nn.Module):
+    """Latent attention (DeepSeek-V2's MLA).  The last four fields are what
+    the published configurations differ in beside their widths, at
+    ``ling_flash``'s values: ``q_rank`` the width of a low-rank query
+    (``q_lora_rank``: ``q_a``, an RMSNorm, ``q_b``; None: one full-rank
+    ``q_proj``), ``head_norms`` a learned RMSNorm over each head's query
+    and key before the rotary term, ``head_gate`` a sigmoid scalar a head
+    on the output, ``interleave`` the rotary term's pairing (``rotary`` or
+    ``rotary_interleaved``).
+    ``joyai_flash`` has a low-rank query, adjacent pairs, no head norm and
+    no gate."""
     hidden: int
     heads: int
     kv_rank: int
@@ -179,20 +211,35 @@ class MLA(nn.Module):
     eps: float
     out_std: float
     dtype: Any
+    q_rank: Optional[int] = None
+    head_norms: bool = True
+    head_gate: bool = True
+    interleave: bool = False
 
     @nn.compact
     def __call__(self, x):
         s = x.shape[1]
         h, qk = self.heads, self.nope + self.rope
-        w_q = self.param("q_proj", _normal(INIT_STD), (self.hidden, h * qk))
+        if self.q_rank:
+            w_qa = self.param("q_a_proj", _normal(INIT_STD),
+                              (self.hidden, self.q_rank))
+            qa_norm = self.param("q_a_norm", nn.initializers.ones,
+                                 (self.q_rank,))
+            w_q = self.param("q_b_proj", _normal(INIT_STD),
+                             (self.q_rank, h * qk))
+        else:
+            w_q = self.param("q_proj", _normal(INIT_STD),
+                             (self.hidden, h * qk))
         w_a = self.param("kv_a_proj", _normal(INIT_STD),
                          (self.hidden, self.kv_rank + self.rope))
         a_norm = self.param("kv_a_norm", nn.initializers.ones, (self.kv_rank,))
         w_b = self.param("kv_b_proj", _normal(INIT_STD),
                          (self.kv_rank, h * (self.nope + self.v_dim)))
-        q_norm = self.param("q_norm", nn.initializers.ones, (qk,))
-        k_norm = self.param("k_norm", nn.initializers.ones, (qk,))
-        w_g = self.param("g_proj", _normal(INIT_STD), (self.hidden, h))
+        if self.head_norms:
+            q_norm = self.param("q_norm", nn.initializers.ones, (qk,))
+            k_norm = self.param("k_norm", nn.initializers.ones, (qk,))
+        if self.head_gate:
+            w_g = self.param("g_proj", _normal(INIT_STD), (self.hidden, h))
         w_o = self.param("o_proj", _normal(self.out_std),
                          (h * self.v_dim, self.hidden))
 
@@ -200,7 +247,11 @@ class MLA(nn.Module):
             """One or more sequences (b, S, hidden) -> (b, S, hidden): no
             parameter is made in here."""
             b = x.shape[0]
-            q = jnp.dot(x, w_q.astype(self.dtype)).reshape(b, s, h, qk)
+            q_in = x
+            if self.q_rank:
+                q_in = rms_norm(jnp.dot(x, w_qa.astype(self.dtype)), qa_norm,
+                                self.eps)
+            q = jnp.dot(q_in, w_q.astype(self.dtype)).reshape(b, s, h, qk)
             latent, k_r = jnp.split(jnp.dot(x, w_a.astype(self.dtype)),
                                     [self.kv_rank], -1)
             kv = jnp.dot(rms_norm(latent, a_norm, self.eps),
@@ -211,18 +262,23 @@ class MLA(nn.Module):
             k = jnp.concatenate(
                 [k_n, jnp.broadcast_to(k_r[:, :, None],
                                        (b, s, h, self.rope))], -1)
-            q = rms_norm(q, q_norm, self.eps)
-            k = rms_norm(k, k_norm, self.eps)
+            if self.head_norms:
+                q = rms_norm(q, q_norm, self.eps)
+                k = rms_norm(k, k_norm, self.eps)
+
+            turn = rotary_interleaved if self.interleave else rotary
 
             def turned(t):
                 return jnp.concatenate(
-                    [t[..., :self.nope],
-                     rotary(t[..., self.nope:], self.theta)], -1)
+                    [t[..., :self.nope], turn(t[..., self.nope:],
+                                              self.theta)], -1)
 
             o = causal_gqa(turned(q), turned(k), v, self.block_q)
-            gate = jax.nn.sigmoid(jnp.dot(
-                x, w_g.astype(self.dtype), preferred_element_type=jnp.float32))
-            o = o * gate[..., None].astype(self.dtype)
+            if self.head_gate:
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x, w_g.astype(self.dtype),
+                    preferred_element_type=jnp.float32))
+                o = o * gate[..., None].astype(self.dtype)
             return jnp.dot(o.reshape(b, s, h * self.v_dim),
                            w_o.astype(self.dtype))
 
@@ -278,7 +334,8 @@ class GatedMoE(nn.Module):
             idx, weight = moe_ops.route(
                 flat, w_r, 0.0, n.num_experts_per_tok,
                 n.routed_scaling_factor, n.norm_topk_prob,
-                (n.n_group, n.topk_group) if n.n_group else None)
+                # one group of all is no limit: the plain top-k
+                (n.n_group, n.topk_group) if n.n_group > 1 else None)
             routed = moe_ops.held_assignments(
                 idx, weight, tuple(n.experts_held), moe_ops.row_capacity(
                     b * s, n.num_experts_per_tok, n.n_routed_experts, count,
@@ -319,7 +376,9 @@ class Block(nn.Module):
                 y = MLA(n.hidden_size, n.num_attention_heads, n.kv_lora_rank,
                         n.qk_nope_head_dim, n.qk_rope_head_dim, n.v_head_dim,
                         n.rope_theta, n.attn_block_q, n.norm_eps, out_std,
-                        self.dtype, name="mixer")(normed)
+                        self.dtype, n.q_lora_rank, n.qk_head_norms,
+                        n.head_gate, n.rope_interleave,
+                        name="mixer")(normed)
             elif self.kind == "D":
                 y = DenseMLP(n.hidden_size, n.intermediate_size, out_std,
                              self.dtype, name="mlp")(normed)
@@ -412,6 +471,11 @@ class Dims:
     routed_scaling_factor: float
     norm_topk_prob: bool
     moe_capacity_factor: float
+    # ``MLA``'s last four fields, at this family's values
+    q_lora_rank: Optional[int] = None
+    qk_head_norms: bool = True
+    head_gate: bool = True
+    rope_interleave: bool = False
 
 
 def build_lm(cfg: Config, quant_phase: str = "apply") -> LingFlash:

@@ -22,7 +22,7 @@ import time
 import jax
 
 from mx_rcnn_tpu import families, runtime
-from mx_rcnn_tpu.config import Config, generate_config
+from mx_rcnn_tpu.config import NETWORK_NAMES, Config, generate_config
 from mx_rcnn_tpu.core.fit import fit
 from mx_rcnn_tpu.core.train import setup_training
 from mx_rcnn_tpu.data import (AnchorLoader, cache_from_config,
@@ -167,7 +167,7 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     ``roidb`` may be injected (the alternate driver does); when None it is
     loaded from ``cfg.dataset``.  The family table (``families.py``) says
     what else differs by ``cfg.network.family``: for a sequence family
-    (``nemotron_h``, ``ling_flash``) ``roidb`` is the token source, an
+    (the table's rows of ``row`` 'sequence') ``roidb`` is the token source, an
     ``(n, S)`` array of ids (``data/tokens.py``), the loader is a
     ``TokenLoader``, ``mode`` is ``'lm'`` whatever was passed, and
     everything from the stager and ``fit`` on is the detectors' code.
@@ -524,9 +524,7 @@ def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser(
         description="Train Faster R-CNN end-to-end (ref train_end2end.py)")
     p.add_argument("--network", default="resnet101",
-                   choices=["vgg", "resnet50", "resnet101", "tiny",
-                            "nemotron_h", "nemotron_h_tiny",
-                            "ling_flash", "ling_flash_tiny"])
+                   choices=NETWORK_NAMES)
     p.add_argument("--dataset", default="PascalVOC",
                    choices=["PascalVOC", "coco", "synthetic",
                             "synthetic_hard", "synthetic_stream",
