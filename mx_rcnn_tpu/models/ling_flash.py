@@ -42,7 +42,7 @@ from mx_rcnn_tpu.models.nemotron_h import (INIT_STD, _dt_bias_init, _normal,
                                            _a_log_init,
                                            chunked_cross_entropy, rms_norm)
 from mx_rcnn_tpu.ops import moe as moe_ops
-from mx_rcnn_tpu.ops.attention import causal_gqa
+from mx_rcnn_tpu.ops.attention import KEEP_FLASH_RESIDUALS, causal_gqa
 from mx_rcnn_tpu.ops.kda import kda_chunked
 
 # the stage a block's device time is found under, by its letter
@@ -285,8 +285,12 @@ class MLA(nn.Module):
         # one sequence at a time, each a ``jax.checkpoint``, as the KDA
         # mixer runs and for its reason: the padded operands of the
         # kernels, their cotangents and the float32 of the head norms and
-        # the rotary term are 4 GB for two sequences of 8192
-        y = jax.lax.map(jax.checkpoint(lambda row: attend(row[None])[0]), x)
+        # the rotary term are 4 GB for two sequences of 8192.  The
+        # checkpoint keeps the flash kernel's ``o`` and log-sum-exp (135 MB
+        # a sequence), so that the backward does not run the forward
+        # kernel again; off the kernels it keeps nothing
+        y = jax.lax.map(jax.checkpoint(lambda row: attend(row[None])[0],
+                                       policy=KEEP_FLASH_RESIDUALS), x)
         return y
 
 

@@ -10,7 +10,8 @@ the shared expert (``ops/moe.py``).  After the last block a final RMSNorm
 and an untied head; the loss is the mean next-token cross-entropy over the
 vocabulary held here.  Activations and the residual stream are in
 ``dtype`` (bfloat16 on the chip), parameters float32.  Every block is a
-``jax.checkpoint``: the backward pass recomputes a block from its input.
+``jax.checkpoint``: the backward pass recomputes a block from its input,
+all but the flash kernel's ``o`` and log-sum-exp (``KEEP_FLASH_RESIDUALS``).
 
 Named scopes (one name whatever implements them): ``embed``; ``ssm_mixer``
 with ``ssd_scan`` inside; ``attention``; ``moe`` with ``moe_route`` and
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.ops import moe as moe_ops
-from mx_rcnn_tpu.ops.attention import causal_gqa
+from mx_rcnn_tpu.ops.attention import KEEP_FLASH_RESIDUALS, causal_gqa
 from mx_rcnn_tpu.ops.ssd import ssd_scan
 
 INIT_STD = 0.02
@@ -274,7 +275,12 @@ class NemotronH(nn.Module):
             x = table[ids].astype(self.dtype)
         sizes, overflow = [], []
         for i, kind in enumerate(n.layer_pattern):
-            x, sz, ov = nn.remat(Block)(kind, n, self.dtype, name=f"b{i}")(x)
+            # each block recomputed in the backward but for the flash
+            # kernel's ``o`` and log-sum-exp, which an attention block on
+            # the kernels keeps (0.14 GB): the backward kernel reads them
+            # and the forward kernel runs once; other blocks name nothing
+            x, sz, ov = nn.remat(Block, policy=KEEP_FLASH_RESIDUALS)(
+                kind, n, self.dtype, name=f"b{i}")(x)
             if kind == "E":
                 sizes.append(sz)
                 overflow.append(ov)

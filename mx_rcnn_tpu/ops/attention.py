@@ -17,6 +17,10 @@ trace time:
   the whole (S, S) matrix per head never exists.  Each block is a
   ``jax.checkpoint``: the backward pass recomputes a block's scores
   instead of keeping every block's.
+
+``KEEP_FLASH_RESIDUALS`` is the kernels' checkpoint policy: a
+``jax.checkpoint`` around a call under it keeps the forward kernel's ``o``
+and log-sum-exp for the backward and recomputes everything else.
 """
 
 from __future__ import annotations
@@ -25,6 +29,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from mx_rcnn_tpu.ops.attention_pallas import KEEP_FLASH_RESIDUALS  # noqa: F401
 
 
 @jax.custom_vjp

@@ -20,7 +20,13 @@ heads inside, all against the one fetched K/V tile; only the tiles that
 cross the diagonal pay for the mask.
 
 Two kernels behind one ``custom_vjp`` whose residuals are ``q, k, v, o``
-and the log-sum-exp of each row:
+and the log-sum-exp of each row.  The last two are the forward kernel's
+own results, named ``FLASH_OUT`` and ``FLASH_LSE``: a ``jax.checkpoint``
+around a call under ``KEEP_FLASH_RESIDUALS`` saves that pair and
+recomputes the rest, so that its backward reads ``o`` and ``lse`` and does
+not run the forward kernel a second time (``q, k, v``, padded, are what a
+checkpoint exists not to hold; a policy finds no name where ``causal_gqa``
+took the blocked path and saves nothing there):
 
 * forward, keys in the lanes: ``s = q . k^T (block_q, block_k)``, the
   statistics ``(block_q, 128)`` with every lane the same, ``acc += p . v``;
@@ -48,6 +54,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -66,6 +73,12 @@ _KV_BYTES = 32 * 2 ** 20
 _VMEM_LIMIT = 96 * 2 ** 20
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))      # (m, d) x (n, d) -> (m, n)
+# the forward kernel's two results, which the backward kernel reads as
+# residuals, and the checkpoint policy that keeps exactly those
+FLASH_OUT = "flash_causal_gqa.o"
+FLASH_LSE = "flash_causal_gqa.lse"
+KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    FLASH_OUT, FLASH_LSE)
 
 
 def tiles(s: int, d: int, blocks: Optional[Tuple[int, int]] = None) -> bool:
@@ -325,6 +338,7 @@ def _forward(q, k, v, blocks, interpret):
                          f"head size {d} do not tile ({bq}, {bk}, 128)")
     o, lse = _forward_call(_flat(q), _flat(k), _flat(v), hkv=hkv, bq=bq,
                            bk=bk, interpret=interpret)
+    o, lse = checkpoint_name(o, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return o.reshape(q.shape), (q, k, v, o, lse)
 
 

@@ -1,9 +1,10 @@
 """The attention kernels (``ops/attention_pallas.py``) against the full
-masked form and its autodiff, and ``causal_gqa``'s choice between them and
-the blocked path.  On CPU the kernels run in the interpreter, and only
-where a test says ``interpret=True``; that Mosaic compiles them at the
-cell's shape and agrees on the chip is the benchmark's ``correct`` to
-check.
+masked form and its autodiff, ``causal_gqa``'s choice between them and
+the blocked path, and the checkpoints that keep the forward kernel's
+``o`` and log-sum-exp (``KEEP_FLASH_RESIDUALS``).  On CPU the kernels run
+in the interpreter, and only where a test says ``interpret=True``; that
+Mosaic compiles them at the cell's shape and agrees on the chip is the
+benchmark's ``correct`` to check.
 """
 
 import re
@@ -194,3 +195,114 @@ def test_kernels_lower_for_tpu_at_the_cells_shape():
             dims = [int(n) for n in shape.split("x")]
             assert dims.count(8192) < 2, shape
             assert not (8192 in dims and 256 in dims), shape
+
+
+def _mapped_loss(policy, interpret=False):
+    """A loss over ``causal_gqa`` as the latent mixer runs it: one sequence
+    at a time under ``lax.map``, each a ``jax.checkpoint``."""
+    def one(row):
+        q, k, v = (a[None] for a in row)
+        return causal_gqa(q, k, v, interpret=interpret)[0]
+
+    def loss(q, k, v):
+        out = jax.lax.map(jax.checkpoint(one, policy=policy), (q, k, v))
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    return loss
+
+
+@pytest.mark.parametrize("policy,calls", [
+    pytest.param(attention_pallas.KEEP_FLASH_RESIDUALS, 2,
+                 id="keep_flash_residuals"),
+    pytest.param(None, 3, id="default_policy"),
+])
+def test_a_checkpoint_under_the_policy_runs_the_forward_kernel_once(
+        monkeypatch, policy, calls):
+    """At the latent mixer's shape — two sequences of 8192 one at a time,
+    32 heads, 192-wide queries and keys, 128-wide values, bfloat16 — the
+    gradient lowers for the TPU to the forward kernel and the backward
+    kernel where the checkpoint keeps ``o`` and the log-sum-exp, and to a
+    second forward kernel in the backward where it keeps nothing."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = (jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16))
+    fn = jax.value_and_grad(_mapped_loss(policy), argnums=(0, 1, 2))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == calls
+
+
+def test_the_kept_residuals_give_the_recomputed_gradients_to_the_bit(
+        monkeypatch):
+    """In the interpreter, at blocks of 128 over 256 positions: the saved
+    ``o`` and log-sum-exp are what the second forward call recomputes."""
+    monkeypatch.setattr(attention_pallas, "BLOCK_Q", 128)
+    monkeypatch.setattr(attention_pallas, "BLOCK_K", 128)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q, k = (jax.random.normal(key, (2, 256, 2, 192), jnp.bfloat16)
+            for key in ks[:2])
+    v = jax.random.normal(ks[2], (2, 256, 2, 128), jnp.bfloat16)
+    grads = []
+    for policy, calls in ((attention_pallas.KEEP_FLASH_RESIDUALS, 2),
+                          (None, 3)):
+        fn = jax.value_and_grad(_mapped_loss(policy, interpret=True),
+                                argnums=(0, 1, 2))
+        assert str(jax.make_jaxpr(fn)(q, k, v)).count("pallas_call[") == calls
+        grads.append(jax.tree_util.tree_leaves(jax.jit(fn)(q, k, v)))
+    for kept, again in zip(*grads):
+        np.testing.assert_array_equal(np.asarray(kept, np.float32),
+                                      np.asarray(again, np.float32))
+
+
+def _remats(jaxpr):
+    """Every checkpoint equation of a jaxpr, inside others too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "remat2":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _remats(sub)
+
+
+# the checkpoints that wrap an attention call in each family's tiny stack:
+# nemotron's every block (its attention block among them), the latent
+# mixers of ling's pattern, and joyai's with its module's
+WRAPPED = {
+    "nemotron_h": lambda n: len(n.layer_pattern),
+    "ling_flash": lambda n: n.layer_pattern.count("L"),
+    "joyai_flash": lambda n: n.layer_pattern.count("L") + 1,
+}
+
+
+@pytest.mark.parametrize("family", sorted(WRAPPED))
+def test_the_checkpoints_around_attention_keep_the_flash_residuals(
+        monkeypatch, family):
+    """The family's grad names ``KEEP_FLASH_RESIDUALS`` on exactly the
+    checkpoints around its attention calls; on the CPU, where attention
+    takes the blocked path and no residual is named, the gradient is the
+    one without the policy, to the bit."""
+    from mx_rcnn_tpu.config import generate_config
+    from mx_rcnn_tpu.models import build_model, ling_flash, nemotron_h
+
+    cfg = generate_config(f"{family}_tiny", "synthetic_tokens")
+    model = build_model(cfg)
+    params, _ = model.init_variables(jax.random.PRNGKey(0))
+    ids = np.random.RandomState(1).randint(0, 200, (2, 64)).astype(np.int32)
+
+    def named_and_grads():
+        """How often the grad names the policy, and the grad (a new
+        function each time: ``jit`` would reuse its trace)."""
+        grad = jax.grad(lambda p: model.apply({"params": p}, ids)[0])
+        policies = [e.params["policy"]
+                    for e in _remats(jax.make_jaxpr(grad)(params).jaxpr)]
+        return (policies.count(attention_pallas.KEEP_FLASH_RESIDUALS),
+                jax.tree_util.tree_leaves(jax.jit(grad)(params)))
+
+    named, grads = named_and_grads()
+    assert named == WRAPPED[family](cfg.network)
+    for module in (ling_flash, nemotron_h):
+        monkeypatch.setattr(module, "KEEP_FLASH_RESIDUALS", None)
+    named, again = named_and_grads()
+    assert named == 0
+    for a, b in zip(grads, again):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
