@@ -186,8 +186,10 @@ def fit(
     ``step_callback``, ``stop_flag``), and at a log step ``train.sync``
     and ``train.log`` — each with ``step=``, the global step it belongs
     to; the first step's ``train.dispatch`` holds the step's trace,
-    lowering and compile or cache read (``setup.first_step_s``).  With
-    span collection off each is one module-flag read.
+    lowering and compile or cache read (``setup.first_step_s``); before
+    the loop ``setup.fit`` covers the prologue (the jit wrap, the state's
+    copy to the device, the snapshotter, the stager's start).  With span
+    collection off each is one module-flag read.
     ``device_cache``: stage the loader's epoch in HBM once and gather each
     step's batch on device (``data/device_cache.py``) — for RAM/HBM-scale
     datasets on hosts or links too slow to stream per step.  Shuffling is
@@ -233,6 +235,8 @@ def fit(
     host batches) and multiproc (global-array assembly is collective and
     stays on the step thread).
     """
+    # ``setup.fit``: from here to the loop's first ``train.data_wait``
+    t_fit = time.perf_counter() if obs_trace.enabled() else None
     frequent = cfg.default.frequent if frequent is None else frequent
     # -- observability wiring (cfg.obs.enabled; docs/OBSERVABILITY.md) --
     # rec stays None when disabled, and every obs touch below hides
@@ -465,6 +469,10 @@ def fit(
             if run_record is not None:
                 run_record.event("epoch_start", epoch=epoch, skip=skip,
                                  steps_per_epoch=steps_per_epoch)
+            if t_fit is not None:
+                obs_trace.complete("setup.fit",
+                                   (time.perf_counter() - t_fit) * 1e3)
+                t_fit = None
             while True:
                 # the global step this iteration produces: every span of
                 # the iteration carries it

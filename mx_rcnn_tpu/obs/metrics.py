@@ -303,10 +303,16 @@ class ServeMetrics:
             return out
 
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 # its seconds accumulate in the registry's ``compile.backend_s``; a
 # persistent-cache read is inside it
 _BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+# fired inside the backend compile that read its program from the cache
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# JAX's three compile phases as spans of the buffer
+_PHASE_SPAN = {_TRACE_EVENT: "compile.trace", _LOWER_EVENT: "compile.lower",
+               _BACKEND_EVENT: "compile.backend"}
 
 
 class LoweringCounter:
@@ -321,6 +327,12 @@ class LoweringCounter:
     a no-op unless spans are collected) carrying its seconds and the train
     step current at the time (:meth:`mark_step`; None outside the fit
     loop), so "which step recompiled" has an answer with a time on it.
+    Each of JAX's three compile phases becomes a span on the compiling
+    thread, ``compile.trace``, ``compile.lower`` and ``compile.backend``,
+    with ``fun=`` (JAX's ``fun_name``) and ``step=``; ``compile.backend``
+    also ``hit=``, 1 where the persistent cache answered inside it on that
+    thread, and each backend compile counts in the registry as
+    ``compile.cache_hits`` or ``compile.cache_misses``.
 
     Import-light: registering the listener touches jax only on first use.
     """
@@ -328,11 +340,14 @@ class LoweringCounter:
     _events = {"lowerings": 0}
     _registered = False
     _step: Optional[int] = None
+    _hit = threading.local()   # .at: JAX's clock at the thread's last hit
 
     @classmethod
     def _ensure_listener(cls) -> None:
         if cls._registered:
             return
+        import time
+
         import jax
 
         from mx_rcnn_tpu.obs import trace as obs_trace
@@ -345,7 +360,26 @@ class LoweringCounter:
             elif event == _BACKEND_EVENT:
                 _GLOBAL.inc("compile.backend_s", float(duration))
 
+        def on_cache_hit(event, **kw):
+            if event == _CACHE_HIT_EVENT:
+                cls._hit.at = time.time()   # the clock JAX stamps spans by
+
+        def on_span(event, start, end, **kw):
+            name = _PHASE_SPAN.get(event)
+            if name is None:
+                return
+            args = {"fun": kw.get("fun_name"), "step": cls._step}
+            if event == _BACKEND_EVENT:
+                hit = int(start <= getattr(cls._hit, "at", -1.0) <= end)
+                _GLOBAL.inc("compile.cache_hits" if hit
+                            else "compile.cache_misses")
+                args["hit"] = hit
+            # on the buffer's clock: it ends now and lasted what JAX says
+            obs_trace.complete(name, (end - start) * 1e3, **args)
+
         jax.monitoring.register_event_duration_secs_listener(on_event)
+        jax.monitoring.register_event_listener(on_cache_hit)
+        jax.monitoring.register_event_time_span_listener(on_span)
         cls._registered = True
 
     @classmethod

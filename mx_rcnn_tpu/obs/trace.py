@@ -116,6 +116,23 @@ def _now_us() -> float:
     return (_EPOCH_NS + time.monotonic_ns()) / 1e3
 
 
+def process_age_s() -> Optional[float]:
+    """Seconds since the kernel started this process: its start time in
+    ``/proc/self/stat`` (clock ticks since boot) against
+    ``CLOCK_BOOTTIME``, good to a tick (10 ms); None where the system keeps
+    no such file."""
+    try:
+        with open("/proc/self/stat") as f:
+            stat = f.read()
+        # field 22, counted from the pid; the name in parentheses before
+        # it may hold spaces, so count from the last ')', field 3's start
+        ticks = int(stat.rsplit(")", 1)[1].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
 def epoch_us() -> int:
     """Integer epoch-µs stamp — the cross-host span/skew clock (the
     wire skew extension ships these, so both ends must agree on units

@@ -202,10 +202,15 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
     injector chains in front of a caller ``step_callback``.
     With ``cfg.obs.enabled`` the start is laid out in spans
     (``obs/trace.py``, collected for any caller): ``setup.loader`` (roidb
-    to loader), ``setup.init`` (the init program and the optimizer's
-    slots), ``setup.load`` (``init_from`` / the pretrained graft),
-    ``setup.resume``, then the ``train.*`` spans of ``core.fit.fit``.
+    to loader), ``setup.model`` (``build_model``), ``setup.init`` (the
+    init program and the optimizer's slots), ``setup.load`` (``init_from``
+    / the pretrained graft), ``setup.resume``, then the ``train.*`` spans
+    of ``core.fit.fit``; the ``setup.entry`` instant before them all
+    carries ``process_s``, the process's age at this entry (what ran before
+    it: interpreter, imports, the backend's start, the caller's own work).
     """
+    if obs_trace.enabled():
+        obs_trace.instant("setup.entry", process_s=obs_trace.process_age_s())
     if cfg.quant.enabled:
         # quantization is inference-only (docs/PERF.md "Quantized
         # inference"): the quantized model needs the calibrated 'quant'
@@ -294,7 +299,10 @@ def train_net(cfg: Config, *, prefix: str, begin_epoch: int = 0,
                 n_total * grad_accum, num_devices, cfg.train.batch_images,
                 grad_accum)
 
-    model = build_model(cfg)
+    # the family's model modules are imported on first use: 1-2 s of a
+    # sequence family's start
+    with obs_trace.span("setup.model"):
+        model = build_model(cfg)
     bh, bw = cfg.bucket.shapes[0]
     key = jax.random.PRNGKey(seed)
     with obs_trace.span("setup.init"):

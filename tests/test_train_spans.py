@@ -120,11 +120,54 @@ def test_train_net_leaves_spans_under_obs_enabled_alone(tmp_path):
         assert placed[e["args"]["step"]] <= e["ts"] + 1.0
 
     setup = _by_name(events, "setup.")
-    assert set(setup) == {"setup.loader", "setup.init"}
+    assert set(setup) == {"setup.entry", "setup.loader", "setup.model",
+                          "setup.init", "setup.fit"}
     d1 = train["train.dispatch"][0]
-    order = [setup["setup.loader"][0]["ts"], setup["setup.init"][0]["ts"],
-             d1["ts"]]
+    order = [setup[name][0]["ts"] for name in (
+        "setup.loader", "setup.model", "setup.init", "setup.fit")]
+    order.append(d1["ts"])
     assert order == sorted(order)
+    # the entry comes first, with the process's age; the prologue ends
+    # before the loop's first wait for a batch
+    entry = setup["setup.entry"][0]
+    assert min(events, key=lambda e: e["ts"]) is entry
+    assert entry["ph"] == "i" and entry["tid"] in fit_tid
+    assert entry["args"]["process_s"] > 0
+    (prologue,) = setup["setup.fit"]
+    assert prologue["tid"] in fit_tid
+    assert (prologue["ts"] + prologue["dur"]
+            <= train["train.data_wait"][0]["ts"] + 1.0)
+
+    # the step's trace, lowering and compile or cache read lie inside the
+    # first dispatch, each phase with the step and the function's name
+    phases = {name: [e for e in events if e["name"] == name
+                     and e["tid"] in fit_tid and e["args"]["step"] == 1]
+              for name in ("compile.trace", "compile.lower",
+                           "compile.backend")}
+    for name, spans in phases.items():
+        assert spans, name
+        for e in spans:
+            assert d1["ts"] - 1.0 <= e["ts"], (name, e)
+            assert e["ts"] + e["dur"] <= d1["ts"] + d1["dur"] + 1.0, (name, e)
+    assert {e["args"]["fun"] for e in phases["compile.backend"]} == {
+        "jit(step)"}
+    assert all(e["args"]["hit"] in (0, 1) for e in phases["compile.backend"])
+    assert "step" in {e["args"]["fun"] for e in phases["compile.trace"]}
+
+    # the caller's thread is tiled from the entry to the second log edge:
+    # no stretch over 5 ms lies under no setup.*, train.* or compile.* span
+    edge = train["train.log"][1]
+    mine = sorted((e["ts"], e["ts"] + e["dur"]) for e in events
+                  if e["ph"] == "X" and e["tid"] in fit_tid
+                  and e["name"].startswith(("setup.", "train.", "compile."))
+                  and e["ts"] < edge["ts"] + edge["dur"])
+    covered, holes = entry["ts"], []
+    for s, t in mine:
+        if s > covered:
+            holes.append((s - covered, s))
+        covered = max(covered, t)
+    assert covered >= edge["ts"] + edge["dur"] - 1.0
+    assert max(holes, default=(0.0,))[0] < 5e3, sorted(holes)[-3:]
 
     # the step program lowers inside the first dispatch and says so
     low = [e for e in events if e["name"] == "compile.lowering"]
@@ -135,6 +178,102 @@ def test_train_net_leaves_spans_under_obs_enabled_alone(tmp_path):
     assert all(e["args"]["lower_s"] > 0 for e in low)
     assert registry().counter("compile.backend_s") >= train["train.log"][0][
         "args"]["backend_compile_s"] > 0
+
+
+def test_a_second_start_reads_the_step_from_the_persistent_cache(tmp_path):
+    """Two ``train_net`` starts in one process against an empty cache: the
+    first compiles the step (``hit`` 0), the second reads it (``hit`` 1),
+    and the registry counts each backend compile as a miss or a hit."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from mx_rcnn_tpu.obs.metrics import registry
+    from mx_rcnn_tpu.tools.train import train_net
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    cfg = shrink_tiny_cfg(generate_config(
+        "tiny", "synthetic", dataset__root_path=str(tmp_path),
+        dataset__dataset_path=str(tmp_path / "synthetic"),
+        dataset__num_classes=4, train__batch_images=2, obs__enabled=True))
+    reg = registry()
+    hits, counts = [], []
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xla"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+        for _ in range(2):
+            obs_trace.reset()
+            before = (reg.counter("compile.cache_hits"),
+                      reg.counter("compile.cache_misses"))
+            train_net(cfg, prefix=None, end_epoch=1, lr=1e-3, frequent=2,
+                      seed=0, dataset_kw=dict(num_images=4,
+                                              image_size=(128, 160),
+                                              max_objects=3))
+            hits.append([e["args"]["hit"] for e in obs_trace.events()
+                         if e["name"] == "compile.backend"
+                         and e["args"]["fun"] == "jit(step)"])
+            counts.append((reg.counter("compile.cache_hits") - before[0],
+                           reg.counter("compile.cache_misses") - before[1]))
+    finally:
+        obs_trace.reset()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert hits == [[0], [1]]
+    (_, misses_1), (hits_2, misses_2) = counts
+    # what the first start compiled, the second read
+    assert misses_1 >= 1 and hits_2 >= 1 and misses_2 == 0
+
+
+def test_the_listener_places_compile_phases_on_the_buffers_clock():
+    """JAX's phase events, fired by hand: each becomes a span that ends
+    when its callback runs and lasts what JAX measured, on JAX's wall
+    clock or not; ``hit`` is 1 only for a cache hit inside the span."""
+    import time
+
+    from mx_rcnn_tpu.obs.metrics import LoweringCounter, registry
+
+    LoweringCounter._ensure_listener()
+    backend = "/jax/core/compile/backend_compile_duration"
+    reg = registry()
+    before = (reg.counter("compile.cache_hits"),
+              reg.counter("compile.cache_misses"))
+    obs_trace.enable()
+    obs_trace.reset()
+    try:
+        LoweringCounter.mark_step(7)
+        # a wall clock stepped a day back: the span still lies at now
+        t = time.time() - 86400.0
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/jaxpr_trace_duration", t, t + 0.25,
+            fun_name="f")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        now = time.time()
+        jax.monitoring.record_event_time_span(backend, now - 0.5, now + 0.1,
+                                              fun_name="jit(f)")
+        # the hit lies before this span: a compile
+        jax.monitoring.record_event_time_span(backend, now + 0.2, now + 0.3,
+                                              fun_name="jit(g)")
+        t_read = obs_trace._now_us()
+        events = obs_trace.events()
+    finally:
+        LoweringCounter.mark_step(None)
+        obs_trace.disable()
+        obs_trace.reset()
+    trace, hit, miss = events
+    assert trace["name"] == "compile.trace"
+    assert trace["args"] == {"fun": "f", "step": 7}
+    assert trace["dur"] == pytest.approx(0.25e6)
+    assert 0 <= t_read - (trace["ts"] + trace["dur"]) < 1e6
+    assert (hit["name"], hit["args"]) == (
+        "compile.backend", {"fun": "jit(f)", "step": 7, "hit": 1})
+    assert miss["args"]["hit"] == 0
+    assert (reg.counter("compile.cache_hits") - before[0],
+            reg.counter("compile.cache_misses") - before[1]) == (1, 1)
 
 
 def test_stager_spans_count_from_the_first_batch_it_is_given():
