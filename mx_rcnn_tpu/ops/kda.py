@@ -40,12 +40,16 @@ and bfloat16 alike (against the sub-chunk's start the factors would reach
 sub-chunk are then a matrix product.
 
 Everything that holds a decay is float32; the matrix products take their
-operands in ``q.dtype`` (bfloat16 on the chip) and accumulate in float32;
-the triangular inverse is float32 throughout: on a TPU forward substitution
-on the vector unit with the chunk-heads in the lanes (the Mosaic kernel of
-``ops/kda_pallas.py``, whose backward is ``-T^T dT T^T``, two float32
-products at the highest precision), elsewhere products at the highest
-precision with autodiff's backward (``inv_unit_lower``).
+operands in ``q.dtype`` (bfloat16 on the chip) and accumulate in float32.
+On a TPU the two score matrices are one Mosaic kernel with a hand-written
+backward (``kda_pallas.scores``), the factors above made in VMEM and only
+``A_qk`` and ``A_kk`` written; elsewhere the products below, with
+autodiff's backward.  The triangular inverse is float32 throughout: on a
+TPU forward substitution on the vector unit with the chunk-heads in the
+lanes (the Mosaic kernel of ``ops/kda_pallas.py``, whose backward is ``-T^T
+dT T^T``, two float32 products at the highest precision), elsewhere
+products at the highest precision with autodiff's backward
+(``inv_unit_lower``).
 """
 
 from __future__ import annotations
@@ -53,17 +57,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from mx_rcnn_tpu.ops import kda_pallas
+
 SUB = 16
 _HI = jax.lax.Precision.HIGHEST
 
 
-def _kernel_takes(n: int, dtype, interpret: bool) -> bool:
-    """A TPU (or the interpreter) and matrices the kernel eliminates:
-    platform and shape are read at trace time."""
-    from mx_rcnn_tpu.ops import kda_pallas
-
-    return ((interpret or jax.default_backend() == "tpu")
-            and kda_pallas.takes(n, dtype))
+def _kernel_takes(fits: bool, interpret: bool) -> bool:
+    """A TPU (or the interpreter) and a shape the kernel ``fits``: platform
+    and shape are read at trace time."""
+    return fits and (interpret or jax.default_backend() == "tpu")
 
 
 def inv_unit_lower(m, interpret: bool = False):
@@ -73,10 +76,8 @@ def inv_unit_lower(m, interpret: bool = False):
     instead: what a test passes, on any platform), for an ``n`` that is a
     multiple of 8, row-by-row substitution with the matrices in the lanes
     (``kda_pallas.inv_unit_lower``); else ``inv_unit_lower_jnp``."""
-    if _kernel_takes(m.shape[-1], m.dtype, interpret):
-        from mx_rcnn_tpu.ops.kda_pallas import inv_unit_lower as by_kernel
-
-        return by_kernel(m, interpret)
+    if _kernel_takes(kda_pallas.takes(m.shape[-1], m.dtype), interpret):
+        return kda_pallas.inv_unit_lower(m, interpret)
     return inv_unit_lower_jnp(m)
 
 
@@ -107,6 +108,41 @@ def inv_unit_lower_jnp(m):
     return jnp.concatenate([top, jnp.concatenate([c, d], -1)], -2)
 
 
+def _scores_jnp(q6, k6, gs, before, sub):
+    """(A_qk, A_kk) (B, NC, H, L, L) float32 as matrix products of
+    ``jnp``: q6, k6 (B, NC, H, n, c, K) cut in sub-chunks, gs their running
+    log-decay inside each, before the sum ahead of each (B, NC, H, n, K)."""
+    b, nc, h, n, _, dk = q6.shape
+    chunk, f32, dtype = n * sub, jnp.float32, q6.dtype
+    # a sub-chunk's rows against its middle, M = G - R there; every key
+    # of the chunk against the middle of each sub-chunk at or after its
+    # own: exp(M_i - (G_j - R_i)) inside sub-chunk i, exp((R_i + M_i) -
+    # G_j) <= 1 before it, 0 after it
+    mid = gs[..., sub // 2:sub // 2 + 1, :]
+    q_s = (q6 * jnp.exp(gs - mid)).astype(dtype)
+    k_s = (k6 * jnp.exp(gs - mid)).astype(dtype)
+    at, of = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    lead = ((before + mid[..., 0, :])[..., :, None, :]
+            - before[..., None, :, :])                   # (..., i, j, K)
+    lead = jnp.where((at == of)[..., None], mid, lead)
+    lead = jnp.where((at >= of)[..., None], lead, -jnp.inf)
+    keys = (k6[..., None, :, :, :] * jnp.exp(
+        lead[..., None, :] - gs[..., None, :, :, :])).astype(dtype)
+    keys = keys.reshape(b, nc, h, n, chunk, dk)          # (..., i, L, K)
+
+    def scores(rows):
+        """(B, NC, H, L, L): sum_d rows_i k_j exp(G_i - G_j) for every
+        (i, j) with j's sub-chunk not after i's; the caller masks."""
+        return jnp.einsum("...icd,...ild->...icl", rows, keys,
+                          preferred_element_type=f32).reshape(
+                              b, nc, h, chunk, chunk)
+
+    pos = jnp.arange(chunk)
+    a_qk = jnp.where(pos[:, None] >= pos[None, :], scores(q_s), 0.0)
+    a_kk = jnp.where(pos[:, None] > pos[None, :], scores(k_s), 0.0)
+    return a_qk, a_kk
+
+
 def _within_chunks(q, k, v, g, beta, chunk: int, sub: int, interpret: bool):
     """Everything of the rule that needs no other chunk, for a batch of
     sequences: (A_qk (B, NC, H, L, L), W (B, NC, H, L, K), U_0 float32
@@ -132,32 +168,12 @@ def _within_chunks(q, k, v, g, beta, chunk: int, sub: int, interpret: bool):
     g_end = (before + total)[..., -1:, :]                    # G_L (B,NC,H,1,K)
 
     with jax.named_scope("kda_scores"):
-        # a sub-chunk's rows against its middle, M = G - R there; every key
-        # of the chunk against the middle of each sub-chunk at or after its
-        # own: exp(M_i - (G_j - R_i)) inside sub-chunk i, exp((R_i + M_i) -
-        # G_j) <= 1 before it, 0 after it
-        mid = gs[..., sub // 2:sub // 2 + 1, :]
-        q_s = (q6 * jnp.exp(gs - mid)).astype(dtype)
-        k_s = (k6 * jnp.exp(gs - mid)).astype(dtype)
-        at, of = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
-        lead = ((before + mid[..., 0, :])[..., :, None, :]
-                - before[..., None, :, :])                   # (..., i, j, K)
-        lead = jnp.where((at == of)[..., None], mid, lead)
-        lead = jnp.where((at >= of)[..., None], lead, -jnp.inf)
-        keys = (k6[..., None, :, :, :] * jnp.exp(
-            lead[..., None, :] - gs[..., None, :, :, :])).astype(dtype)
-        keys = keys.reshape(b, nc, h, n, chunk, dk)          # (..., i, L, K)
-
-        def scores(rows):
-            """(B, NC, H, L, L): sum_d rows_i k_j exp(G_i - G_j) for every
-            (i, j) with j's sub-chunk not after i's; the caller masks."""
-            return jnp.einsum("...icd,...ild->...icl", rows, keys,
-                              preferred_element_type=f32).reshape(
-                                  b, nc, h, chunk, chunk)
-
-        pos = jnp.arange(chunk)
-        a_qk = jnp.where(pos[:, None] >= pos[None, :], scores(q_s), 0.0)
-        a_kk = jnp.where(pos[:, None] > pos[None, :], scores(k_s), 0.0)
+        if _kernel_takes(kda_pallas.scores_take(chunk, sub, dk, dtype),
+                         interpret):
+            a_qk, a_kk = kda_pallas.scores(flat(q6), flat(k6), g_in, sub,
+                                           interpret)
+        else:
+            a_qk, a_kk = _scores_jnp(q6, k6, gs, before, sub)
 
     with jax.named_scope("kda_solve"):
         t_low = inv_unit_lower(jnp.eye(chunk, dtype=f32) + beta_l * a_kk,
@@ -181,7 +197,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = SUB,
     most negative within-chunk cumulative log-decay, a float32 scalar
     without gradient).  ``S`` must be a multiple of ``chunk`` and ``chunk``
     of ``sub`` (a chunk shorter than ``sub`` is one sub-chunk).  ``interpret``
-    is ``inv_unit_lower``'s.
+    runs the kernels in the Pallas interpreter, as ``inv_unit_lower``'s.
 
     Its float32 intermediates are a dozen arrays of the size of ``g``: a
     caller short of memory hands over one sequence at a time

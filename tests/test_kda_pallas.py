@@ -110,15 +110,25 @@ def test_off_the_tpu_the_rule_takes_the_jnp_form_without_being_told():
     assert "pallas_call" in told
 
 
+def _kernels(text):
+    """The Mosaic calls of a lowered program, by kernel name."""
+    names = [re.findall(r'kernel_name = "([^"]*)"', call)
+             for call in re.findall(r"stablehlo.custom_call @tpu_custom_call.*",
+                                    text)]
+    assert all(len(n) == 1 for n in names)
+    return sorted(n[0] for n in names)
+
+
 def test_the_rule_lowers_for_tpu_at_a_parts_shape(monkeypatch):
     """One part of the cell's train step — one sequence of 8192 positions,
     16 of the 32 heads, 128 wide, bfloat16: 2048 matrices of 64 x 64 — lowers
-    for the TPU platform with the inverse as one Mosaic custom call, and
-    with the cotangents as one still (the backward is two products, not a
-    kernel, and the forward is not run again).  The rule reads the platform
-    at trace time, which here is the CPU: the test answers for it.  Lowering
-    runs on CPU; what libtpu makes of the call is the chip's to say."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for the TPU platform with the scores as one Mosaic call and the inverse
+    as another, and with the cotangents adds the scores' backward kernel
+    alone (the inverse's backward is two products, not a kernel, and no
+    forward is run again).  The rule reads the platform at trace time, which
+    here is the CPU: untold, it lowers with no Mosaic call at all; told, the
+    test answers for the TPU.  Lowering runs on CPU; what libtpu makes of
+    the calls is the chip's to say."""
     s, h, d = 8192, 16, 128
 
     def loss(q, k, v, g, beta):
@@ -128,11 +138,24 @@ def test_the_rule_lowers_for_tpu_at_a_parts_shape(monkeypatch):
     half = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16)
     args = (half, half, half, jax.ShapeDtypeStruct((1, s, h, d), jnp.float32),
             jax.ShapeDtypeStruct((1, s, h), jnp.float32))
-    for fn in (loss, jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))):
-        text = jax.jit(fn).trace(*args).lower(
+    fns = (loss, jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
+
+    def lowered(fn):
+        # a new function each time: a trace cached before the platform
+        # changed would be found again
+        return jax.jit(lambda *a: fn(*a)).trace(*args).lower(
             lowering_platforms=("tpu",)).as_text()
-        assert text.count("tpu_custom_call") == 1
+
+    for fn in fns:
+        assert "tpu_custom_call" not in lowered(fn)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    forward = ["kda_inv_unit_lower", "kda_scores_fwd"]
+    for fn, want in zip(fns, (forward, forward + ["kda_scores_bwd"])):
+        text = lowered(fn)
+        assert _kernels(text) == sorted(want)
         assert f"tensor<64x64x{s // 64 * h}xf32>" in text
+        assert (f"-> (tensor<{s // 64 * h}x64x64xbf16>, "
+                f"tensor<{s // 64 * h}x64x64xf32>)") in text
 
 
 def test_backward_names_its_ops_for_the_solves_scope():
