@@ -136,7 +136,7 @@ class NetworkConfig:
     # no cell has measured it (ROADMAP D5).  0 = off.
     stem_channel_pad: int = 0
     # -- sequence-model families (models/nemotron_h.py, models/ling_flash.py,
-    # models/joyai_flash.py)
+    # models/joyai_flash.py, models/keye_vl2.py)
     # "detector" = the Faster R-CNN families above; "nemotron_h" = a hybrid
     # stack of Mamba-2 ('M'), attention ('*') and routed-expert ('E')
     # blocks, one letter a block in ``layer_pattern`` (the published
@@ -148,7 +148,10 @@ class NetworkConfig:
     # mixer with a low-rank query alone (``layer_pattern`` all 'L'), the same
     # two kinds of MLP, and after the last layer ``num_nextn_predict_layers``
     # multi-token-prediction modules that share the stack's embedding and
-    # head.  Widths keep their published names.  The
+    # head; "keye_vl2" = layers of grouped-query attention over the keys a
+    # learned indexer chooses ('S' each), every one followed by a
+    # softmax-routed expert MLP with no shared expert.  Widths keep their
+    # published names.  The
     # family also chooses the builder, the loss, the loader and the
     # optimizer: the one table of ``families.py``.
     family: str = "detector"
@@ -211,6 +214,19 @@ class NetworkConfig:
     # weight of their loss beside the next-token loss
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.0
+    # -- keye_vl2 alone ----------------------------------------------------------
+    # the indexer of DeepSeek Sparse Attention (ops/sparse_attention.py): its
+    # query heads and their width (one key head of that width), the channels
+    # of each that take the rotary term, and the keys a query attends to
+    indexer_num_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_rope_dim: int = 0
+    indexer_topk: int = 0
+    # the router's scores over all experts: "sigmoid" or "softmax"
+    router_scoring: str = "sigmoid"
+    # tokens a part of the expert layer, run one after the other, each with
+    # its own row capacity (0: the whole sequence at once)
+    moe_token_chunk: int = 0
     # -- ling_flash alone ------------------------------------------------------
     # the KDA gate's lower bound a position (kda_safe_gate): the log-decay
     # is ``kda_lower_bound * sigmoid(.)``, so a sub-chunk of 16 positions
@@ -1034,6 +1050,40 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         moe_capacity_factor=4.0,
         compute_dtype="float32",
     ),
+    # Keye-VL-2.0-30B-A3B's language stack (Kwai-Keye; config.json's keys
+    # with sa_config's) at its published widths, whole: 48 layers of
+    # grouped-query attention (32 over 4 heads of 128) over the 2048 keys a
+    # query an indexer of 16 heads of 64 chooses, each followed by 128
+    # experts routed by softmax, top 8, no shared expert.  A chip's share is
+    # this preset with layer_pattern, experts_held and vocab_size
+    # overridden (benchmark/configs/keye-vl2-a3b-6l-ep8.json).
+    "keye_vl2": dict(
+        name="keye_vl2", family="keye_vl2", fixed_params=(),
+        layer_pattern="S" * 48, init_layers=48, hidden_size=2048,
+        vocab_size=151936, norm_eps=1e-6, num_attention_heads=32,
+        num_key_value_heads=4, head_dim=128, rope_theta=1e7,
+        indexer_num_heads=16, indexer_head_dim=64, indexer_rope_dim=32,
+        indexer_topk=2048, n_routed_experts=128, experts_held=(0, 128),
+        num_experts_per_tok=8, moe_intermediate_size=768,
+        norm_topk_prob=True, router_scoring="softmax",
+    ),
+    # test-only miniature of the same family: every mechanism, CPU-sized
+    # (two layers; 4 over 2 heads of 16, an indexer of 4 heads of 16
+    # choosing 16 keys; 16 experts, 4 held, top 2)
+    "keye_vl2_tiny": dict(
+        name="keye_vl2_tiny", family="keye_vl2", fixed_params=(),
+        layer_pattern="SS", init_layers=2, hidden_size=64, vocab_size=256,
+        norm_eps=1e-6, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, rope_theta=1e7, attn_block_q=16, indexer_num_heads=4,
+        indexer_head_dim=16, indexer_rope_dim=8, indexer_topk=16,
+        n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=2,
+        moe_intermediate_size=32, norm_topk_prob=True,
+        router_scoring="softmax", moe_token_chunk=32,
+        # 4 x the even share is every assignment a token can make to held
+        # experts (top 2 of them): a bound that cannot be exceeded
+        moe_capacity_factor=4.0,
+        compute_dtype="float32",
+    ),
 }
 
 # every preset ``generate_config`` builds a network from (the train CLI's
@@ -1062,13 +1112,15 @@ _NETWORK_SECTIONS: Mapping[str, Mapping[str, Mapping[str, Any]]] = {
         "train": dict(seq_len=64, batch_images=2, flip=False),
     },
 }
-# ling_flash and joyai_flash train by the recipe nemotron_h states, for the
-# reasons it states
+# ling_flash, joyai_flash and keye_vl2 train by the recipe nemotron_h
+# states, for the reasons it states
 _NETWORK_SECTIONS = {**_NETWORK_SECTIONS,
                      "ling_flash": _NETWORK_SECTIONS["nemotron_h"],
                      "ling_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"],
                      "joyai_flash": _NETWORK_SECTIONS["nemotron_h"],
-                     "joyai_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
+                     "joyai_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"],
+                     "keye_vl2": _NETWORK_SECTIONS["nemotron_h"],
+                     "keye_vl2_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
 
 _DATASETS: Mapping[str, Mapping[str, Any]] = {
     "PascalVOC": dict(

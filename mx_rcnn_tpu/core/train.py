@@ -317,7 +317,8 @@ def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The loss of a sequence family's stack (``models/nemotron_h.py``,
     ``models/ling_flash.py``: the next-token loss; ``models/joyai_flash.py``:
-    that plus its multi-token-prediction module's, weighted) on
+    that plus its multi-token-prediction module's, weighted;
+    ``models/keye_vl2.py``: that plus every layer's indexer loss) on
     ``(N, S)`` ids, and the routed-expert counters of the step: mean
     assignments per token that fell on held experts (mean over the expert
     layers), the worst layer's largest expert load over its mean,
@@ -325,8 +326,9 @@ def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
     (layers x held; a log line shows its mean); beside them whatever
     further scalar counters the family's stack returns, under their own
     names (``models/ling_flash.py``: ``kda_chunk_log_decay_min``;
-    ``models/joyai_flash.py``: ``loss_main``, ``mtp_loss``).  Nothing here
-    knows a family."""
+    ``models/joyai_flash.py``: ``loss_main``, ``mtp_loss``;
+    ``models/keye_vl2.py``: ``loss_main``, ``index_loss``,
+    ``tiles_touched_pct``, ``dsa_chosen``).  Nothing here knows a family."""
     loss, aux = model.apply({"params": params}, batch.ids)
     sizes = aux.pop("sizes").astype(jnp.float32)
     overflow = aux.pop("overflow")

@@ -10,12 +10,14 @@ module imports nothing of the package and any module may import it.
 
 A new family is one entry here, its model under ``models/``, and (for a
 sequence family) a preset in ``config.py``; no other file tests the
-family's name.  The sequence families are three: ``nemotron_h`` (Mamba-2,
+family's name.  The sequence families are four: ``nemotron_h`` (Mamba-2,
 attention and routed-expert blocks), ``ling_flash`` (a gated delta rule or
-latent attention, then a dense or routed-expert MLP) and ``joyai_flash``
+latent attention, then a dense or routed-expert MLP), ``joyai_flash``
 (latent attention with a low-rank query in every layer, the same MLPs, and
-a multi-token-prediction module on the stack's own embedding and head); all
-three take the ``lm`` step, AdamW and the token loader.
+a multi-token-prediction module on the stack's own embedding and head) and
+``keye_vl2`` (grouped-query attention over the keys a learned indexer
+chooses, then a softmax-routed expert MLP); all four take the ``lm`` step,
+AdamW and the token loader.
 """
 
 from __future__ import annotations
@@ -67,6 +69,7 @@ FAMILIES: Dict[str, Family] = {
     "nemotron_h": _sequence("mx_rcnn_tpu.models.nemotron_h:build_lm"),
     "ling_flash": _sequence("mx_rcnn_tpu.models.ling_flash:build_lm"),
     "joyai_flash": _sequence("mx_rcnn_tpu.models.joyai_flash:build_lm"),
+    "keye_vl2": _sequence("mx_rcnn_tpu.models.keye_vl2:build_lm"),
 }
 
 

@@ -322,24 +322,30 @@ class GatedMoE(nn.Module):
         n = self.net
         b, s, h = x.shape
         count, f = n.experts_held[1], n.moe_intermediate_size
+        # no shared expert where its width is 0 (``keye_vl2``)
         fs = n.moe_shared_expert_intermediate_size
         w_r = self.param("router", _normal(INIT_STD), (h, n.n_routed_experts))
         w_gate = self.param("experts_gate", _normal(INIT_STD), (count, h, f))
         w_up = self.param("experts_up", _normal(INIT_STD), (count, h, f))
         w_down = self.param("experts_down", _normal(self.out_std),
                             (count, f, h))
-        s_gate = self.param("shared_gate", _normal(INIT_STD), (h, fs))
-        s_up = self.param("shared_up", _normal(INIT_STD), (h, fs))
-        s_down = self.param("shared_down", _normal(self.out_std), (fs, h))
+        if fs:
+            s_gate = self.param("shared_gate", _normal(INIT_STD), (h, fs))
+            s_up = self.param("shared_up", _normal(INIT_STD), (h, fs))
+            s_down = self.param("shared_down", _normal(self.out_std),
+                                (fs, h))
         flat = x.reshape(b * s, h)
         with jax.named_scope("moe_route"):
             # the expert bias is held at zero (no balancing update is part
-            # of the configuration)
+            # of the configuration); the scoring is named where it is not
+            # the default sigmoid, so that a call is what it was otherwise
             idx, weight = moe_ops.route(
                 flat, w_r, 0.0, n.num_experts_per_tok,
                 n.routed_scaling_factor, n.norm_topk_prob,
                 # one group of all is no limit: the plain top-k
-                (n.n_group, n.topk_group) if n.n_group > 1 else None)
+                (n.n_group, n.topk_group) if n.n_group > 1 else None,
+                **({} if n.scoring_func == "sigmoid"
+                   else {"scoring": n.scoring_func}))
             routed = moe_ops.held_assignments(
                 idx, weight, tuple(n.experts_held), moe_ops.row_capacity(
                     b * s, n.num_experts_per_tok, n.n_routed_experts, count,
@@ -347,7 +353,8 @@ class GatedMoE(nn.Module):
         with jax.named_scope("moe_experts"):
             y = moe_ops.held_experts(flat, routed, w_up, w_down,
                                      w_gate=w_gate)
-            y = y + moe_ops.swiglu_ffn(flat, s_gate, s_up, s_down)
+            if fs:
+                y = y + moe_ops.swiglu_ffn(flat, s_gate, s_up, s_down)
         return (y.astype(self.dtype).reshape(b, s, h), routed.sizes,
                 routed.overflow)
 
@@ -475,6 +482,8 @@ class Dims:
     routed_scaling_factor: float
     norm_topk_prob: bool
     moe_capacity_factor: float
+    # the router's scores (``ops/moe.py::route``)
+    scoring_func: str = "sigmoid"
     # ``MLA``'s last four fields, at this family's values
     q_lora_rank: Optional[int] = None
     qk_head_norms: bool = True

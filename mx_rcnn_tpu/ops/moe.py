@@ -74,16 +74,23 @@ def limit_to_groups(biased, n_group: int, topk_group: int):
 
 
 def route(x, w_router, bias, top_k: int, scale: float, norm_topk: bool,
-          groups: Optional[Tuple[int, int]] = None):
+          groups: Optional[Tuple[int, int]] = None,
+          scoring: str = "sigmoid"):
     """x (T, H) -> (idx (T, k) int32, weight (T, k) float32).  Logits and
-    scores in float32; the choice is by ``scores + bias`` — among all
+    scores in float32, the scores a sigmoid of each logit or (``scoring``
+    'softmax') a softmax over all experts; the choice is by ``scores +
+    bias`` — among all
     experts, or with ``groups = (n_group, topk_group)`` among the experts of
     the groups :func:`limit_to_groups` keeps — the weights are the unbiased
     scores of the chosen (divided by their sum with ``norm_topk``) times
     ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"router scoring is sigmoid or softmax, got "
+                         f"{scoring!r}")
+    scores = (jax.nn.softmax(logits, -1) if scoring == "softmax"
+              else jax.nn.sigmoid(logits))
     biased = scores + bias
     if groups is not None:
         biased = limit_to_groups(biased, *groups)
