@@ -8,8 +8,9 @@ comparison in the others' place: the program's configuration
 (``lm_train.program_config``), the token source (``benchmark/lm_traffic.py``),
 seed-made weights handed over in memory, the probe of the first two steps
 (``lm_train.Probe``, which here keeps the next-token loss ``loss_main`` in
-the losses' place, step 1's indexer loss and the chosen keys of the sample
-rows the step hands out, and brings back whole the gradients of the vectors
+the losses' place, step 1's indexer loss, the chosen keys of the sample
+rows the step hands out and each step's ``dsa_count_misses``, and brings
+back whole the gradients of the vectors
 ``reference.SCAN_LEAVES`` names), the call of ``train_net``, the plain
 reference (``benchmark/reference/keye_vl2.py``) one sequence at a time
 after the state is freed, and the comparison (``reference/keye_compare.py``).
@@ -45,11 +46,14 @@ COUNTERS = {"moe_assignments_per_token": None, "moe_load_max_over_mean": max,
 class Probe(lm_train.Probe):
     """``lm_train.Probe`` with this family's seed-made weights and small
     vectors, which keeps the next-token loss in the losses' place, step
-    1's indexer loss and the chosen keys of the sample rows."""
+    1's indexer loss, the chosen keys of the sample rows and each step's
+    count of queries whose attention took another number of keys than the
+    choice holds."""
 
     def __init__(self, config: Dict, seed: int, steps: int):
         super().__init__(config, seed, steps)
         self.main_losses, self.index_losses, self.chosen = [], [], None
+        self.count_misses = []
 
     def _norms(self, params, mu):
         import jax
@@ -75,6 +79,8 @@ class Probe(lm_train.Probe):
 
             m = _fit_locals()["metrics"]
             self.main_losses.append(float(jax.device_get(m["loss_main"])))
+            self.count_misses.append(int(jax.device_get(
+                m["dsa_count_misses"])))
             if step == 1:
                 self.index_losses.append(float(jax.device_get(
                     m["index_loss"])))
@@ -84,7 +90,8 @@ class Probe(lm_train.Probe):
 
     def result(self) -> Dict:
         return dict(super().result(), losses=self.main_losses,
-                    index_losses=self.index_losses, chosen=self.chosen)
+                    index_losses=self.index_losses, chosen=self.chosen,
+                    count_misses=self.count_misses)
 
 
 def run(cell: Dict, *, seed: int, seconds: float, trace: bool,

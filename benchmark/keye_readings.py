@@ -60,7 +60,8 @@ def planted_rows(cell, seed, tags):
         out = reference.reference_steps(
             config, config["optimizer"],
             reference.make_weights(config, s32), batches, **kw)
-        return dict(out, overflow=0.0)
+        # the reference attends to its own choice: no count to miss
+        return dict(out, overflow=0.0, count_misses=[0])
 
     plain, row = follow(), {"seed": seed}
     for tag in tags:

@@ -2,7 +2,7 @@
 ``reference/lm_compare.py``'s numbers (``loss_s1`` and ``loss_s2`` of the
 next-token loss ``L_main``, ``grad_worst``, ``first_delta_worst``,
 ``routing_diff``, ``moe_overflow``), every one held against a limit of its
-own (the cell's ``limits``; PERF.md section 2 has the readings), and four of
+own (the cell's ``limits``; PERF.md section 2 has the readings), and five of
 this family:
 
 * ``index_loss_s1``: relative gap of step 1's indexer loss, summed over the
@@ -16,8 +16,13 @@ this family:
   ``index_weights_proj`` (as one vector) and its key's LayerNorm
   (``keye_vl2.INDEX_LEAVES``), which only the indexer's loss reaches;
 * ``selection_diff``: of step 1's chosen (query, key) pairs in the rows the
-  step hands out (four a layer, the first sequence), the share the
-  reference did not choose.
+  step hands out (four a layer, the first sequence) and the reference's,
+  the pairs only one of them chose over the pairs either chose;
+* ``count_misses``: the program's own, over steps 1 and 2 (the worse): the
+  queries whose attention took another number of keys than the choice
+  holds, ``min(t + 1, topk)``, summed over layers.  The attention's mask is
+  formed from scores computed again; a count that is not the choice's says
+  that attention masked in another set than the one chosen.
 """
 
 from __future__ import annotations
@@ -33,19 +38,19 @@ VECTORS = {"head_norm_grad_worst": HEAD_LEAVES,
 
 
 def selection_diff(program, reference) -> float:
-    """Chosen by the program and not by the reference, over all the
-    program chose, in the sample rows."""
+    """Chosen by one of the two alone, over chosen by either, in the sample
+    rows."""
     got = np.asarray(program, bool)
     want = np.asarray(reference, bool)
     if got.shape != want.shape:
         return float("inf")
-    return float((got & ~want).sum()) / max(int(got.sum()), 1)
+    return float((got ^ want).sum()) / max(int((got | want).sum()), 1)
 
 
 def compare_keye(program, reference, limits):
     """As ``lm_compare.compare_lm``, with ``index_losses`` and ``chosen``
     beside ``losses``; returns (correct, numbers, notes)."""
-    own = set(VECTORS) | {"index_loss_s1", "selection_diff"}
+    own = set(VECTORS) | {"index_loss_s1", "selection_diff", "count_misses"}
     no_vectors = lambda r: dict(r, scan_grad={})  # noqa: E731
     ok, out, notes = lm_compare.compare_lm(
         no_vectors(program), no_vectors(reference),
@@ -57,7 +62,8 @@ def compare_keye(program, reference, limits):
     values = {"index_loss_s1": rel_gap(program["index_losses"][0],
                                        reference["index_losses"][0]),
               "selection_diff": selection_diff(program["chosen"],
-                                               reference["chosen"])}
+                                               reference["chosen"]),
+              "count_misses": float(max(program["count_misses"]))}
     for name, leaves in VECTORS.items():
         gaps = {}
         for k, w in reference["scan_grad"].items():

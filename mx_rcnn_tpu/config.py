@@ -227,6 +227,11 @@ class NetworkConfig:
     # tokens a part of the expert layer, run one after the other, each with
     # its own row capacity (0: the whole sequence at once)
     moe_token_chunk: int = 0
+    # each held expert's sorted rows start on a multiple of this many rows,
+    # in at least one such block (0: packed); at the grouped products' row
+    # tile every tile is one expert's, so that their work is the same on
+    # every step whatever the routing (ops/moe.py — held_assignments)
+    moe_group_rows: int = 0
     # -- ling_flash alone ------------------------------------------------------
     # the KDA gate's lower bound a position (kda_safe_gate): the log-decay
     # is ``kda_lower_bound * sigmoid(.)``, so a sub-chunk of 16 positions
@@ -1066,6 +1071,8 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         indexer_topk=2048, n_routed_experts=128, experts_held=(0, 128),
         num_experts_per_tok=8, moe_intermediate_size=768,
         norm_topk_prob=True, router_scoring="softmax",
+        # ops/gmm_pallas.py's row tile
+        moe_group_rows=256,
     ),
     # test-only miniature of the same family: every mechanism, CPU-sized
     # (two layers; 4 over 2 heads of 16, an indexer of 4 heads of 16
@@ -1078,7 +1085,7 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         indexer_head_dim=16, indexer_rope_dim=8, indexer_topk=16,
         n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=2,
         moe_intermediate_size=32, norm_topk_prob=True,
-        router_scoring="softmax", moe_token_chunk=32,
+        router_scoring="softmax", moe_token_chunk=32, moe_group_rows=8,
         # 4 x the even share is every assignment a token can make to held
         # experts (top 2 of them): a bound that cannot be exceeded
         moe_capacity_factor=4.0,

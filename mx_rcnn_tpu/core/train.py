@@ -328,7 +328,8 @@ def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
     names (``models/ling_flash.py``: ``kda_chunk_log_decay_min``;
     ``models/joyai_flash.py``: ``loss_main``, ``mtp_loss``;
     ``models/keye_vl2.py``: ``loss_main``, ``index_loss``,
-    ``tiles_touched_pct``, ``dsa_chosen``).  Nothing here knows a family."""
+    ``tiles_touched_pct``, ``dsa_chosen``, ``dsa_count_misses``).  Nothing
+    here knows a family."""
     loss, aux = model.apply({"params": params}, batch.ids)
     sizes = aux.pop("sizes").astype(jnp.float32)
     overflow = aux.pop("overflow")

@@ -179,7 +179,6 @@ class Dims:
     mtp_loss_weight: float
     qk_head_norms: bool = False
     head_gate: bool = False
-    scoring_func: str = "sigmoid"
 
 
 def build_lm(cfg: Config, quant_phase: str = "apply") -> JoyAIFlash:

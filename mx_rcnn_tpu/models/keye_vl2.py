@@ -78,7 +78,8 @@ class DSA(nn.Module):
     """Attention over the keys the indexer chooses; ``__call__(x)`` (B, S,
     hidden) -> (y, the layer's indexer loss (mean over positions), the
     share of causal tiles touched, the first sequence's chosen keys of
-    ``sample_rows``)."""
+    ``sample_rows``, the queries whose attention took another number of
+    keys than the choice holds)."""
     net: Any        # Dims
     out_std: float
     dtype: Any
@@ -122,18 +123,20 @@ class DSA(nn.Module):
                          n.norm_eps)
             q, k = rotary(q, n.rope_theta)[0], rotary(k, n.rope_theta)[0]
             v = proj(x, w_v).reshape(s, hkv, d)
-            o, kl, touched, chosen = sparse_attention(
+            o, kl, touched, chosen, misses = sparse_attention(
                 q, k, v, qi, wi, ki, n.indexer_topk, n.attn_block_q, rows)
-            return proj(o.reshape(s, hq * d), w_o), kl, touched, chosen
+            return (proj(o.reshape(s, hq * d), w_o), kl, touched, chosen,
+                    misses)
 
-        y, kl, touched, chosen = jax.lax.map(
+        y, kl, touched, chosen, misses = jax.lax.map(
             jax.checkpoint(attend, policy=KEEP_CHOICE), x)
-        return y, jnp.sum(kl) / (b * s), jnp.mean(touched), chosen[0]
+        return (y, jnp.sum(kl) / (b * s), jnp.mean(touched), chosen[0],
+                jnp.sum(misses))
 
 
 class AttentionBlock(nn.Module):
-    """x + DSA(RMSNorm(x)) under scope ``dsa``; returns (x, the indexer's
-    loss, the tiles touched, the sample's chosen keys)."""
+    """x + DSA(RMSNorm(x)) under scope ``dsa``; returns (x, and ``DSA``'s
+    counters)."""
     net: Any        # Dims
     dtype: Any
 
@@ -143,10 +146,9 @@ class AttentionBlock(nn.Module):
         scale = self.param("norm", nn.initializers.ones, (n.hidden_size,))
         out_std = INIT_STD / math.sqrt(2 * n.init_layers)
         with jax.named_scope("dsa"):
-            y, kl, touched, chosen = DSA(n, out_std, self.dtype,
-                                         name="mixer")(
+            y, *counters = DSA(n, out_std, self.dtype, name="mixer")(
                 rms_norm(x, scale, n.norm_eps))
-            return x + y, kl, touched, chosen
+            return (x + y, *counters)
 
 
 class KeyeVL2(nn.Module):
@@ -165,16 +167,18 @@ class KeyeVL2(nn.Module):
                            (n.vocab_size, n.hidden_size))
         with jax.named_scope("embed"):
             x = table[ids].astype(self.dtype)
-        sizes, overflow, kls, touched, chosen = [], [], [], [], []
+        sizes, overflow, kls, touched, chosen, misses = [], [], [], [], [], []
         for i in range(n.num_layers):
             # an attention block checkpoints itself, a sequence at a time
-            x, kl, tt, ch = AttentionBlock(n, self.dtype, name=f"l{i}_mix")(x)
+            x, kl, tt, ch, ms = AttentionBlock(n, self.dtype,
+                                               name=f"l{i}_mix")(x)
             x, sz, ov = self.expert_block(x, i)
             sizes.append(sz)
             overflow.append(ov)
             kls.append(kl)
             touched.append(tt)
             chosen.append(ch)
+            misses.append(ms)
         final = self.param("final_norm", nn.initializers.ones,
                            (n.hidden_size,))
         w_head = self.param("head", _normal(INIT_STD),
@@ -192,7 +196,8 @@ class KeyeVL2(nn.Module):
             "sizes": jnp.stack(sizes), "overflow": jnp.stack(overflow),
             "loss_main": loss_main, "index_loss": index_loss,
             "tiles_touched_pct": 100.0 * jnp.mean(jnp.stack(touched)),
-            "dsa_chosen": jnp.stack(chosen)}
+            "dsa_chosen": jnp.stack(chosen),
+            "dsa_count_misses": sum(misses)}
 
     def expert_block(self, x, i: int):
         """Layer ``i``'s ``ling_flash.Block("E")`` (an ``nn.remat``) on the
@@ -259,7 +264,9 @@ class Dims:
     norm_topk_prob: bool
     # tokens a part of the expert layer (0: the whole sequence)
     moe_token_chunk: int = 0
+    # what ``ling_flash.GatedMoE`` reads beside (``ling_flash.MOE_DEFAULTS``)
     scoring_func: str = "softmax"
+    moe_group_rows: int = 0
     # what ``GatedMoE`` reads beside, at this family's values: no shared
     # expert, no scaling, no group limit
     moe_shared_expert_intermediate_size: int = 0
@@ -299,7 +306,7 @@ def build_lm(cfg: Config, quant_phase: str = "apply") -> KeyeVL2:
         moe_intermediate_size=n.moe_intermediate_size,
         moe_capacity_factor=n.moe_capacity_factor,
         norm_topk_prob=n.norm_topk_prob, scoring_func=n.router_scoring,
-        moe_token_chunk=n.moe_token_chunk)
+        moe_token_chunk=n.moe_token_chunk, moe_group_rows=n.moe_group_rows)
     return KeyeVL2(
         net=dims,
         dtype=(jnp.bfloat16 if n.compute_dtype == "bfloat16"

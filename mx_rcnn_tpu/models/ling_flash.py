@@ -310,6 +310,17 @@ class DenseMLP(nn.Module):
                                   w_down).astype(self.dtype).reshape(b, s, h)
 
 
+# what ``GatedMoE`` reads of a family's ``Dims`` beside the fields every such
+# ``Dims`` has, with the value of a family that has no such field: the
+# router's scores (``ops/moe.py::route``) and the held rows' alignment
+# (``ops/moe.py::held_assignments``; ``keye_vl2`` sets both)
+MOE_DEFAULTS = {"scoring_func": "sigmoid", "moe_group_rows": 0}
+
+
+def moe_option(net, name: str):
+    return getattr(net, name, MOE_DEFAULTS[name])
+
+
 class GatedMoE(nn.Module):
     net: Any        # Dims
     out_std: float
@@ -337,19 +348,21 @@ class GatedMoE(nn.Module):
         flat = x.reshape(b * s, h)
         with jax.named_scope("moe_route"):
             # the expert bias is held at zero (no balancing update is part
-            # of the configuration); the scoring is named where it is not
-            # the default sigmoid, so that a call is what it was otherwise
+            # of the configuration); the scoring is named only where it is
+            # not the default sigmoid, so that a sigmoid router's call keeps
+            # the signature that stand-ins for ``route`` of that signature
+            # (the benchmark's planted faults) take
+            scoring = moe_option(n, "scoring_func")
             idx, weight = moe_ops.route(
                 flat, w_r, 0.0, n.num_experts_per_tok,
                 n.routed_scaling_factor, n.norm_topk_prob,
                 # one group of all is no limit: the plain top-k
                 (n.n_group, n.topk_group) if n.n_group > 1 else None,
-                **({} if n.scoring_func == "sigmoid"
-                   else {"scoring": n.scoring_func}))
+                **({} if scoring == "sigmoid" else {"scoring": scoring}))
             routed = moe_ops.held_assignments(
                 idx, weight, tuple(n.experts_held), moe_ops.row_capacity(
                     b * s, n.num_experts_per_tok, n.n_routed_experts, count,
-                    n.moe_capacity_factor))
+                    n.moe_capacity_factor), moe_option(n, "moe_group_rows"))
         with jax.named_scope("moe_experts"):
             y = moe_ops.held_experts(flat, routed, w_up, w_down,
                                      w_gate=w_gate)
@@ -482,8 +495,6 @@ class Dims:
     routed_scaling_factor: float
     norm_topk_prob: bool
     moe_capacity_factor: float
-    # the router's scores (``ops/moe.py::route``)
-    scoring_func: str = "sigmoid"
     # ``MLA``'s last four fields, at this family's values
     q_lora_rank: Optional[int] = None
     qk_head_norms: bool = True

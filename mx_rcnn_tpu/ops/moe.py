@@ -20,7 +20,9 @@ stated bound on the assignments to *all* held experts together (how they
 split among the held experts is free: the grouped product takes the
 sizes).  ``overflow`` counts the assignments beyond it; a run in which it
 is not zero has dropped work and is not a sound run.  Where ``capacity``
-is ``tokens * min(top_k, count)`` it cannot be exceeded.
+is ``tokens * min(top_k, count)`` it cannot be exceeded, unless the rows
+are aligned (``held_assignments``' ``align``: each held expert's rows in
+whole tiles of their own, the rows between them padding).
 """
 
 from __future__ import annotations
@@ -102,8 +104,10 @@ def route(x, w_router, bias, top_k: int, scale: float, norm_topk: bool,
 
 
 def held_assignments(idx, weight, held: Tuple[int, int],
-                     capacity: int) -> Routed:
-    """Sort the (T, k) assignments by held expert; keep ``capacity``."""
+                     capacity: int, align: int = 0) -> Routed:
+    """Sort the (T, k) assignments by held expert; keep ``capacity``.
+    ``align`` > 0 lays each held expert's rows out from a multiple of
+    ``align`` rows, in at least one such block (``_aligned``)."""
     first, count = held
     t, k = idx.shape
     local = idx - first
@@ -111,6 +115,8 @@ def held_assignments(idx, weight, held: Tuple[int, int],
     order = jnp.argsort(key, stable=True)[:capacity]
     valid = key[order] < count
     sizes = (key[:, None] == jnp.arange(count)).sum(0).astype(jnp.int32)
+    if align:
+        return _aligned(order, weight.reshape(-1), sizes, k, capacity, align)
     ends = jnp.minimum(jnp.cumsum(sizes), capacity)
     # the rows past the last held assignment go to the last group: they
     # enter as zeros and leave masked, and the grouped products then run
@@ -124,6 +130,40 @@ def held_assignments(idx, weight, held: Tuple[int, int],
         weight=jnp.where(valid, weight.reshape(-1)[order], 0.0),
         valid=valid, group_sizes=group_sizes, sizes=sizes,
         overflow=jnp.maximum(sizes.sum() - capacity, 0).astype(jnp.int32))
+
+
+def _aligned(order, weight, sizes, k: int, capacity: int,
+             align: int) -> Routed:
+    """The held rows with each expert's block starting on a multiple of
+    ``align`` and at least ``align`` rows long, the rows between them
+    padding: with ``align`` the grouped products' row tile, every tile is
+    one expert's whatever the routing, so that the products' work is the
+    same on every step (where experts share tiles, how many tiles they
+    share and how many experts have no rows follow the routing, and the
+    step's time follows it).  ``order``: the sorted assignments' first
+    ``capacity``; an expert's rows past ``capacity`` are not kept."""
+    count = sizes.shape[0]
+    padded = jnp.maximum(-(-sizes // align), 1) * align
+    block_ends = jnp.cumsum(padded)
+    block_starts = block_ends - padded
+    starts = jnp.cumsum(sizes) - sizes
+    rows = jnp.arange(capacity, dtype=jnp.int32)
+    group = jnp.sum(rows[:, None] >= block_ends[None, :], axis=1)
+    g = jnp.minimum(group, count - 1)
+    offset = rows - block_starts[g]
+    valid = (group < count) & (offset < sizes[g])
+    # a padding row reads what the packed layout had in its place: the rows
+    # of one step stay distinct tokens, as without alignment
+    pick = order[jnp.where(valid, starts[g] + offset, rows)]
+    ends = jnp.minimum(block_ends, capacity)
+    group_sizes = jnp.diff(ends.at[-1].set(capacity), prepend=0).astype(
+        jnp.int32)
+    kept = jnp.sum(jnp.clip(capacity - block_starts, 0, sizes))
+    return Routed(
+        token=(pick // k).astype(jnp.int32),
+        weight=jnp.where(valid, weight[pick], 0.0), valid=valid,
+        group_sizes=group_sizes, sizes=sizes,
+        overflow=(sizes.sum() - kept).astype(jnp.int32))
 
 
 def relu2_ffn(x, w_up, w_down):

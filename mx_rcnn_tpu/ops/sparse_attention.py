@@ -36,9 +36,11 @@ tile and masks by the threshold: correct, not yet sparse in its work.
 
 Also returned, for the step's counters: the share of (``TILE`` x ``TILE``)
 causal tiles that hold at least one chosen key of their query tile (what a
-kernel that skips empty tiles could save), and the chosen keys of a few
-query rows (``sample``), which the benchmark compares with its reference's
-choice.
+kernel that skips empty tiles could save), the chosen keys of a few query
+rows (``sample``), which the benchmark compares with its reference's
+choice, and how many queries attention took another number of keys than
+the choice holds (``count_misses``: 0 where the attention's mask is the
+choice, whose scores it forms again).
 """
 
 from __future__ import annotations
@@ -89,6 +91,13 @@ def chosen_mask(scores, theta, cut, qpos, kpos):
     return (kpos[None, :] <= qpos[:, None]) & (
         (scores > theta[:, None])
         | ((scores == theta[:, None]) & (kpos[None, :] <= cut[:, None])))
+
+
+def count_misses(attended, qpos, top_k: int):
+    """The queries at ``qpos`` whose keys attended to, ``attended``, are
+    not ``min(t + 1, top_k)``: () int32."""
+    return jnp.sum(attended != jnp.minimum(qpos + 1, top_k)).astype(
+        jnp.int32)
 
 
 def _barrier_sum(x):
@@ -142,9 +151,10 @@ def _log_softmax_bwd(p, g):
 _log_softmax_rows.defvjp(_log_softmax_fwd, _log_softmax_bwd)
 
 
-def _attend_and_kl(q, k, v, qi, w, ki, theta, cut, lo):
+def _attend_and_kl(q, k, v, qi, w, ki, theta, cut, lo, top_k):
     """One block of queries at positions ``lo ..`` against the keys
-    ``0 .. K``: (o (Q, Hq, D), sum of the block's KL)."""
+    ``0 .. K``: (o (Q, Hq, D), sum of the block's KL, ``count_misses`` of
+    the keys it attended to)."""
     nq, hq, d = q.shape
     nk, hkv = k.shape[0], k.shape[1]
     qpos = lo + jnp.arange(nq)
@@ -158,6 +168,7 @@ def _attend_and_kl(q, k, v, qi, w, ki, theta, cut, lo):
         p = _softmax_rows(jnp.where(chosen[:, None, None, :], s, -jnp.inf))
         o = jnp.einsum("qgrk,kgd->qgrd", p.astype(v.dtype), v,
                        preferred_element_type=jnp.float32)
+        misses = count_misses(jnp.sum(chosen, -1), qpos, top_k)
     with jax.named_scope("dsa_index_loss"):
         target = jax.lax.stop_gradient(p.sum((1, 2)) / hq)
         logq = _log_softmax_rows(jnp.where(chosen, scores, -jnp.inf))
@@ -165,7 +176,7 @@ def _attend_and_kl(q, k, v, qi, w, ki, theta, cut, lo):
         kl = jnp.sum(jnp.where(
             live, target * (jnp.log(jnp.where(live, target, 1.0))
                             - jnp.where(live, logq, 0.0)), 0.0))
-    return o.reshape(nq, hq, d).astype(q.dtype), kl
+    return o.reshape(nq, hq, d).astype(q.dtype), kl, misses
 
 
 def _layout(s: int, block_q: int):
@@ -241,20 +252,22 @@ def _blocked(q, k, v, qi, w, ki, top_k: int, block_q: int,
     if tile > block_q:
         hits = hits.reshape(s // tile, -1, s // tile).any(1)
 
-    outs, kls = [], []
-    part = jax.checkpoint(_attend_and_kl)
+    outs, kls, misses = [], [], []
+    part = jax.checkpoint(_attend_and_kl, static_argnums=(9,))
     for g in range(s // size):
         end = (g + 1) * size
         k_g, v_g, ki_g = k[:end], v[:end], ki[:end]
-        o, kl = jax.lax.map(
+        o, kl, miss = jax.lax.map(
             lambda a: part(a[0], k_g, v_g, a[1], a[2], ki_g, a[3], a[4],
-                           a[5]),
+                           a[5], top_k),
             (blocks(q, g), blocks(qi, g), blocks(w, g),
              blocks(theta, g), blocks(cut, g), starts(g)))
         outs.append(o.reshape((size,) + q.shape[1:]))
         kls.append(jnp.sum(kl))
+        misses.append(jnp.sum(miss))
     return (jnp.concatenate(outs), sum(kls), _tiles_touched(hits, s, tile),
-            functools.reduce(jnp.logical_or, picks).astype(jnp.int8))
+            functools.reduce(jnp.logical_or, picks).astype(jnp.int8),
+            sum(misses))
 
 
 def sparse_attention(q, k, v, qi, w, ki, top_k: int, block_q: int = 256,
@@ -263,9 +276,10 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, block_q: int = 256,
     qi (S, J, Di); w (S, J) float32; ki (S, Di) -> (o (S, Hq, D) in
     ``q.dtype``, the sum over queries of the indexer's KL () float32, the
     share of causal tiles touched () float32, the chosen keys of the rows
-    ``sample`` (len, S) int8).  ``block_q``: queries a block of the blocked
-    path; ``interpret`` runs the TPU kernels in the Pallas interpreter
-    (tests only).  A ``jax.checkpoint`` around a call under ``KEEP_CHOICE``
+    ``sample`` (len, S) int8, the queries whose attention took another
+    number of keys than the choice holds () int32).  ``block_q``: queries
+    a block of the blocked path; ``interpret`` runs the TPU kernels in the
+    Pallas interpreter (tests only).  A ``jax.checkpoint`` around a call under ``KEEP_CHOICE``
     keeps the choice and recomputes everything else."""
     if interpret or jax.default_backend() == "tpu":  # graphlint: disable=GL203 the platform name is a host string, fixed at trace time
         from mx_rcnn_tpu.ops import sparse_attention_pallas as kernels

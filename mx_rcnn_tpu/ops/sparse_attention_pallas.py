@@ -16,7 +16,9 @@ in one order of operations, so that a score is the same number in each:
   (``cut``, by halving the index range), then the log-sum-exp of the
   chosen scores and the chosen keys a (query block, key tile) pair holds;
 * ``dsa_attend_fwd``: attention over the chosen keys of one key-value head
-  group, online softmax; ``o`` and each row's log-sum-exp;
+  group, online softmax; ``o``, each row's log-sum-exp and how many keys
+  each query attended to (the choice holds ``min(t + 1, top_k)``: a
+  different count says that this kernel's scores are not the choice's);
 * ``dsa_attend_bwd``: its backward (``ops/attention_pallas.py``'s five
   products, under the mask), ``d k, d v`` of the group's whole sequence
   resident in VMEM;
@@ -262,8 +264,8 @@ def _tile_mask(i, j, bq, bk, ki_ref, qi_ref, w_ref, theta_ref, cut_ref,
 
 
 def _fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
-                theta_ref, cut_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, bq, bk, d, r, hq, scale, heads):
+                theta_ref, cut_ref, o_ref, lse_ref, n_ref, m_ref, l_ref,
+                acc_ref, n_acc, *, bq, bk, d, r, hq, scale, heads):
     t = pl.program_id(0)
     i, j = qb_ref[t], kb_ref[t]
 
@@ -272,10 +274,12 @@ def _fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        n_acc[...] = jnp.zeros_like(n_acc)
 
     # the tile's choice, once for every head
     _, chosen = _tile_mask(i, j, bq, bk, ki_ref, qi_ref, w_ref, theta_ref,
                            cut_ref, scale, heads)
+    n_acc[...] += jnp.sum(chosen.astype(jnp.float32), axis=0, keepdims=True)
 
     def head(h):
         group = _head(h // r, d)
@@ -304,6 +308,7 @@ def _fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
             lse_ref[h] = m_ref[h] + jnp.log(l_ref[h])
 
         _each_head(one, hq)
+        n_ref[...] = n_acc[...]
 
 
 def _bwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -450,9 +455,9 @@ def _indexer_specs(bq, bk, heads, at):
                                              "bk", "interpret"))
 def attend_fwd(q, k, v, qi, ki, w_t, theta, cut, *, hkv, heads, scale, bq,
                bk, interpret):
-    """q (S, Hq * D); k, v (S, Hkv * D) -> o as q, lse (Hkv, R, 1, S).
-    Every head of a tile in one grid step, so that the tile's choice is
-    formed once."""
+    """q (S, Hq * D); k, v (S, Hkv * D) -> o as q, lse (Hkv, R, 1, S), the
+    keys each query attended to (1, S) float32.  Every head of a tile in one
+    grid step, so that the tile's choice is formed once."""
     s, hd = q.shape
     d = k.shape[1] // hkv
     hq = hd // d
@@ -460,24 +465,27 @@ def attend_fwd(q, k, v, qi, ki, w_t, theta, cut, *, hkv, heads, scale, bq,
     at = lambda t, qb, kb: (qb[t], kb[t])   # noqa: E731
     rows_q = pl.BlockSpec((bq, hd), lambda t, qb, kb: (qb[t], 0))
     rows_k = pl.BlockSpec((bk, k.shape[1]), lambda t, qb, kb: (kb[t], 0))
-    o, lse = pl.pallas_call(
+    o, lse, attended = pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, d=d, r=hq // hkv, hq=hq,
                           scale=scale, heads=heads),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((hq, 1, s), jnp.float32)),
+                   jax.ShapeDtypeStruct((hq, 1, s), jnp.float32),
+                   jax.ShapeDtypeStruct((1, s), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(qb.shape[0],),
             in_specs=[rows_q, rows_k, rows_k]
             + _indexer_specs(bq, bk, heads, at),
             out_specs=(rows_q, pl.BlockSpec(
-                (hq, 1, bq), lambda t, qb, kb: (0, 0, qb[t]))),
+                (hq, 1, bq), lambda t, qb, kb: (0, 0, qb[t])),
+                pl.BlockSpec((1, bq), lambda t, qb, kb: (0, qb[t]))),
             scratch_shapes=[pltpu.VMEM((hq, 1, bq), jnp.float32),
                             pltpu.VMEM((hq, 1, bq), jnp.float32),
-                            pltpu.VMEM((hq, d, bq), jnp.float32)]),
+                            pltpu.VMEM((hq, d, bq), jnp.float32),
+                            pltpu.VMEM((1, bq), jnp.float32)]),
         compiler_params=_params(0), interpret=interpret,
         name="dsa_attend_fwd",
     )(qb, kb, q, k, v, qi, ki, w_t, theta, cut)
-    return o, lse.reshape(hkv, hq // hkv, 1, s)
+    return o, lse.reshape(hkv, hq // hkv, 1, s), attended
 
 
 @functools.partial(jax.jit, static_argnames=("hkv", "heads", "scale", "bq",
@@ -565,10 +573,10 @@ def _attend(static, q, k, v, qi, ki, w_t, theta, cut):
 
 def _attend_fwd(static, q, k, v, qi, ki, w_t, theta, cut):
     hkv, heads, scale, bq, bk, interpret = static
-    o, lse = attend_fwd(q, k, v, qi, ki, w_t, theta, cut, hkv=hkv,
-                        heads=heads, scale=scale, bq=bq, bk=bk,
-                        interpret=interpret)
-    return (o, lse), (q, k, v, qi, ki, w_t, theta, cut, o, lse)
+    o, lse, attended = attend_fwd(q, k, v, qi, ki, w_t, theta, cut, hkv=hkv,
+                                  heads=heads, scale=scale, bq=bq, bk=bk,
+                                  interpret=interpret)
+    return (o, lse, attended), (q, k, v, qi, ki, w_t, theta, cut, o, lse)
 
 
 def _attend_bwd(static, res, g):
@@ -630,6 +638,7 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, sample=(),
     same arguments and results.  ``blocks`` is a test's (block_q, block_k)
     in place of the file's."""
     from mx_rcnn_tpu.ops.sparse_attention import (CUT, THETA, chosen_mask,
+                                                  count_misses,
                                                   indexer_scores)
     s, hq, d = q.shape
     hkv, (heads, di) = k.shape[1], qi.shape[1:]
@@ -650,7 +659,9 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, sample=(),
     q2, k2, v2 = (q.reshape(s, hq * d), k.reshape(s, hkv * d),
                   v.reshape(s, hkv * d))
     with jax.named_scope("dsa_attend"):
-        o, lse = _attend(static, q2, k2, v2, qi_p, ki_p, w_t, theta, cut)
+        o, lse, attended = _attend(static, q2, k2, v2, qi_p, ki_p, w_t, theta,
+                                   cut)
+        misses = count_misses(attended[0], jnp.arange(s), top_k)
     with jax.named_scope("dsa_index_loss"):
         kl = _kl(static, q2, k2, jax.lax.stop_gradient(lse).reshape(hq, 1, s),
                  qi_p, ki_p, w_t, theta, cut, lse_i)
@@ -670,4 +681,4 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, sample=(),
                                 jnp.arange(s)).astype(jnp.int8)
     else:
         picks = jnp.zeros((0, s), jnp.int8)
-    return o.reshape(s, hq, d), kl, touched, picks
+    return o.reshape(s, hq, d), kl, touched, picks, misses

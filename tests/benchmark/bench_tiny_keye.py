@@ -42,4 +42,8 @@ def tiny_keye_cell(name: str = CELL) -> dict:
     traffic.update({"seq_len": 64, "per_chip_batch": 2,
                     "sequences_per_chip": 8, "warmup_steps": 4,
                     "epoch_steps": 100})
+    # the cell's loss limits are set over 32768 positions a step; over this
+    # cell's 126, bfloat16 rounding alone moves the loss by up to 2.4e-4,
+    # as much as float8 does: here they are the other sequence cells' limit
+    cell["check"]["limits"].update(loss_s1=0.005, loss_s2=0.005)
     return cell

@@ -32,7 +32,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 LM_CELL = "nemotron3-nano-9l-ep16.train-8k"
 LING_CELL = "ling3-flash-6l-ep64.train-8k"
 JOYAI_CELL = "joyai-flash-5l-mtp-ep16.train-8k"
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# A JSON-lines catalog of published architectures (one row a model, with its
+# ``source_url`` and ``config``); the row check runs where it is given.
+CATALOG = os.environ.get("MODEL_CATALOG", "")
 SEED = 2147483659
 
 
@@ -205,7 +207,7 @@ def test_config_file_keeps_the_catalogs_row():
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "keye-vl2-a3b-6l-ep8.json")) as f:
         config = json.load(f)
-    if os.path.exists(CATALOG):
+    if CATALOG and os.path.exists(CATALOG):
         with open(CATALOG) as f:
             row = next(r for r in map(json.loads, f)
                        if r["name"] == "Keye-VL-2.0-30B-A3B")
@@ -285,8 +287,9 @@ def test_the_cells_limits_each_have_a_reason():
     assert set(check["limits"]) == set(check["reasons"]) == {
         "loss_s1", "loss_s2", "index_loss_s1", "grad_worst",
         "head_norm_grad_worst", "indexer_grad_worst", "selection_diff",
-        "first_delta_worst", "routing_diff", "moe_overflow"}
+        "count_misses", "first_delta_worst", "routing_diff", "moe_overflow"}
     assert check["limits"]["moe_overflow"] == 0
+    assert check["limits"]["count_misses"] == 0
     assert all(len(r) > 40 for r in check["reasons"].values())
 
 

@@ -84,11 +84,12 @@ def test_with_every_key_chosen_the_op_is_causal_attention():
     """top_k no smaller than the sequence: every key at or before a query is
     chosen, and the output is ``causal_gqa``'s."""
     q, k, v, qi, w, ki = _operands(64)
-    o, kl, touched, _ = sa.sparse_attention(q, k, v, qi, w, ki, 64, 16)
+    o, kl, touched, _, misses = sa.sparse_attention(q, k, v, qi, w, ki, 64,
+                                                    16)
     want = causal_gqa(q[None], k[None], v[None], 16)[0]
     np.testing.assert_allclose(o, want, rtol=2e-5,
                                atol=2e-5 * float(jnp.abs(want).max()))
-    assert float(touched) == 1.0 and float(kl) > 0
+    assert float(touched) == 1.0 and float(kl) > 0 and int(misses) == 0
 
 
 @pytest.mark.parametrize("exact", [False, True], ids=["random", "ties"])
@@ -99,8 +100,9 @@ def test_the_choice_is_the_top_k_of_its_own_scores(exact):
     s, top_k = 128, 24
     q, k, v, qi, w, ki = _operands(s, exact=exact)
     rows = tuple(range(s))
-    _, _, _, chosen = sa.sparse_attention(q, k, v, qi, w, ki, top_k, 32,
-                                          rows)
+    _, _, _, chosen, misses = sa.sparse_attention(q, k, v, qi, w, ki, top_k,
+                                                  32, rows)
+    assert int(misses) == 0
     scores = sa.indexer_scores(qi, w, ki)
     pos = jnp.arange(s)
     masked = jnp.where(pos[None, :] <= pos[:, None], scores, -jnp.inf)
@@ -119,8 +121,8 @@ def test_the_choice_is_the_top_k_of_its_own_scores(exact):
 
 def _loss_of(fn, ct):
     def loss(*a):
-        o, kl, _, picks = fn(*a)
-        return jnp.sum(o * ct) + 0.7 * kl, (kl, picks)
+        o, kl, _, picks, misses = fn(*a)
+        return jnp.sum(o * ct) + 0.7 * kl, (kl, picks, misses)
     return loss
 
 
@@ -145,14 +147,48 @@ def test_the_kernels_in_the_interpreter_are_the_blocked_path(exact):
         (_, aux), g = jax.value_and_grad(_loss_of(fn, ct), argnums=range(6),
                                          has_aux=True)(*args)
         grads[name] = (aux, g)
-    (kl_b, pick_b), g_b = grads["blocked"]
-    (kl_k, pick_k), g_k = grads["kernels"]
+    (kl_b, pick_b, miss_b), g_b = grads["blocked"]
+    (kl_k, pick_k, miss_k), g_k = grads["kernels"]
     assert float(kl_k) == pytest.approx(float(kl_b), rel=1e-5)
+    # each attended to what its own choice holds
+    assert int(miss_b) == int(miss_k) == 0
     for name, a, b in zip(("q", "k", "v", "qi", "w", "ki"), g_b, g_k):
         err = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(a))
         assert err < (1e-5 if exact or name in "qkv" else 5e-2), (name, err)
     if exact:
         np.testing.assert_array_equal(pick_k, pick_b)
+
+
+def test_attention_that_masks_another_set_than_the_choice_counts_misses():
+    """The forward kernel counts the keys each query attended to.  With the
+    choice's own threshold every query attends to ``min(t + 1, top_k)``
+    keys and none is counted; with the threshold raised a little past the
+    first ``top_k`` queries (what scores formed in another order would do
+    to a key on it), those queries lose their key on it and each is
+    counted."""
+    s, top_k, blocks = 256, 40, (128, 128)
+    q, k, v, qi, w, ki = _operands(s, hq=4, hkv=2, d=128, heads=4, di=16)
+    heads, di = qi.shape[1:]
+    qi_p = sp._padded(qi).reshape(s, heads * 128)
+    ki_p, w_t = sp._padded(ki), w.T
+    theta, cut, _, _ = sp.select(qi_p, ki_p, w_t, top_k=top_k, heads=heads,
+                                 scale=di ** -0.5, bk=128, interpret=True)
+    pos = jnp.arange(s)
+
+    def misses(theta):
+        attended = sp.attend_fwd(
+            q.reshape(s, -1), k.reshape(s, -1), v.reshape(s, -1), qi_p, ki_p,
+            w_t, theta, cut, hkv=2, heads=heads, scale=di ** -0.5,
+            bq=blocks[0], bk=blocks[1], interpret=True)[2]
+        return int(sa.count_misses(attended[0], pos, top_k))
+
+    assert misses(theta) == 0
+    # a step of a millionth, not of one float32 step: a threshold of 0 (a
+    # query whose indexer heads all read ReLU's zero on many keys) would
+    # step to a subnormal, which flushing to zero reads as 0
+    raised = jnp.where(pos >= top_k,
+                       theta + 1e-6 * jnp.maximum(jnp.abs(theta), 1.0), theta)
+    assert misses(raised) == s - top_k
 
 
 def test_the_kernels_lower_for_the_tpu_at_the_cells_shape():
@@ -163,7 +199,7 @@ def test_the_kernels_lower_for_the_tpu_at_the_cells_shape():
     s, bf = 32768, jnp.bfloat16
 
     def loss(q, k, v, qi, w, ki):
-        o, kl, _, _ = sp.sparse_attention(q, k, v, qi, w, ki, 2048)
+        o, kl, _, _, _ = sp.sparse_attention(q, k, v, qi, w, ki, 2048)
         return jnp.sum(o.astype(jnp.float32)) + kl
 
     args = (jax.ShapeDtypeStruct((s, 32, 128), bf),
@@ -323,6 +359,84 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer():
         int(c) for r in routed for c in r.sizes]
 
 
+@pytest.mark.parametrize("capacity,align", [(192, 8), (192, 16), (64, 16)],
+                         ids=["aligned", "wider", "short"])
+def test_aligned_rows_are_the_packed_rows_with_each_tile_one_experts(
+        capacity, align):
+    """``held_assignments``' aligned layout keeps the packed layout's rows,
+    each held expert's in order from a multiple of ``align``, at least one
+    block of ``align`` rows an expert: every tile of ``align`` rows holds
+    one expert's rows and the layer's output is the packed one's.  Where the
+    blocks do not fit the capacity, the assignments left out are the
+    overflow."""
+    _, p, x = _moe_setup()
+    held = (4, 4)
+    idx, weight = moe_ops.route(x, p["router"], 0.0, 2, 1.0, True,
+                                scoring="softmax")
+    packed = moe_ops.held_assignments(idx, weight, held, capacity)
+    aligned = moe_ops.held_assignments(idx, weight, held, capacity, align)
+    assert aligned.token.shape == (capacity,)
+    assert aligned.sizes.tolist() == packed.sizes.tolist()
+    sizes = np.asarray(aligned.sizes)
+    bounds = np.cumsum(np.asarray(aligned.group_sizes))
+    assert bounds[-1] == capacity and not any(bounds[:-1] % align)
+    valid, token = np.asarray(aligned.valid), np.asarray(aligned.token)
+    assert int(aligned.overflow) == int(sizes.sum() - valid.sum())
+    lo = 0
+    for g, hi in enumerate(bounds):
+        kept = valid[lo:hi]
+        # an expert's rows come first in its blocks, then padding
+        assert kept.sum() == min(sizes[g], hi - lo) and kept[:kept.sum()].all()
+        lo = hi
+    if int(aligned.overflow):
+        return
+    assert int(packed.overflow) == 0
+    cut = lambda name: p[name][4:8]  # noqa: E731
+    outs = [moe_ops.held_experts(x, r, cut("experts_up"),
+                                 cut("experts_down"),
+                                 w_gate=cut("experts_gate"))
+            for r in (packed, aligned)]
+    np.testing.assert_allclose(outs[1], outs[0], rtol=1e-6, atol=1e-7)
+    assert sorted(token[valid]) == sorted(np.asarray(packed.token)[
+        np.asarray(packed.valid)])
+
+
+def test_aligned_rows_give_the_products_the_same_visits_for_any_routing():
+    """At the grouped products' row tile, the aligned layout's visits
+    (``gmm_pallas._schedule``) that compute a product are one a tile, each
+    over the whole tile, for every routing, one that leaves held experts
+    without rows among them: the products' work does not follow the data.
+    The packed layout's count of such visits does."""
+    from mx_rcnn_tpu.ops import gmm_pallas
+
+    _, p, _ = _moe_setup()
+    capacity, tm = 256, 16
+    routings = []
+    for seed in range(4):
+        x = jax.random.normal(jax.random.PRNGKey(100 + seed), (96, 64))
+        routings.append(moe_ops.route(x, p["router"], 0.0, 2, 1.0, True,
+                                      scoring="softmax"))
+    # every token to held experts 4 and 5 alone: 6 and 7 get no rows
+    routings.append((jnp.tile(jnp.array([4, 5]), (96, 1)),
+                     jnp.full((96, 2), 0.5)))
+    visits = {"packed": set(), "aligned": set()}
+    for idx, weight in routings:
+        for name, align in (("packed", 0), ("aligned", tm)):
+            routed = moe_ops.held_assignments(idx, weight, (4, 4), capacity,
+                                              align)
+            assert int(routed.overflow) == 0
+            _, tile, lo, hi = map(np.asarray, gmm_pallas._schedule(
+                routed.group_sizes, capacity, tm))
+            row0 = tile * tm
+            product = np.maximum(lo, row0) < np.minimum(hi, row0 + tm)
+            visits[name].add(int(product.sum()))
+            if align:
+                assert ((lo[product] <= row0[product])
+                        & (hi[product] >= row0[product] + tm)).all()
+    assert visits["aligned"] == {capacity // tm}
+    assert len(visits["packed"]) > 1
+
+
 def _as_it_was(fn, old, new):
     """``fn``'s source with ``new`` put back to ``old``, compiled in its own
     module's namespace: the function as the parent commit had it."""
@@ -343,15 +457,25 @@ ROUTE_NEW = ('''    if scoring not in ("sigmoid", "softmax"):
           scoring: str = "sigmoid"):''')
 ROUTE_OLD = ('''    scores = jax.nn.sigmoid(logits)''',
              '''groups: Optional[Tuple[int, int]] = None):''')
+HELD_NEW = ('''                     capacity: int, align: int = 0) -> Routed:
+    """Sort the (T, k) assignments by held expert; keep ``capacity``.
+    ``align`` > 0 lays each held expert's rows out from a multiple of
+    ``align`` rows, in at least one such block (``_aligned``)."""''',
+            '''    if align:
+        return _aligned(order, weight.reshape(-1), sizes, k, capacity, align)
+''')
+HELD_OLD = ('''                     capacity: int) -> Routed:
+    """Sort the (T, k) assignments by held expert; keep ``capacity``."""''',
+            "")
 
 
 @pytest.mark.parametrize("family", ["ling_flash_tiny", "joyai_flash_tiny"])
 def test_route_and_gated_moe_trace_as_they_did_for_the_accepted_families(
         family, monkeypatch):
-    """``route``'s new argument and ``GatedMoE``'s optional shared expert
-    leave the accepted families' expert layers what they were: their
-    jaxprs equal the ones the parent's ``route`` gives, argument for
-    argument."""
+    """``route``'s and ``held_assignments``' new arguments and
+    ``GatedMoE``'s optional shared expert leave the accepted families'
+    expert layers what they were: their jaxprs equal the ones the parent's
+    ``route`` and ``held_assignments`` give, argument for argument."""
     cfg = generate_config(family, "synthetic_tokens")
     build = (ling_flash if family.startswith("ling") else joyai_flash).build_lm
     net = build(cfg).net
@@ -361,7 +485,16 @@ def test_route_and_gated_moe_trace_as_they_did_for_the_accepted_families(
     now = str(jax.make_jaxpr(layer.apply)(params, x))
     route, old = moe_ops.route, _as_it_was(moe_ops.route, ROUTE_OLD,
                                            ROUTE_NEW)
+    old_held = _as_it_was(moe_ops.held_assignments, HELD_OLD, HELD_NEW)
+
+    def parents_held(idx, weight, held, capacity, align):
+        assert align == 0
+        return old_held(idx, weight, held, capacity)
+
+    # the parent's ``route`` takes no ``scoring``: a sigmoid router's call
+    # names none
     monkeypatch.setattr(moe_ops, "route", old)
+    monkeypatch.setattr(moe_ops, "held_assignments", parents_held)
     assert str(jax.make_jaxpr(layer.apply)(params, x)) == now
     assert set(params["params"]) >= {"shared_gate", "shared_up",
                                       "shared_down"}
@@ -405,7 +538,7 @@ def test_presets_build_what_they_name():
             "keye_vl2_tiny", "synthetic_tokens", network__layer_pattern="SL"))
 
 
-def test_the_cells_parameters_are_the_issues_count():
+def test_the_cells_parameter_count():
     """Six layers at published widths, 16 of 128 experts, an eighth of the
     vocabulary: 659.19 M parameters, counted from the program's own
     leaves."""
