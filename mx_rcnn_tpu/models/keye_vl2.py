@@ -27,8 +27,9 @@ Layer ``i`` is two residual blocks, ``x <- x + DSA_i(RMSNorm(x))`` and ``x
 After the last layer a final RMSNorm and an untied head give the next-token
 loss.  Activations and the residual stream are in ``dtype`` (bfloat16 on
 the chip), parameters float32.  An attention block is a ``jax.checkpoint``
-a sequence that keeps the choice, a threshold a query, and nothing else
-(``KEEP_CHOICE``); an MoE block is an ``nn.remat``.
+a sequence that keeps the choice, a threshold and a cut a query, and on
+the kernels' path the indexer loss's gradient (``KEEP_CHOICE``); an MoE
+block is an ``nn.remat``.
 
 Named scopes: ``embed``; ``dsa`` (the attention block whole) with
 ``dsa_index`` (the indexer's projections, scores and choice),

@@ -53,12 +53,13 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-# the choice as a checkpoint around a call keeps it: a threshold and a cut
+# what a checkpoint around a call keeps: the choice, a threshold and a cut
 # a query, and on the kernels' path the chosen scores' log-sum-exp a query
-# (``sparse_attention_pallas.LSE_I``)
+# (``sparse_attention_pallas.LSE_I``) and the indexer loss's gradient
+# (``sparse_attention_pallas.KL_GRAD``)
 THETA, CUT = "sparse_attention.theta", "sparse_attention.cut"
 KEEP_CHOICE = jax.checkpoint_policies.save_only_these_names(
-    THETA, CUT, "sparse_attention.lse_i")
+    THETA, CUT, "sparse_attention.lse_i", "sparse_attention.kl_grad")
 # the (queries, keys) tile ``tiles_touched`` counts, where the sequence's
 # groups are at least that long
 TILE = 512
@@ -279,8 +280,9 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, block_q: int = 256,
     ``sample`` (len, S) int8, the queries whose attention took another
     number of keys than the choice holds () int32).  ``block_q``: queries
     a block of the blocked path; ``interpret`` runs the TPU kernels in the
-    Pallas interpreter (tests only).  A ``jax.checkpoint`` around a call under ``KEEP_CHOICE``
-    keeps the choice and recomputes everything else."""
+    Pallas interpreter (tests only).  A ``jax.checkpoint`` around a call
+    under ``KEEP_CHOICE`` keeps the choice and, on the kernels' path, the
+    indexer loss's gradient, and recomputes everything else."""
     if interpret or jax.default_backend() == "tpu":  # graphlint: disable=GL203 the platform name is a host string, fixed at trace time
         from mx_rcnn_tpu.ops import sparse_attention_pallas as kernels
 

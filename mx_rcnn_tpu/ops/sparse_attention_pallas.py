@@ -26,7 +26,9 @@ in one order of operations, so that a score is the same number in each:
   the target (the heads' probabilities summed over the head count), the
   indexer's loss a query (``sum p log p - sum p I + lse_I``) and its
   gradient with respect to the indexer's queries, key and weights, which
-  the ``custom_vjp`` hands back times the loss's cotangent.
+  the ``custom_vjp`` hands back times the loss's cotangent; a checkpoint
+  under ``KEEP_CHOICE`` keeps that gradient (``KL_GRAD``), so that its
+  backward does not run this kernel again.
 
 This first form visits every tile on or below the diagonal and masks by
 the threshold: correct, with the work of dense causal attention.  A tile
@@ -64,6 +66,10 @@ _INT_MIN = np.int32(-2 ** 31)
 # the chosen scores' log-sum-exp a query, kept by a checkpoint as the choice
 # is (``sparse_attention.KEEP_CHOICE``)
 LSE_I = "sparse_attention.lse_i"
+# ``dsa_kl``'s gradient of the indexer's queries, weights and key, kept by
+# the same checkpoint at the indexer's width, so that its backward does not
+# run the kernel again
+KL_GRAD = "sparse_attention.kl_grad"
 _LOW = np.int32(0x7FFFFFFF)
 
 
@@ -607,11 +613,14 @@ def _kl(static, q, k, lse, qi, ki, w_t, theta, cut, lse_i):
 
 
 def _kl_fwd(static, q, k, lse, qi, ki, w_t, theta, cut, lse_i):
-    hkv, heads, scale, bq, bk, interpret = static
+    hkv, heads, scale, bq, bk, interpret, di = static
     kl, dqi, dw, dki = index_loss(q, k, lse, qi, ki, w_t, theta, cut, lse_i,
                                   hkv=hkv, heads=heads, scale=scale, bq=bq,
                                   bk=bk, interpret=interpret)
-    return jnp.sum(kl), (dqi, dw, dki)
+    # lanes di.. of a head's block are zeros (the other side's operand is
+    # padded with zeros): keep the gradient without them
+    dqi = dqi.reshape(qi.shape[0], heads, _LANES)[..., :di]
+    return jnp.sum(kl), checkpoint_name((dqi, dw, dki[:, :di]), KL_GRAD)
 
 
 def _kl_bwd(static, res, g):
@@ -619,6 +628,8 @@ def _kl_bwd(static, res, g):
     # the target is held constant and the choice takes no gradient: the
     # loss reaches the indexer's queries, key and weights alone
     with jax.named_scope("dsa"), jax.named_scope("dsa_index_loss"):
+        dqi = _padded(dqi).reshape(dqi.shape[0], -1)
+        dki = _padded(dki)
         return (None, None, None, (g * dqi).astype(dqi.dtype),
                 (g * dki).astype(dki.dtype), g * dw, None, None, None)
 
@@ -644,7 +655,8 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, sample=(),
     hkv, (heads, di) = k.shape[1], qi.shape[1:]
     bq, bk = blocks or (BLOCK_Q, BLOCK_K)
     scale = float(di) ** -0.5
-    # (hkv, heads, scale, bq, bk, interpret): the custom_vjp's static part
+    # (hkv, heads, scale, bq, bk, interpret): the custom_vjps' static part,
+    # the loss's with the indexer's head size after it
     static = (hkv, heads, scale, bq, bk, interpret)
     qi_p = _padded(qi).reshape(s, heads * _LANES)
     ki_p = _padded(ki.astype(qi.dtype))
@@ -663,8 +675,9 @@ def sparse_attention(q, k, v, qi, w, ki, top_k: int, sample=(),
                                    cut)
         misses = count_misses(attended[0], jnp.arange(s), top_k)
     with jax.named_scope("dsa_index_loss"):
-        kl = _kl(static, q2, k2, jax.lax.stop_gradient(lse).reshape(hq, 1, s),
-                 qi_p, ki_p, w_t, theta, cut, lse_i)
+        kl = _kl(static + (di,), q2, k2,
+                 jax.lax.stop_gradient(lse).reshape(hq, 1, s), qi_p, ki_p,
+                 w_t, theta, cut, lse_i)
     # the chosen keys a (tile x tile) pair holds, from the choice's
     # (SELECT_Q x bk) counts
     n = s // bk

@@ -6,14 +6,17 @@ dense causal attention where every key is chosen, and its choice against
 softmax router; the share of the experts tied to the uncut layer with no
 shared expert; ``route`` and ``GatedMoE`` tracing for the accepted families
 to what they traced before; the Mosaic kernels in the interpreter against
-the blocked path, and lowered for the TPU at the cell's shape; the family
+the blocked path, and lowered for the TPU at the cell's shape; what a
+checkpoint under ``KEEP_CHOICE`` keeps of them and runs again; the family
 through ``train_net`` with its counters and scopes.
 
 Tolerances as ``test_joyai_flash.py``: float32 outputs to 2e-5 of their
 scale, gradients to 2e-4 (two orders of summation of the same products).
 """
 
+import collections
 import inspect
+import re
 import textwrap
 
 import jax
@@ -215,6 +218,120 @@ def test_the_kernels_lower_for_the_tpu_at_the_cells_shape():
     outside = "\n".join(line for line in text.splitlines()
                         if "tpu_custom_call" not in line)
     assert "32768x32768" not in outside
+
+
+# the policy the checkpoint had before it kept the indexer loss's gradient
+CHOICE_ALONE = jax.checkpoint_policies.save_only_these_names(
+    sa.THETA, sa.CUT, sp.LSE_I)
+
+
+def _mapped_loss(policy, top_k, interpret=False, blocks=None):
+    """A loss over the kernels as the model runs them: one sequence at a
+    time under ``lax.map``, each a ``jax.checkpoint`` under ``policy``."""
+    def one(a):
+        o, kl, _, _, _ = sp.sparse_attention(*a, top_k, (), interpret, blocks)
+        return o, kl
+
+    def loss(*a):
+        o, kl = jax.lax.map(jax.checkpoint(one, policy=policy), a)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(kl)
+
+    return loss
+
+
+def _kernel_runs(text):
+    """How often each Mosaic kernel of a lowered module runs, by its name:
+    a private function's kernels as often as it is called (a loop's body
+    once), since calls that lower alike share one function."""
+    kernels, calls, name = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"\s*func\.func \w+ @([\w.$-]+)\(", line)
+        if head:
+            name = head.group(1)
+            kernels[name], calls[name] = [], []
+        elif name is not None:
+            kernels[name] += re.findall(r'kernel_name = "(\w+)"', line)
+            calls[name] += re.findall(r"(?:^|\s)(?:func\.)?call @([\w.$-]+)\(",
+                                      line)
+
+    def runs(f):
+        out = collections.Counter(kernels[f])
+        for g in calls[f]:
+            out += runs(g)
+        return out
+
+    return dict(runs("main"))
+
+
+@pytest.mark.parametrize("policy,kl_runs", [
+    pytest.param(sa.KEEP_CHOICE, 1, id="keep_choice"),
+    pytest.param(CHOICE_ALONE, 2, id="choice_alone"),
+])
+def test_a_checkpoint_under_the_policy_runs_the_loss_kernel_once(policy,
+                                                                 kl_runs):
+    """At the cell's shape (one sequence of 32768, 32 query heads over 4 of
+    128, an indexer of 16 heads of 64 choosing 2048), under ``lax.map``
+    and a checkpoint, the loss and its gradient lower for the TPU to five
+    kernel calls where ``KEEP_CHOICE`` keeps the loss kernel's gradient —
+    ``dsa_kl`` once — and to six where the checkpoint keeps the choice
+    alone and runs ``dsa_kl`` again.  The forward kernel runs twice either
+    way: the checkpoint does not keep ``o``."""
+    s, bf = 32768, jnp.bfloat16
+    args = (jax.ShapeDtypeStruct((1, s, 32, 128), bf),
+            jax.ShapeDtypeStruct((1, s, 4, 128), bf),
+            jax.ShapeDtypeStruct((1, s, 4, 128), bf),
+            jax.ShapeDtypeStruct((1, s, 16, 64), bf),
+            jax.ShapeDtypeStruct((1, s, 16), jnp.float32),
+            jax.ShapeDtypeStruct((1, s, 64), bf))
+    fn = jax.value_and_grad(_mapped_loss(policy, 2048), argnums=range(6))
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert _kernel_runs(text) == {"dsa_select": 1, "dsa_attend_fwd": 2,
+                                  "dsa_kl": kl_runs, "dsa_attend_bwd": 1}
+
+
+def _pallas_calls(jaxpr, name):
+    """The ``pallas_call``s named ``name`` in a jaxpr, inside others too,
+    each as often as the jaxpr holding it is an equation's."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pallas_calls(sub, name)
+    return n
+
+
+def test_the_kept_loss_gradient_is_the_recomputed_one_to_the_bit(capsys):
+    """In the interpreter, at blocks of 128 over 256 positions: the
+    gradient of every operand with the loss kernel's gradient kept equals
+    the one whose checkpoint runs the kernel again, bit for bit; the jaxpr
+    holds one ``dsa_kl`` call fewer; and the kept gradient of the indexer's
+    queries and key has the indexer's width, not the kernels' 128 lanes."""
+    s, top_k, blocks = 256, 40, (128, 128)
+    args = tuple(a[None] for a in _operands(s, hq=4, hkv=2, d=128, heads=4,
+                                            di=16))
+    grads, calls = [], []
+    for policy in (sa.KEEP_CHOICE, CHOICE_ALONE):
+        fn = jax.value_and_grad(_mapped_loss(policy, top_k, True, blocks),
+                                argnums=range(6))
+        calls.append(_pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr, "dsa_kl"))
+        grads.append(jax.tree_util.tree_leaves(jax.jit(fn)(*args)))
+    assert calls == [1, 2]
+    for kept, again in zip(*grads):
+        np.testing.assert_array_equal(np.asarray(kept), np.asarray(again))
+
+    def one(*a):
+        o, kl, _, _, _ = sp.sparse_attention(*a, top_k, (), True, blocks)
+        return jnp.sum(o ** 2) + kl
+
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        jax.checkpoint(one, policy=sa.KEEP_CHOICE), *(a[0] for a in args))
+    # one line a residual: "f32[256,4,16] named '<name>' from ..."
+    kept = sorted(line.split()[0] for line in capsys.readouterr().out
+                  .splitlines() if f"named '{sp.KL_GRAD}'" in line)
+    assert kept == sorted([f"f32[{s},4,16]", f"f32[4,{s}]", f"f32[{s},16]"])
 
 
 def test_off_the_tpu_the_op_takes_the_blocked_path_without_being_told():
