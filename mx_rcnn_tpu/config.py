@@ -136,7 +136,7 @@ class NetworkConfig:
     # no cell has measured it (ROADMAP D5).  0 = off.
     stem_channel_pad: int = 0
     # -- sequence-model families (models/nemotron_h.py, models/ling_flash.py,
-    # models/joyai_flash.py, models/keye_vl2.py)
+    # models/joyai_flash.py, models/keye_vl2.py, models/evabyte.py)
     # "detector" = the Faster R-CNN families above; "nemotron_h" = a hybrid
     # stack of Mamba-2 ('M'), attention ('*') and routed-expert ('E')
     # blocks, one letter a block in ``layer_pattern`` (the published
@@ -150,8 +150,11 @@ class NetworkConfig:
     # multi-token-prediction modules that share the stack's embedding and
     # head; "keye_vl2" = layers of grouped-query attention over the keys a
     # learned indexer chooses ('S' each), every one followed by a
-    # softmax-routed expert MLP with no shared expert.  Widths keep their
-    # published names.  The
+    # softmax-routed expert MLP with no shared expert; "evabyte" = layers of
+    # EVA attention ('V' each: exact keys inside a window, one pooled key
+    # and value for each earlier chunk) and a dense SwiGLU on a float32
+    # residual stream, then several byte-prediction heads.  Widths keep
+    # their published names.  The
     # family also chooses the builder, the loss, the loader and the
     # optimizer: the one table of ``families.py``.
     family: str = "detector"
@@ -232,6 +235,15 @@ class NetworkConfig:
     # tile every tile is one expert's, so that their work is the same on
     # every step whatever the routing (ops/moe.py — held_assignments)
     moe_group_rows: int = 0
+    # -- evabyte alone -----------------------------------------------------------
+    # EVA attention (ops/eva.py): exact keys inside blocks of ``eva_window``
+    # positions, one pooled key and value for each earlier chunk of
+    # ``eva_chunk`` positions; every matrix starts normal with ``init_std``
+    eva_window: int = 0
+    eva_chunk: int = 0
+    init_std: float = 0.02
+    # heads after the last layer: head i predicts the token 1 + i ahead
+    num_pred_heads: int = 1
     # -- ling_flash alone ------------------------------------------------------
     # the KDA gate's lower bound a position (kda_safe_gate): the log-decay
     # is ``kda_lower_bound * sigmoid(.)``, so a sub-chunk of 16 positions
@@ -1091,6 +1103,31 @@ _NETWORKS: Mapping[str, Mapping[str, Any]] = {
         moe_capacity_factor=4.0,
         compute_dtype="float32",
     ),
+    # EvaByte (config.json's keys) at its published widths, whole: 32 layers
+    # of EVA attention (32 heads of 128 for queries, keys and values alike,
+    # windows of 2048 bytes, chunks of 16) and a dense SwiGLU of 11008, a
+    # vocabulary of 320 byte ids, 8 prediction heads.  A chip's share is
+    # this preset with layer_pattern overridden
+    # (benchmark/configs/evabyte-4l-pp8.json).
+    "evabyte": dict(
+        name="evabyte", family="evabyte", fixed_params=(),
+        layer_pattern="V" * 32, hidden_size=4096, vocab_size=320,
+        norm_eps=1e-5, init_std=0.01275, num_attention_heads=32,
+        num_key_value_heads=32, head_dim=128, rope_theta=1e5,
+        eva_window=2048, eva_chunk=16, intermediate_size=11008,
+        num_pred_heads=8,
+    ),
+    # test-only miniature of the same family: every mechanism, CPU-sized
+    # (two layers; 2 heads of 128, so that the kernels' interpreter takes
+    # them; windows of 64, chunks of 16; 4 heads)
+    "evabyte_tiny": dict(
+        name="evabyte_tiny", family="evabyte", fixed_params=(),
+        layer_pattern="VV", hidden_size=256, vocab_size=320, norm_eps=1e-5,
+        init_std=0.0625, num_attention_heads=2, num_key_value_heads=2,
+        head_dim=128, rope_theta=1e5, eva_window=64, eva_chunk=16,
+        attn_block_q=32, intermediate_size=384, num_pred_heads=4,
+        compute_dtype="float32",
+    ),
 }
 
 # every preset ``generate_config`` builds a network from (the train CLI's
@@ -1119,15 +1156,17 @@ _NETWORK_SECTIONS: Mapping[str, Mapping[str, Mapping[str, Any]]] = {
         "train": dict(seq_len=64, batch_images=2, flip=False),
     },
 }
-# ling_flash, joyai_flash and keye_vl2 train by the recipe nemotron_h
-# states, for the reasons it states
+# ling_flash, joyai_flash, keye_vl2 and evabyte train by the recipe
+# nemotron_h states, for the reasons it states
 _NETWORK_SECTIONS = {**_NETWORK_SECTIONS,
                      "ling_flash": _NETWORK_SECTIONS["nemotron_h"],
                      "ling_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"],
                      "joyai_flash": _NETWORK_SECTIONS["nemotron_h"],
                      "joyai_flash_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"],
                      "keye_vl2": _NETWORK_SECTIONS["nemotron_h"],
-                     "keye_vl2_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
+                     "keye_vl2_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"],
+                     "evabyte": _NETWORK_SECTIONS["nemotron_h"],
+                     "evabyte_tiny": _NETWORK_SECTIONS["nemotron_h_tiny"]}
 
 _DATASETS: Mapping[str, Mapping[str, Any]] = {
     "PascalVOC": dict(

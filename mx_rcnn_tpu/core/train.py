@@ -318,19 +318,24 @@ def loss_and_metrics_lm(  # graphlint: jit (traced via LOSS_FNS)
     """The loss of a sequence family's stack (``models/nemotron_h.py``,
     ``models/ling_flash.py``: the next-token loss; ``models/joyai_flash.py``:
     that plus its multi-token-prediction module's, weighted;
-    ``models/keye_vl2.py``: that plus every layer's indexer loss) on
-    ``(N, S)`` ids, and the routed-expert counters of the step: mean
-    assignments per token that fell on held experts (mean over the expert
-    layers), the worst layer's largest expert load over its mean,
-    the rows not computed (must be 0), and the rows of every held expert
-    (layers x held; a log line shows its mean); beside them whatever
-    further scalar counters the family's stack returns, under their own
-    names (``models/ling_flash.py``: ``kda_chunk_log_decay_min``;
-    ``models/joyai_flash.py``: ``loss_main``, ``mtp_loss``;
-    ``models/keye_vl2.py``: ``loss_main``, ``index_loss``,
-    ``tiles_touched_pct``, ``dsa_chosen``, ``dsa_count_misses``).  Nothing
-    here knows a family."""
+    ``models/keye_vl2.py``: that plus every layer's indexer loss;
+    ``models/evabyte.py``: the mean of its prediction heads' losses) on
+    ``(N, S)`` ids, and, where the stack has an expert layer (it returns
+    ``sizes``), the routed-expert counters of the step: mean assignments
+    per token that fell on held experts (mean over the expert layers), the
+    worst layer's largest expert load over its mean, the rows not computed
+    (must be 0), and the rows of every held expert (layers x held; a log
+    line shows its mean); a stack without one reports none of them.
+    Beside them whatever further scalar counters the family's stack
+    returns, under their own names (``models/ling_flash.py``:
+    ``kda_chunk_log_decay_min``; ``models/joyai_flash.py``: ``loss_main``,
+    ``mtp_loss``; ``models/keye_vl2.py``: ``loss_main``, ``index_loss``,
+    ``tiles_touched_pct``, ``dsa_chosen``, ``dsa_count_misses``;
+    ``models/evabyte.py``: ``loss_main``, ``eva_summary_keys_pct``,
+    ``eva_count_misses``).  Nothing here knows a family."""
     loss, aux = model.apply({"params": params}, batch.ids)
+    if "sizes" not in aux:
+        return loss, {**aux, "loss": loss}
     sizes = aux.pop("sizes").astype(jnp.float32)
     overflow = aux.pop("overflow")
     per_layer = sizes.sum(-1)

@@ -10,14 +10,17 @@ module imports nothing of the package and any module may import it.
 
 A new family is one entry here, its model under ``models/``, and (for a
 sequence family) a preset in ``config.py``; no other file tests the
-family's name.  The sequence families are four: ``nemotron_h`` (Mamba-2,
+family's name.  The sequence families are five: ``nemotron_h`` (Mamba-2,
 attention and routed-expert blocks), ``ling_flash`` (a gated delta rule or
 latent attention, then a dense or routed-expert MLP), ``joyai_flash``
 (latent attention with a low-rank query in every layer, the same MLPs, and
-a multi-token-prediction module on the stack's own embedding and head) and
+a multi-token-prediction module on the stack's own embedding and head),
 ``keye_vl2`` (grouped-query attention over the keys a learned indexer
-chooses, then a softmax-routed expert MLP); all four take the ``lm`` step,
-AdamW and the token loader.
+chooses, then a softmax-routed expert MLP) and ``evabyte`` (EVA attention,
+exact keys inside a window and one pooled key and value for each earlier
+chunk, then a dense MLP, on a float32 residual stream, with several
+byte-prediction heads); all five take the ``lm`` step, AdamW and the token
+loader.
 """
 
 from __future__ import annotations
@@ -70,6 +73,7 @@ FAMILIES: Dict[str, Family] = {
     "ling_flash": _sequence("mx_rcnn_tpu.models.ling_flash:build_lm"),
     "joyai_flash": _sequence("mx_rcnn_tpu.models.joyai_flash:build_lm"),
     "keye_vl2": _sequence("mx_rcnn_tpu.models.keye_vl2:build_lm"),
+    "evabyte": _sequence("mx_rcnn_tpu.models.evabyte:build_lm"),
 }
 
 
