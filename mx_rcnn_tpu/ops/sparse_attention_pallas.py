@@ -4,10 +4,12 @@ Four Mosaic kernels, each a walk over (key block, query block) tiles with
 the keys down the sublanes and the queries across the lanes (keys-major,
 as ``ops/attention_pallas.py``'s backward), so that everything a query owns
 — its threshold, its softmax statistics, its indexer weights — is a row
-``(1, block_q)`` that broadcasts down the tile.  Every kernel forms the
-indexer's scores of its tile again from the padded operands (``_index_tile``:
-an indexer head's 64 channels padded to the 128 lanes, one product a head),
-in one order of operations, so that a score is the same number in each:
+``(1, block_q)`` that broadcasts down the tile.  Three kernels form the
+indexer's scores of a tile from the padded operands (``_index_tile``: an
+indexer head's 64 channels padded to the 128 lanes, one product a head), in
+one order of operations, so that a score is the same number in each:
+``dsa_select``, ``dsa_attend_fwd`` and ``dsa_kl``.  The backward reads the
+forward's choice, packed 32 keys to an int32 word:
 
 * ``dsa_select``: one block of queries against all the keys before it;
   its scores, as order-preserving int32 keys, stay in VMEM while the
@@ -15,13 +17,17 @@ in one order of operations, so that a score is the same number in each:
   passes after the sign), then the last tied key that is still chosen
   (``cut``, by halving the index range), then the log-sum-exp of the
   chosen scores and the chosen keys a (query block, key tile) pair holds;
-* ``dsa_attend_fwd``: attention over the chosen keys of one key-value head
-  group, online softmax; ``o``, each row's log-sum-exp and how many keys
-  each query attended to (the choice holds ``min(t + 1, top_k)``: a
-  different count says that this kernel's scores are not the choice's);
+* ``dsa_attend_fwd``: attention over the chosen keys, every head of a tile
+  in one grid step, online softmax; ``o``, each row's log-sum-exp, how many
+  keys each query attended to (the choice holds ``min(t + 1, top_k)``: a
+  different count says that this kernel's scores are not the choice's) and
+  each tile's choice as a packed mask (``_pack``: a ``(block_k // 32,
+  block_q)`` int32 block a (query block, key block) pair of the pair list,
+  68.2 MB a layer at 32768 positions and blocks of 512);
 * ``dsa_attend_bwd``: its backward (``ops/attention_pallas.py``'s five
-  products, under the mask), ``d k, d v`` of the group's whole sequence
-  resident in VMEM;
+  products, under the forward's mask, unpacked once a grid step for the
+  query heads of one key-value head), ``d k, d v`` of the group's whole
+  sequence resident in VMEM;
 * ``dsa_kl``: all heads of a tile at once, with the forward's log-sum-exp:
   the target (the heads' probabilities summed over the head count), the
   indexer's loss a query (``sum p log p - sum p I + lse_I``) and its
@@ -107,6 +113,23 @@ def _chosen(scores, key, causal, theta, cut):
     its score is above the threshold, or on it and not after the cut."""
     return causal & ((scores > theta)
                      | ((scores == theta) & (key.astype(jnp.float32) <= cut)))
+
+
+def _pack(chosen):
+    """(bk, bq) bool -> (bk // 32, bq) int32: bit ``b`` of word row ``w``
+    holds key row ``w + (bk // 32) * b``, so that each bit is a whole block
+    of rows."""
+    rows = chosen.shape[0] // 32
+    bits = chosen.astype(jnp.int32)
+    words = bits[:rows]
+    for b in range(1, 32):
+        words = words | (bits[b * rows:(b + 1) * rows] << b)
+    return words
+
+
+def _unpack(words):
+    """``_pack``'s inverse: (bk // 32, bq) int32 -> (bk, bq) bool."""
+    return jnp.concatenate([(words >> b) & 1 for b in range(32)], axis=0) != 0
 
 
 def _ordered(x):
@@ -270,8 +293,8 @@ def _tile_mask(i, j, bq, bk, ki_ref, qi_ref, w_ref, theta_ref, cut_ref,
 
 
 def _fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
-                theta_ref, cut_ref, o_ref, lse_ref, n_ref, m_ref, l_ref,
-                acc_ref, n_acc, *, bq, bk, d, r, hq, scale, heads):
+                theta_ref, cut_ref, o_ref, lse_ref, n_ref, mask_ref, m_ref,
+                l_ref, acc_ref, n_acc, *, bq, bk, d, r, hq, scale, heads):
     t = pl.program_id(0)
     i, j = qb_ref[t], kb_ref[t]
 
@@ -282,10 +305,11 @@ def _fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         n_acc[...] = jnp.zeros_like(n_acc)
 
-    # the tile's choice, once for every head
+    # the tile's choice, once for every head, and for the backward
     _, chosen = _tile_mask(i, j, bq, bk, ki_ref, qi_ref, w_ref, theta_ref,
                            cut_ref, scale, heads)
     n_acc[...] += jnp.sum(chosen.astype(jnp.float32), axis=0, keepdims=True)
+    mask_ref[...] = _pack(chosen)
 
     def head(h):
         group = _head(h // r, d)
@@ -318,9 +342,8 @@ def _fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
 
 
 def _bwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, qi_ref, ki_ref, w_ref, theta_ref, cut_ref, dq_ref,
-                dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, bq, bk, d, r,
-                last, scale, heads):
+                delta_ref, mask_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
+                dv_acc, *, bq, bk, d, r, last):
     t = pl.program_id(1)
     i, j = qb_ref[t], kb_ref[t]
 
@@ -333,8 +356,7 @@ def _bwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init_q():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    _, chosen = _tile_mask(i, j, bq, bk, ki_ref, qi_ref, w_ref, theta_ref,
-                           cut_ref, scale, heads)
+    chosen = _unpack(mask_ref[...])
     k, v = k_ref[...], v_ref[...]
     rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
 
@@ -462,8 +484,11 @@ def _indexer_specs(bq, bk, heads, at):
 def attend_fwd(q, k, v, qi, ki, w_t, theta, cut, *, hkv, heads, scale, bq,
                bk, interpret):
     """q (S, Hq * D); k, v (S, Hkv * D) -> o as q, lse (Hkv, R, 1, S), the
-    keys each query attended to (1, S) float32.  Every head of a tile in one
-    grid step, so that the tile's choice is formed once."""
+    keys each query attended to (1, S) float32, and the choice of each pair
+    of ``_pairs(S, bq, bk)`` packed (``_pack``) as (pairs, bk // 32, bq)
+    int32: (2080, 16, 512), 68.2 MB, at 32768 positions and blocks of 512.
+    Every head of a tile in one grid step, so that the tile's choice is
+    formed once; the backward reads it from the mask."""
     s, hd = q.shape
     d = k.shape[1] // hkv
     hq = hd // d
@@ -471,19 +496,23 @@ def attend_fwd(q, k, v, qi, ki, w_t, theta, cut, *, hkv, heads, scale, bq,
     at = lambda t, qb, kb: (qb[t], kb[t])   # noqa: E731
     rows_q = pl.BlockSpec((bq, hd), lambda t, qb, kb: (qb[t], 0))
     rows_k = pl.BlockSpec((bk, k.shape[1]), lambda t, qb, kb: (kb[t], 0))
-    o, lse, attended = pl.pallas_call(
+    o, lse, attended, mask = pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, d=d, r=hq // hkv, hq=hq,
                           scale=scale, heads=heads),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((hq, 1, s), jnp.float32),
-                   jax.ShapeDtypeStruct((1, s), jnp.float32)),
+                   jax.ShapeDtypeStruct((1, s), jnp.float32),
+                   jax.ShapeDtypeStruct((qb.shape[0], bk // 32, bq),
+                                        jnp.int32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(qb.shape[0],),
             in_specs=[rows_q, rows_k, rows_k]
             + _indexer_specs(bq, bk, heads, at),
             out_specs=(rows_q, pl.BlockSpec(
                 (hq, 1, bq), lambda t, qb, kb: (0, 0, qb[t])),
-                pl.BlockSpec((1, bq), lambda t, qb, kb: (0, qb[t]))),
+                pl.BlockSpec((1, bq), lambda t, qb, kb: (0, qb[t])),
+                pl.BlockSpec((None, bk // 32, bq),
+                             lambda t, qb, kb: (t, 0, 0))),
             scratch_shapes=[pltpu.VMEM((hq, 1, bq), jnp.float32),
                             pltpu.VMEM((hq, 1, bq), jnp.float32),
                             pltpu.VMEM((hq, d, bq), jnp.float32),
@@ -491,19 +520,18 @@ def attend_fwd(q, k, v, qi, ki, w_t, theta, cut, *, hkv, heads, scale, bq,
         compiler_params=_params(0), interpret=interpret,
         name="dsa_attend_fwd",
     )(qb, kb, q, k, v, qi, ki, w_t, theta, cut)
-    return o, lse.reshape(hkv, hq // hkv, 1, s), attended
+    return o, lse.reshape(hkv, hq // hkv, 1, s), attended, mask
 
 
-@functools.partial(jax.jit, static_argnames=("hkv", "heads", "scale", "bq",
-                                             "bk", "interpret"))
-def attend_bwd(q, k, v, do, lse, delta, qi, ki, w_t, theta, cut, *, hkv,
-               heads, scale, bq, bk, interpret):
-    """The cotangents of q, k and v, each as its operand."""
+@functools.partial(jax.jit, static_argnames=("hkv", "bq", "bk", "interpret"))
+def attend_bwd(q, k, v, do, lse, delta, mask, *, hkv, bq, bk, interpret):
+    """The cotangents of q, k and v, each as its operand; ``mask`` the
+    forward's packed choice (``attend_fwd``), one block a pair, read by each
+    key-value head's pass: the indexer's scores are not formed here."""
     s, hd = q.shape
     d = k.shape[1] // hkv
     r = hd // d // hkv
     qb, kb = _pairs(s, bq, bk)
-    at = lambda g, t, qb, kb: (qb[t], kb[t])   # noqa: E731
     rows_q = pl.BlockSpec((bq, r * d), lambda g, t, qb, kb: (qb[t], g))
     rows_k = pl.BlockSpec((bk, d), lambda g, t, qb, kb: (kb[t], g))
     whole_k = pl.BlockSpec((s, d), lambda g, t, qb, kb: (0, g))
@@ -511,21 +539,22 @@ def attend_bwd(q, k, v, do, lse, delta, qi, ki, w_t, theta, cut, *, hkv,
                          lambda g, t, qb, kb: (g, 0, 0, qb[t]))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, bq=bq, bk=bk, d=d, r=r,
-                          last=qb.shape[0] - 1, scale=scale, heads=heads),
+                          last=qb.shape[0] - 1),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(hkv, qb.shape[0]),
-            in_specs=[rows_q, rows_k, rows_k, rows_q, stats, stats]
-            + _indexer_specs(bq, bk, heads, at),
+            in_specs=[rows_q, rows_k, rows_k, rows_q, stats, stats,
+                      pl.BlockSpec((None, bk // 32, bq),
+                                   lambda g, t, qb, kb: (t, 0, 0))],
             out_specs=(rows_q, whole_k, whole_k),
             scratch_shapes=[pltpu.VMEM((r, d, bq), jnp.float32),
                             pltpu.VMEM((s, d), jnp.float32),
                             pltpu.VMEM((s, d), jnp.float32)]),
         compiler_params=_params(1), interpret=interpret,
         name="dsa_attend_bwd",
-    )(qb, kb, q, k, v, do, lse, delta, qi, ki, w_t, theta, cut)
+    )(qb, kb, q, k, v, do, lse, delta, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("hkv", "heads", "scale", "bq",
@@ -578,16 +607,20 @@ def _attend(static, q, k, v, qi, ki, w_t, theta, cut):
 
 
 def _attend_fwd(static, q, k, v, qi, ki, w_t, theta, cut):
+    """The forward kernel; its residuals are q, k, v, o, lse and the packed
+    choice, which the backward reads in place of the indexer's operands.
+    The mask is not named: a checkpoint under ``KEEP_CHOICE`` runs this
+    again before the backward, and one layer's mask is live at a time."""
     hkv, heads, scale, bq, bk, interpret = static
-    o, lse, attended = attend_fwd(q, k, v, qi, ki, w_t, theta, cut, hkv=hkv,
-                                  heads=heads, scale=scale, bq=bq, bk=bk,
-                                  interpret=interpret)
-    return (o, lse, attended), (q, k, v, qi, ki, w_t, theta, cut, o, lse)
+    o, lse, attended, mask = attend_fwd(q, k, v, qi, ki, w_t, theta, cut,
+                                        hkv=hkv, heads=heads, scale=scale,
+                                        bq=bq, bk=bk, interpret=interpret)
+    return (o, lse, attended), (q, k, v, mask, o, lse)
 
 
 def _attend_bwd(static, res, g):
-    hkv, heads, scale, bq, bk, interpret = static
-    q, k, v, qi, ki, w_t, theta, cut, o, lse = res
+    hkv, _, _, bq, bk, interpret = static
+    q, k, v, mask, o, lse = res
     do = g[0].astype(q.dtype)
     s, hd = q.shape
     d = k.shape[1] // hkv
@@ -597,8 +630,7 @@ def _attend_bwd(static, res, g):
         delta = jnp.sum((o.astype(jnp.float32) * g[0].astype(jnp.float32))
                         .reshape(s, hd // d, d), -1)           # (S, Hq)
         delta = delta.T.reshape(hkv, -1, 1, s)
-        dq, dk, dv = attend_bwd(q, k, v, do, lse, delta, qi, ki, w_t, theta,
-                                cut, hkv=hkv, heads=heads, scale=scale,
+        dq, dk, dv = attend_bwd(q, k, v, do, lse, delta, mask, hkv=hkv,
                                 bq=bq, bk=bk, interpret=interpret)
     # the choice and the indexer's operands take no gradient from here
     return dq, dk, dv, None, None, None, None, None
